@@ -1411,12 +1411,6 @@ def cmd_quarantine(state: State, args) -> None:
 # ---- schedule ----
 def cmd_schedule(state: State, args) -> None:
     rt = state.build_runtime()
-    if getattr(args, "platform", None):
-        # explicit device selection (some images pin jax_platforms in
-        # sitecustomize, so the env var alone cannot force a backend)
-        import jax
-
-        jax.config.update("jax_platforms", args.platform)
     if getattr(args, "drain", False):
         # capacity what-if: the pending backlog planned in one device
         # dispatch (core/drain) and summarized; the cycle loop below
@@ -1452,6 +1446,7 @@ def cmd_schedule(state: State, args) -> None:
             timestamp_fn=lambda wl: queue_order_timestamp(
                 wl, rt.queues._ts_policy
             ),
+            ordered_stamps=getattr(rt.clock, "tracks_wall", False),
         )
         evicted = len(getattr(outcome, "evictions", []) or [])
         # heads the classifier dropped to the cycle loop (TAS heads in
@@ -1871,10 +1866,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="print a bulk what-if plan (whole backlog in one device "
         "dispatch) before the cycle loop decides",
     )
-    sch.add_argument(
-        "--platform", choices=["cpu", "tpu"],
-        help="force the JAX backend for --drain dispatches",
-    )
     sch.set_defaults(fn=cmd_schedule)
 
     imp = sub.add_parser("import")
@@ -1911,4 +1902,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from kueue_tpu._jax import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())

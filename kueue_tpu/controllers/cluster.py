@@ -1486,12 +1486,16 @@ class ClusterRuntime:
                 mesh=self.mesh,
                 policy=self.policy,
                 now=self.clock.now(),
+                # a running clock stamps the host loop's admissions one
+                # after another; the drain must rank its own the same way
+                ordered_stamps=getattr(self.clock, "tracks_wall", False),
             ),
             label="bulk drain",
         )
         if guarded.result is None:
             return None
         outcome = guarded.result
+        self._resume_walks(outcome)
         t_solve = _time.perf_counter() - t1
         sched.guard.phase_checkpoint("drain.solve", device_used=True)
         from kueue_tpu.testing import faults
@@ -1693,6 +1697,11 @@ class ClusterRuntime:
             ):
                 last_round = log.rounds[-1]
                 t1 = _time.perf_counter()
+                # the prefetch plans the last round's undecided heads
+                # from their latest walks (no round is replayed in a
+                # launch that prefetches)
+                for round_out in log.rounds:
+                    self._resume_walks(round_out)
                 pf_snap = speculative_snapshot(
                     snapshot, last_round.final_usage
                 )
@@ -1814,6 +1823,7 @@ class ClusterRuntime:
                     sched.tracer.discard_cycle()
                     _set_inflight(0)
                     return last_result
+                self._resume_walks(outcome)
                 t_apply = _time.perf_counter() - t1
                 pstats.note_apply(t_apply, overlapped=pf is not None)
                 self.metrics.pipeline_overlap_ratio.set(
@@ -2063,6 +2073,7 @@ class ClusterRuntime:
                 if host is not None:
                     outcome = host  # host mirror is now the authority
             undecided = outcome.undecided
+            self._resume_walks(outcome)
             decided = bool(outcome.admitted or outcome.parked)
             if not decided:
                 # the chunk decided NOTHING (fully unrepresentable or
@@ -2205,6 +2216,24 @@ class ClusterRuntime:
             if not undecided or rounds >= 100000:
                 _set_inflight(0)
                 return last_result
+
+    def _resume_walks(self, outcome) -> None:
+        """Store the flavor walk of every head a drain cut off mid-walk
+        as its LastAssignment, as the host cycle loop keeps it between
+        cycles, so the next round's lowering resumes it."""
+        from kueue_tpu.core.solver import resumed_assignment
+
+        for wl, cq_name, walks in outcome.resume:
+            cached = self.cache.cluster_queues.get(cq_name)
+            if cached is None:
+                continue
+            wl.last_assignment = (
+                resumed_assignment(
+                    cached.model, wl, walks, cached.allocatable_generation
+                )
+                if walks.max() > 0
+                else None  # the walk restarts at the first flavor
+            )
 
     def _apply_drain_outcome(self, outcome, snapshot):
         """Apply a DrainOutcome in kernel cycle order: evictions before

@@ -64,6 +64,27 @@ class DrainOutcome:
     # post-apply snapshot the pipeline launches round t+1 against
     # (None on paths that don't report it)
     final_usage: Optional[np.ndarray] = None
+    # (workload, cq_name, int[P, G] next flavor per group) of each
+    # undecided head the kernel nominated: the runtime stores the walk
+    # as the head's LastAssignment before the next round is planned
+    resume: List[Tuple[Workload, str, np.ndarray]] = field(
+        default_factory=list
+    )
+
+
+def _resume_of(plan, cursor, stuck, g_start) -> List[Tuple[Workload, str, np.ndarray]]:
+    """Undecided queue heads (at ``cursor``, queue not stuck) the kernel
+    nominated, with their flavor walks ``g_start`` [Q, P, G]; a head it
+    never nominated carries -1 there and keeps its LastAssignment."""
+    out = []
+    for qi in np.flatnonzero(np.any(g_start >= 0, axis=(1, 2))):
+        i = plan.head_of.get((int(qi), int(cursor[qi])))
+        if i is not None and not bool(stuck[qi]):
+            out.append(
+                (plan.lowered.heads[i], plan.lowered.cq_names[i],
+                 np.array(g_start[qi]))
+            )
+    return out
 
 
 def _admitted_flavors(lowered, i: int, adm_k_row) -> Dict[str, str]:
@@ -522,6 +543,7 @@ def _lower_victim_pools(
     lpaths = np.full((s_dim, m_dim, dmax), -1, dtype=np.int32)
     hlocal = np.zeros(q, dtype=np.int32)
     perm = np.tile(np.arange(v_cap, dtype=np.int32), (q, 1))
+    ptier = perm.copy()  # each ord position its own tier: order fixed
     entry_slot = np.full((q, nl), -1, dtype=np.int32)
     victim_of: Dict[Tuple[int, int], object] = {}
     slot_meta: Dict[int, list] = {}  # s -> [(evicted0, owner, prio, rt, uid, adj)]
@@ -632,6 +654,15 @@ def _lower_victim_pools(
             perm[qi, len(keyed) :] = np.arange(
                 len(keyed), v_cap, dtype=np.int32
             )
+            # the order's tiers above reservation time: within one, the
+            # kernel may re-rank part B by when the drain admitted it
+            tier = [
+                (meta[j][0], meta[j][1] != own, meta[j][5], meta[j][2])
+                for j in keyed
+            ]
+            ptier[qi, : len(keyed)] = np.cumsum(
+                [0] + [a != b for a, b in zip(tier, tier[1:])]
+            )
 
     # ---- drop ineligible queues to the fallback path ----
     extra_fb_entries: List[Tuple[Workload, str]] = []
@@ -681,7 +712,7 @@ def _lower_victim_pools(
         scells=scells, sqty=sqty, sprio=sprio, sts=sts, svalid0=svalid0,
         sowner=sowner, sowner_local=sowner_local, sslot_q=sslot_q,
         sslot_l=sslot_l, seg_nodes=seg_nodes, lpaths=lpaths,
-        hlocal=hlocal, perm=perm, entry_slot=entry_slot,
+        hlocal=hlocal, perm=perm, ptier=ptier, entry_slot=entry_slot,
         same_enabled=same_enabled, same_prio_ok=same_prio_ok,
         reclaim_enabled=reclaim_enabled, only_lower=only_lower, bwc=bwc,
         bwc_thr1=bwc_thr1,
@@ -778,17 +809,20 @@ def run_drain_for_scope(
     mesh=None,  # jax.sharding.Mesh: shard every drain kind's Q axis
     policy=None,  # kueue_tpu/policy AdmissionPolicy, every kind
     now: float = 0.0,
+    ordered_stamps: bool = False,
 ):
     """Dispatch the drain a classify_drain_scope kind names — the ONE
     place the kind→drain mapping lives, so the service bulk path and
     the CLI what-if stay identical by construction. ``mesh`` flows to
     every kind: the whole drain family runs under a ``(wl[, fr])`` mesh
     with decisions bit-for-bit the single-device kernels'
-    (tests/test_mesh_drain.py)."""
+    (tests/test_mesh_drain.py). ``ordered_stamps``: the caller's clock
+    runs (see run_drain_preempt)."""
     if kind == "fair_preempt":
         return run_drain_fair_preempt(
             snapshot, pending, flavors, timestamp_fn=timestamp_fn,
             fs_strategies=fs_strategies, mesh=mesh, policy=policy,
+            ordered_stamps=ordered_stamps,
         )
     if kind == "fair":
         return run_drain(
@@ -798,7 +832,7 @@ def run_drain_for_scope(
     if kind == "preempt":
         return run_drain_preempt(
             snapshot, pending, flavors, timestamp_fn=timestamp_fn, mesh=mesh,
-            policy=policy,
+            policy=policy, ordered_stamps=ordered_stamps,
         )
     if kind == "tas":
         return run_drain_tas(
@@ -841,7 +875,7 @@ class PanelTuner:
     """Online kernel-shape search for the victim-search panel width.
 
     The contended drain is THROUGHPUT-bound in the strategy-ladder scan
-    (BENCH_NOTES_r05.md: cost scales with ``search_width``; fusing the
+    (the round-5 capture: cost scales with ``search_width``; fusing the
     two attempts changed nothing), so the shape lever is the panel
     width itself. Candidate panels are already sorted by the
     preemption-cost key (evicted first, other-CQ first, lowest
@@ -936,6 +970,7 @@ def run_drain_preempt(
     panel_tuner: Optional[PanelTuner] = None,
     policy=None,  # kueue_tpu/policy AdmissionPolicy: scored flavor
     #               choice + PREMA victim-cost adjustments
+    ordered_stamps: bool = False,
     # internal (the narrow-panel GSPMD probe): run the given
     # panel_widths under the mesh WITHOUT consulting the probe verdict
     # — the probe itself is what establishes it
@@ -951,7 +986,10 @@ def run_drain_preempt(
     the host cycle loop sees drain-admitted workloads in its snapshot
     the same way). ``now`` is the quota-reservation instant attributed
     to in-drain admissions for candidate ordering (default: after every
-    part-A reservation). ``max_victims`` caps a SEGMENT's pool;
+    part-A reservation). With ``ordered_stamps`` — the runtime's clock
+    runs, so the host cycle loop stamps each admission as it applies it
+    — in-drain admissions instead rank by (cycle, entry order), and the
+    outcome lists them in that order for the caller to apply. ``max_victims`` caps a SEGMENT's pool;
     overflowing segments route their preempt-capable queues to
     ``fallback`` for the sequential cycle loop, as do victims with more
     than ``max_victim_cells`` distinct usage cells. ``search_width``
@@ -962,12 +1000,12 @@ def run_drain_preempt(
     reclaiming CQ: it appears in BOTH lists) — this function only
     decides.
 
-    ``panel_widths`` overrides the panel schedule (last entry = the
-    trusted exact width); default is the process-wide ``PanelTuner``'s
-    (narrow, search_width) schedule — the solve runs at the narrow
-    cost-ordered panel and re-solves at the wide exact width ONLY when
-    the kernel reports an inconclusive truncated search, so decisions
-    always equal the fixed-``search_width`` kernel's (asserted in
+    ``panel_widths`` overrides the panel schedule; default is the
+    process-wide ``PanelTuner``'s (narrow, search_width) schedule. The
+    solve runs at the narrow cost-ordered panel and re-solves wider ONLY
+    when the kernel reports an inconclusive truncated search, doubling
+    past the schedule's last width up to the whole candidate axis, so
+    decisions always equal an untruncated search's (asserted in
     tests/test_drain_parity.py).
     """
     from kueue_tpu._jax import jnp
@@ -1063,6 +1101,14 @@ def run_drain_preempt(
         safe = mesh_safe_widths(mesh, widths)
         note_panel_schedule(safe, fenced=safe != widths)
         widths = safe
+    # Past the exact width one last rung searches the whole candidate
+    # axis, where no list can overflow: a search that missed in a
+    # truncated window otherwise freezes its queue while the rest of
+    # the cohort drains on, which changes the decisions (seen at 1,000
+    # contended CQs, where a cohort's pool outgrows 32 slots).
+    v_slots = int(victims_np["perm"].shape[1])
+    if widths[-1] < v_slots:
+        widths = tuple(widths) + (v_slots,)
     escalated = False
     for i, width in enumerate(widths):
         if mesh is not None:
@@ -1088,6 +1134,7 @@ def run_drain_preempt(
                     n_steps=plan.n_steps,
                     max_cycles=plan.max_cycles,
                     search_width=int(width),
+                    ordered_stamps=ordered_stamps,
                 )
             )  # one fetch per tier; the common case stops at the first
         except Exception as exc:
@@ -1150,6 +1197,7 @@ def _preempt_outcome(
     status = flat[off : off + ql].reshape((nq, nl2)); off += ql
     adm_k = flat[off : off + qlp].reshape((nq, nl2, npd)); off += qlp
     adm_cycle = flat[off : off + ql].reshape((nq, nl2)); off += ql
+    adm_order = flat[off : off + ql].reshape((nq, nl2)); off += ql
     evicted = flat[off : off + sv].reshape((s_dim, v_cap)).astype(bool); off += sv
     evict_cycle = flat[off : off + sv].reshape((s_dim, v_cap)); off += sv
     evict_by = flat[off : off + sv].reshape((s_dim, v_cap)); off += sv
@@ -1170,6 +1218,7 @@ def _preempt_outcome(
     admitted: List[Tuple[Workload, str, Dict[str, str], int]] = []
     parked: List[Tuple[Workload, str]] = []
     extra_fallback: List[Tuple[Workload, str]] = list(extra_fb_entries)
+    order_of: Dict[str, int] = {}
     for (qi, pos), i in plan.head_of.items():
         wl = lowered.heads[i]
         cq_name = lowered.cq_names[i]
@@ -1180,12 +1229,15 @@ def _preempt_outcome(
                 (wl, cq_name, _admitted_flavors(lowered, i, adm_k[qi, pos]),
                  int(adm_cycle[qi, pos]))
             )
+            order_of[wl.key] = int(adm_order[qi, pos])
         elif st == 0:
             # still pending at max_cycles: not a decision
             extra_fallback.append((wl, cq_name))
         else:
             parked.append((wl, cq_name))
-    admitted.sort(key=lambda t: t[3])
+    # cycle by cycle, each in its entry order: the order the host cycle
+    # loop admits (and stamps) them
+    admitted.sort(key=lambda t: (t[3], order_of[t[0].key]))
     from kueue_tpu.core.preemption import (
         IN_CLUSTER_QUEUE,
         IN_COHORT_FAIR_SHARING,
@@ -1303,6 +1355,7 @@ def run_drain_fair_preempt(
     policy=None,  # kueue_tpu/policy AdmissionPolicy
     fs_strategies: Optional[Sequence[str]] = None,
     mesh=None,  # jax.sharding.Mesh: shard the Q axis across devices
+    ordered_stamps: bool = False,  # as run_drain_preempt
 ) -> PreemptDrainOutcome:
     """Multi-cycle drain with FAIR-SHARING admission ordering AND
     fair-sharing preemption — the production fair-cohort configuration
@@ -1513,6 +1566,7 @@ def run_drain_fair_preempt(
             prio_tie=bool(_feature_enabled("PrioritySortingWithinCohort")),
             strategy1=strategy1,
             has_second=len(strategies) > 1,
+            ordered_stamps=ordered_stamps,
         )
     )  # the single fetch
     return _preempt_outcome(plan, low, flat, queues_np, fair=True)
@@ -2057,7 +2111,7 @@ class DrainLaunch:
 
     plan: DrainPlan
     queues_np: dict
-    flat_dev: object  # unfetched device array
+    flat_dev: object  # unfetched device outputs (_pack_drain_result)
     usage_shape: Tuple[int, int]
     extra_fb_entries: List[Tuple[Workload, str]] = field(default_factory=list)
     # the exact backlog this launch solves, in per-CQ heap order — the
@@ -2067,22 +2121,25 @@ class DrainLaunch:
     max_cycles: Optional[int] = None
 
     def fetch(self) -> DrainOutcome:
-        flat = np.asarray(self.flat_dev)  # the single fetch
+        from kueue_tpu._jax import jax
+
+        # the single fetch (ops/drain_kernel._pack_drain_result)
+        flat, final_usage, cycles = jax.device_get(self.flat_dev)
         nq, nl, npd = self.queues_np["cells"].shape[:3]  # incl. padding
+        ng = self.queues_np["gidx"].shape[-1]
         ql = nq * nl
         qlp = nq * nl * npd
         adm_k = flat[:qlp].reshape((nq, nl, npd))
         adm_cycle = flat[qlp : qlp + ql].reshape((nq, nl))
         cursor = flat[qlp + ql : qlp + ql + nq]
         stuck_q = flat[qlp + ql + nq : qlp + ql + 2 * nq].astype(bool)
-        off = qlp + ql + 2 * nq
-        n_u = int(self.usage_shape[0]) * int(self.usage_shape[1])
-        final_usage = flat[off : off + n_u].reshape(self.usage_shape)
-        cycles = int(flat[-1])
+        g_start = flat[qlp + ql + 2 * nq :].reshape((nq, npd, ng))
+        final_usage = np.asarray(final_usage).reshape(self.usage_shape)
+        cycles = int(cycles)
         return _map_drain_result(
             self.plan, adm_k, adm_cycle, cursor, stuck_q, cycles,
             self.queues_np, self.extra_fb_entries,
-            final_usage=final_usage,
+            final_usage=final_usage, g_start=g_start,
         )
 
 
@@ -2229,31 +2286,23 @@ class MegaloopLaunch:
 
     plan: DrainPlan
     queues_np: dict
-    flat_dev: object  # unfetched device array (the packed log)
+    flat_dev: object  # unfetched (int32 log, int64 round usage)
     usage_shape: Tuple[int, int]
     start_usage: np.ndarray  # launch-time leaf usage (row-0 fallback)
     pending: List[Tuple[Workload, str]]
     chunk_cycles: int
     max_rounds: int
 
-    def _usage_offset(self, r: int) -> int:
-        q, l, p = self.queues_np["cells"].shape[:3]
-        n, fr = self.usage_shape
-        return (
-            q * l * p + 2 * q * l + 2 * self.max_rounds * q
-            + self.max_rounds + r * n * fr
-        )
-
     def usage_dev(self, r: int):
         """Round r's final leaf usage as a DEVICE slice of the packed
         log — the in-loop usage carry the ResidentEncoder adopts after
         a fully-committed launch (no host round trip)."""
-        n, fr = self.usage_shape
-        off = self._usage_offset(r)
-        return self.flat_dev[off : off + n * fr].reshape((n, fr))
+        return self.flat_dev[1][r]
 
     def fetch(self) -> MegaloopLog:
-        flat = np.asarray(self.flat_dev)  # the single fetch
+        from kueue_tpu._jax import jax
+
+        flat, r_usage = jax.device_get(self.flat_dev)  # the single fetch
         q, l, p = self.queues_np["cells"].shape[:3]
         n, fr = self.usage_shape
         rr = self.max_rounds
@@ -2267,13 +2316,15 @@ class MegaloopLaunch:
             flat[off : off + rr * q].reshape((rr, q)).astype(bool)
         ); off += rr * q
         r_cycles = flat[off : off + rr]; off += rr
-        r_usage = flat[off : off + rr * n * fr].reshape((rr, n, fr))
+        ng = self.queues_np["gidx"].shape[-1]
+        r_gstart = flat[off : off + rr * q * p * ng].reshape((rr, q, p, ng))
+        r_usage = np.asarray(r_usage).reshape((rr, n, fr))
         n_rounds = int(flat[-2])
         cycles = int(flat[-1])
         rounds = _map_megaloop_rounds(
             self.plan, self.queues_np, adm_k, adm_cycle, adm_round,
             r_cursor, r_stuck, r_cycles, r_usage, n_rounds,
-            self.start_usage,
+            self.start_usage, r_gstart,
         )
         return MegaloopLog(
             rounds=rounds,
@@ -2295,6 +2346,7 @@ def _map_megaloop_rounds(
     r_usage,
     n_rounds: int,
     start_usage: np.ndarray,
+    r_gstart: np.ndarray,
 ) -> List[DrainOutcome]:
     """Slice the fused log into per-round DrainOutcomes — each
     bit-for-bit what ``_map_drain_result`` would have produced for a
@@ -2357,6 +2409,11 @@ def _map_megaloop_rounds(
                 truncated=bool(undecided),
                 undecided=undecided,
                 final_usage=usage_r.astype(np.int64, copy=False),
+                resume=(
+                    _resume_of(plan, cursor_r, stuck_r, r_gstart[r])
+                    if ran and undecided
+                    else []
+                ),
             )
         )
         prev_cursor = cursor_r.astype(np.int64).copy()
@@ -2528,7 +2585,7 @@ def run_drain_megaloop_host(
         plan, plan.queues_np, host.admitted_k, host.admitted_cycle,
         host.admitted_round, host.round_cursor, host.round_stuck,
         host.round_cycles, host.round_usage, host.rounds,
-        np.asarray(snapshot.local_usage),
+        np.asarray(snapshot.local_usage), host.round_gstart,
     )
     return MegaloopLog(
         rounds=rounds,
@@ -2650,6 +2707,7 @@ def run_drain(
             plan.queues_np,
             extra_fb_entries=[],
             final_usage=np.asarray(host.local_usage),
+            g_start=host.g_start,
         )
     tree, paths, _ = tree_arrays(snapshot)
     queues_np = plan.queues_np
@@ -2750,7 +2808,8 @@ def _map_drain_result(
     cycles: int,
     queues_np: dict,
     extra_fb_entries: List[Tuple[Workload, str]],
-    final_usage: Optional[np.ndarray] = None,
+    final_usage: np.ndarray,
+    g_start: np.ndarray,
 ) -> DrainOutcome:
     """Map a plain drain's per-queue result tensors back onto workloads
     — ONE definition shared by the device fetch and the numpy host
@@ -2791,4 +2850,5 @@ def _map_drain_result(
     return DrainOutcome(
         admitted=admitted, parked=parked, fallback=fb, cycles=cycles,
         truncated=truncated, undecided=undecided, final_usage=final_usage,
+        resume=_resume_of(plan, cursor, stuck_q, g_start) if undecided else [],
     )

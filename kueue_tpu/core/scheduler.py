@@ -303,7 +303,7 @@ class Scheduler:
         # reporting and admission spies (no monkeypatching schedule()).
         self.cycle_observers: List[Callable[[CycleResult], None]] = []
         # Latency-aware auto gating. A device dispatch pays a fixed
-        # round-trip cost (tens of ms on remote-attached TPUs) that only
+        # round-trip cost (a per-launch dispatch floor) that only
         # amortizes once the cycle batches enough heads, so auto mode
         # measures both paths at runtime and routes each cycle to the
         # cheaper one: EMA of the host cost per head, windowed MIN of

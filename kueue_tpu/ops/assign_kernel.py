@@ -633,8 +633,7 @@ def _solve_cycle_segmented_packed(
 ):
     """solve_cycle_segmented with the per-head outputs stacked into ONE
     int64[5, W] tensor, so the host retrieves the whole cycle outcome in
-    a single device->host fetch (each fetch pays a full round trip on
-    remote-attached TPUs; see bench.py)."""
+    a single device->host fetch (each fetch pays a full round trip)."""
     r = solve_cycle_segmented(
         tree, local_usage, heads, paths, seg_id, n_segments, n_steps
     )

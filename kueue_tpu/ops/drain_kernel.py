@@ -1,8 +1,8 @@
 """Multi-cycle admission drain — the whole backlog on the device.
 
 The interactive scheduler ping-pongs one cycle at a time: pop heads,
-solve, fetch, admit, repeat. On a remote-attached TPU every fetch pays
-a full host<->device round trip, which dwarfs the solve itself. For the
+solve, fetch, admit, repeat. Every fetch pays a full host<->device
+round trip, which can dwarf the solve itself. For the
 bulk scenario the north star describes (a large pending backlog drained
 to quiescence with no arrivals in between — BASELINE.md: 50k pending
 over 1k ClusterQueues), the TPU-native formulation is to keep the WHOLE
@@ -127,7 +127,9 @@ class DrainResult(NamedTuple):
     admission (-1 = never); cursor: int32[Q] final queue position —
     entries at pos >= cursor were never processed (max_cycles hit);
     cycles: int32 scalar — cycles executed; local_usage: int64[N,FR]
-    final leaf usage."""
+    final leaf usage; g_start: int32[Q,P,G] the flavor walk each
+    queue's head resumes from (its LastAssignment), so a drain cut at
+    max_cycles continues where the host cycle loop would."""
 
     admitted_k: jnp.ndarray
     admitted_cycle: jnp.ndarray
@@ -135,6 +137,7 @@ class DrainResult(NamedTuple):
     cycles: jnp.ndarray
     local_usage: jnp.ndarray
     stuck: jnp.ndarray  # bool[Q] — frozen PendingFlavors spinners
+    g_start: jnp.ndarray
 
 
 def _group_walk(
@@ -439,9 +442,12 @@ def _cursor_queue_motion(
         jnp.where(admitted & active, cycle, adm_cycle[q_idx, cur])
     )
     lost = active & is_fit & (~admitted)
+    # a queue's next head starts its walk at -1 (masks like 0): the
+    # host tells a head the drain never nominated (keeps its stored
+    # LastAssignment) from one whose walk restarted at 0
     g_start = jnp.where(
         advance[:, None, None],
-        0,
+        -1,
         jnp.where((lost | retrying)[:, None, None], walk_next, g_start),
     ).astype(jnp.int32)
     cursor = cursor + advance.astype(jnp.int32)
@@ -630,9 +636,8 @@ def solve_drain(
         jnp.full((q, l), -1, dtype=jnp.int32),
         jnp.int32(0),
     )
-    (local_f, cursor_f, _, _, stuck_f, _, adm_k, adm_cycle, cycles) = (
-        lax.while_loop(cond, cycle_body, init)
-    )
+    (local_f, cursor_f, g_start_f, _, stuck_f, _, adm_k, adm_cycle,
+     cycles) = lax.while_loop(cond, cycle_body, init)
     return DrainResult(
         admitted_k=adm_k,
         admitted_cycle=adm_cycle,
@@ -640,6 +645,7 @@ def solve_drain(
         cycles=cycles,
         local_usage=local_f,
         stuck=stuck_f,
+        g_start=g_start_f,
     )
 
 
@@ -1462,9 +1468,8 @@ def solve_drain_fair(
         jnp.full((q, l), -1, dtype=jnp.int32),
         jnp.int32(0),
     )
-    (local_f, cursor_f, _, _, stuck_f, _, adm_k, adm_cycle, cycles) = (
-        lax.while_loop(cond, cycle_body, init)
-    )
+    (local_f, cursor_f, g_start_f, _, stuck_f, _, adm_k, adm_cycle,
+     cycles) = lax.while_loop(cond, cycle_body, init)
     return DrainResult(
         admitted_k=adm_k,
         admitted_cycle=adm_cycle,
@@ -1472,6 +1477,7 @@ def solve_drain_fair(
         cycles=cycles,
         local_usage=local_f,
         stuck=stuck_f,
+        g_start=g_start_f,
     )
 
 
@@ -1484,18 +1490,9 @@ def _solve_drain_fair_packed(
         tree, local_usage, queues, paths, depth_of, weight, lendable,
         res_of_fr, n_segments, n_steps, max_cycles, n_res, prio_tie,
     )
-    # same layout as _solve_drain_packed (final leaf usage included)
-    # so run_drain unpacks both scopes with one decoder
-    return jnp.concatenate(
-        [
-            r.admitted_k.reshape(-1).astype(jnp.int64),
-            r.admitted_cycle.reshape(-1).astype(jnp.int64),
-            r.cursor.astype(jnp.int64),
-            r.stuck.astype(jnp.int64),
-            r.local_usage.reshape(-1),
-            r.cycles[None].astype(jnp.int64),
-        ]
-    )
+    # same layout as _solve_drain_packed so run_drain unpacks both
+    # scopes with one decoder
+    return _pack_drain_result(r)
 
 
 solve_drain_fair_packed_jit = jax.jit(
@@ -1540,6 +1537,9 @@ class SegVictims(NamedTuple):
             slots (preemption.go:591-618: evicted first, other-CQ
             first, lowest priority, most recently reserved; in-drain
             admissions all share one reservation instant).
+    ptier: int32[Q,V] — per ord position, the rank of its (evicted,
+            other-CQ, cost, priority) tier; with ``ordered_stamps``
+            the kernel re-ranks part B within a tier by admission.
     entry_slot: int32[Q,L] — part-B pool slot of each entry (-1 none).
     same_enabled: bool[Q] — withinClusterQueue != Never.
     same_prio_ok: bool[Q] — policy == LowerOrNewerEqualPriority.
@@ -1564,6 +1564,7 @@ class SegVictims(NamedTuple):
     lpaths: jnp.ndarray
     hlocal: jnp.ndarray
     perm: jnp.ndarray
+    ptier: jnp.ndarray
     entry_slot: jnp.ndarray
     same_enabled: jnp.ndarray
     same_prio_ok: jnp.ndarray
@@ -1577,7 +1578,7 @@ class SegVictims(NamedTuple):
 # path shards exactly these along ``wl`` and pads them with inert
 # queues; everything else is per-segment / topology, replicated.
 SEG_VICTIM_Q_FIELDS = (
-    "hlocal", "perm", "entry_slot", "same_enabled", "same_prio_ok",
+    "hlocal", "perm", "ptier", "entry_slot", "same_enabled", "same_prio_ok",
     "reclaim_enabled", "only_lower", "bwc", "bwc_thr1",
 )
 
@@ -1603,11 +1604,17 @@ class PreemptDrainResult(NamedTuple):
     prefix — or failed with the full eligible list in-window), so the
     whole drain's decisions are identical to any wider panel's. The
     host uses it as the escalation trigger of the two-tier panel
-    ladder (core/drain.run_drain_preempt panel_widths)."""
+    ladder (core/drain.run_drain_preempt panel_widths).
+
+    admitted_order: int32[Q,L] an admitted entry's position in its
+    cycle's entry order (-1 where not admitted; the fair kernel gives
+    its tournament step, an order within each root cohort), so the host applies — and stamps — a cycle's admissions
+    in the order the host cycle loop would."""
 
     status: jnp.ndarray
     admitted_k: jnp.ndarray
     admitted_cycle: jnp.ndarray
+    admitted_order: jnp.ndarray  # int32[Q,L] rank in its cycle's entry order
     evicted: jnp.ndarray
     evicted_cycle: jnp.ndarray
     evicted_by: jnp.ndarray
@@ -1615,6 +1622,25 @@ class PreemptDrainResult(NamedTuple):
     cycles: jnp.ndarray
     local_usage: jnp.ndarray
     overflowed: jnp.ndarray
+
+
+def _recency_order(perm, ptier, part_b, fill_cycle, fill_pos):
+    """Candidate order with part B re-ranked by admission: on a
+    running clock the host stamps each admission when it applies it,
+    so among drain admissions of one tier the later cycle — and within
+    a cycle the later entry-order position — is more recently reserved
+    and comes first (preemption.go:591-618). Part A keeps its static
+    order after part B, as every snapshot reservation is older.
+
+    perm/ptier: int32[Q,V] static order and its tiers; part_b: bool,
+    fill_cycle/fill_pos: int32[Q,V] per SLOT. Returns int32[Q,V]."""
+    j = jnp.broadcast_to(jnp.arange(perm.shape[1], dtype=jnp.int32), perm.shape)
+    b = jnp.take_along_axis(part_b, perm, axis=1)
+    k1 = jnp.where(b, -jnp.take_along_axis(fill_cycle, perm, axis=1), 1 << 30)
+    k2 = jnp.where(b, -jnp.take_along_axis(fill_pos, perm, axis=1), j)
+    return lax.sort(
+        (ptier, k1, k2, perm), dimension=1, is_stable=True, num_keys=3
+    )[-1]
 
 
 def _compact_candidates(cand_ord: jnp.ndarray, width: int):
@@ -1752,6 +1778,7 @@ def solve_drain_preempt(
     n_steps: int,
     max_cycles: int,
     search_width: int = 32,
+    ordered_stamps: bool = False,
 ) -> PreemptDrainResult:
     """Multi-cycle drain with classic preemption on the device —
     within-ClusterQueue AND cross-CQ cohort reclamation. Per cycle:
@@ -1790,6 +1817,11 @@ def solve_drain_preempt(
     ``search_width`` and whose search fails is frozen as a no-decision
     (truncation is only exact when the search succeeds in-window or
     fails without overflow — see _compact_candidates).
+
+    ``ordered_stamps``: the runtime's clock runs, so its admissions are
+    stamped one after another; drain admissions are then ranked as
+    candidates by (cycle, entry order) — _recency_order — instead of
+    sharing one reservation instant.
     """
     max_depth = tree.max_depth
     subtree, guaranteed = subtree_quota(tree)
@@ -1811,10 +1843,12 @@ def solve_drain_preempt(
     )
     ladder_v = jax.vmap(_ladder_search_one, in_axes=(0,) * 20 + (None,))
 
+    part_b_q = (victims.sslot_q >= 0)[sq]  # [Q,V]
+
     def cycle_body(state):
         (local, status, g_start, retries, stuck, no_prog, adm_k,
          adm_cycle, pcells, pqty, pvalid, vevicted, evict_cycle,
-         evict_by, ovf, cycle) = state
+         evict_by, ovf, cycle, adm_order, fill_cycle, fill_pos) = state
 
         # head of each queue = first pending entry in heap order
         entry_pending = status == 0  # [Q,L]
@@ -1926,7 +1960,15 @@ def solve_drain_preempt(
         cand2 = elig & same_q
 
         enabled1 = active & is_pre & can_search
-        ord_of = victims.perm  # [Q,V] slot ids in candidate order
+        # [Q,V] slot ids in candidate order
+        ord_of = (
+            _recency_order(
+                victims.perm, victims.ptier, part_b_q, fill_cycle[sq],
+                fill_pos[sq],
+            )
+            if ordered_stamps
+            else victims.perm
+        )
 
         def to_ord(x):
             return jnp.take_along_axis(x, ord_of, axis=1)
@@ -1995,6 +2037,9 @@ def solve_drain_preempt(
                 head_borrow.astype(jnp.int64),
                 nofit.astype(jnp.int64),
             )
+        )
+        pos_of = jnp.zeros(q, dtype=jnp.int32).at[order].set(
+            jnp.arange(q, dtype=jnp.int32)
         )
         seg = jnp.maximum(queues.seg_id, 0)[order]
         valid_sorted = active[order] & (queues.seg_id[order] >= 0) & (~nofit[order])
@@ -2195,6 +2240,8 @@ def solve_drain_preempt(
         )
         pqty = pqty.at[sq_w, sl_w].set(mq_w, mode="drop")
         pvalid = pvalid.at[sq_w, sl_w].max(fill, mode="drop")
+        fill_cycle = fill_cycle.at[sq_w, sl_w].set(cycle, mode="drop")
+        fill_pos = fill_pos.at[sq_w, sl_w].set(pos_of, mode="drop")
 
         # ---- queue motion ----
         adm_k = adm_k.at[q_idx, cur].set(
@@ -2204,6 +2251,9 @@ def solve_drain_preempt(
         )
         adm_cycle = adm_cycle.at[q_idx, cur].set(
             jnp.where(admitted & active, cycle, adm_cycle[q_idx, cur])
+        )
+        adm_order = adm_order.at[q_idx, cur].set(
+            jnp.where(admitted & active, pos_of, adm_order[q_idx, cur])
         )
         # park only NOT_NOMINATED outcomes (NoFit, or preempt search
         # found no victim set — the reserve branch). Heads SKIPPED in
@@ -2272,7 +2322,7 @@ def solve_drain_preempt(
         return (
             local, status, g_start, retries, stuck, no_prog, adm_k,
             adm_cycle, pcells, pqty, pvalid, vevicted, evict_cycle,
-            evict_by, ovf, cycle + 1,
+            evict_by, ovf, cycle + 1, adm_order, fill_cycle, fill_pos,
         )
 
     def cond(state):
@@ -2304,15 +2354,18 @@ def solve_drain_preempt(
         jnp.full((s_dim, v), -1, dtype=jnp.int32),
         jnp.zeros((), dtype=bool),
         jnp.int32(0),
+        jnp.full((q, l), -1, dtype=jnp.int32),
+        jnp.full((s_dim, v), -1, dtype=jnp.int32),
+        jnp.full((s_dim, v), -1, dtype=jnp.int32),
     )
     (local_f, status_f, _, _, stuck_f, _, adm_k, adm_cycle, _, _, _,
-     vevicted, evict_cycle, evict_by, ovf_f, cycles) = lax.while_loop(
-        cond, cycle_body, init
-    )
+     vevicted, evict_cycle, evict_by, ovf_f, cycles, adm_order, _,
+     _) = lax.while_loop(cond, cycle_body, init)
     return PreemptDrainResult(
         status=status_f,
         admitted_k=adm_k,
         admitted_cycle=adm_cycle,
+        admitted_order=adm_order,
         evicted=vevicted,
         evicted_cycle=evict_cycle,
         evicted_by=evict_by,
@@ -2374,6 +2427,7 @@ def solve_drain_fair_preempt(
     prio_tie: bool,
     strategy1: int,
     has_second: bool,
+    ordered_stamps: bool = False,
 ) -> PreemptDrainResult:
     """Multi-cycle drain with FAIR-SHARING admission ordering AND
     fair-sharing preemption, fully on the device — the production
@@ -2453,10 +2507,12 @@ def solve_drain_fair_preempt(
         axis=1,
     )  # [Q, M]
 
+    part_b_q = (victims.sslot_q >= 0)[sq]  # [Q,V]
+
     def cycle_body(state):
         (local, status, g_start, retries, stuck, no_prog, adm_k,
          adm_cycle, pcells, pqty, pq_cu, pvalid, vevicted, evict_cycle,
-         evict_by, cycle) = state
+         evict_by, cycle, adm_order, fill_cycle, fill_pos) = state
 
         # head of each queue = first pending entry in heap order
         entry_pending = status == 0  # [Q,L]
@@ -2530,7 +2586,15 @@ def solve_drain_fair_preempt(
 
         # ---- fair victim tournament, vmapped over heads ----
         enabled1 = active & is_pre & can_search
-        ord_of = victims.perm  # [Q,V] slot ids in candidate order
+        # [Q,V] slot ids in candidate order (see solve_drain_preempt)
+        ord_of = (
+            _recency_order(
+                victims.perm, victims.ptier, part_b_q, fill_cycle[sq],
+                fill_pos[sq],
+            )
+            if ordered_stamps
+            else victims.perm
+        )
 
         def to_ord(x):
             return jnp.take_along_axis(x, ord_of, axis=1)
@@ -2740,6 +2804,9 @@ def solve_drain_fair_preempt(
         )
         admitted = jnp.any(admit_sn, axis=0)  # [Q]
         preempt_ok = jnp.any(pre_ok_sn, axis=0)
+        # a root cohort's admissions, in the order its tournament took
+        # them (one winner per segment and step)
+        pos_of = jnp.argmax(admit_sn, axis=0).astype(jnp.int32)
 
         # ---- cycle end: leaf usage ----
         add = jnp.where(cell_need & admitted[:, None], qty_eff, 0)
@@ -2767,6 +2834,8 @@ def solve_drain_fair_preempt(
         pqty = pqty.at[sq_w2, sl_w].set(mq_w, mode="drop")
         pq_cu = pq_cu.at[sq_w2, sl_w].set(need_qty_cu, mode="drop")
         pvalid = pvalid.at[sq_w2, sl_w].max(fill, mode="drop")
+        fill_cycle = fill_cycle.at[sq_w2, sl_w].set(cycle, mode="drop")
+        fill_pos = fill_pos.at[sq_w2, sl_w].set(pos_of, mode="drop")
 
         # ---- queue motion (as solve_drain_preempt) ----
         adm_k = adm_k.at[q_idx, cur].set(
@@ -2776,6 +2845,9 @@ def solve_drain_fair_preempt(
         )
         adm_cycle = adm_cycle.at[q_idx, cur].set(
             jnp.where(admitted & active, cycle, adm_cycle[q_idx, cur])
+        )
+        adm_order = adm_order.at[q_idx, cur].set(
+            jnp.where(admitted & active, pos_of, adm_order[q_idx, cur])
         )
         pre_skipped = psuccess & ~preempt_ok
         over_budget = retries >= queues.retry_cap
@@ -2833,7 +2905,7 @@ def solve_drain_fair_preempt(
         return (
             local, status, g_start, retries, stuck, no_prog, adm_k,
             adm_cycle, pcells, pqty, pq_cu, pvalid, vevicted, evict_cycle,
-            evict_by, cycle + 1,
+            evict_by, cycle + 1, adm_order, fill_cycle, fill_pos,
         )
 
     def cond(state):
@@ -2865,15 +2937,18 @@ def solve_drain_fair_preempt(
         jnp.full((s_dim, v), -1, dtype=jnp.int32),
         jnp.full((s_dim, v), -1, dtype=jnp.int32),
         jnp.int32(0),
+        jnp.full((q, l), -1, dtype=jnp.int32),
+        jnp.full((s_dim, v), -1, dtype=jnp.int32),
+        jnp.full((s_dim, v), -1, dtype=jnp.int32),
     )
     (local_f, status_f, _, _, stuck_f, _, adm_k, adm_cycle, _, _, _, _,
-     vevicted, evict_cycle, evict_by, cycles) = lax.while_loop(
-        cond, cycle_body, init
-    )
+     vevicted, evict_cycle, evict_by, cycles, adm_order, _,
+     _) = lax.while_loop(cond, cycle_body, init)
     return PreemptDrainResult(
         status=status_f,
         admitted_k=adm_k,
         admitted_cycle=adm_cycle,
+        admitted_order=adm_order,
         evicted=vevicted,
         evicted_cycle=evict_cycle,
         evicted_by=evict_by,
@@ -2890,17 +2965,19 @@ def _solve_drain_fair_preempt_packed(
     tree, local_usage, queues, victims, fair, paths, depth_of, weight,
     lendable, res_of_fr, n_segments: int, n_steps: int, max_cycles: int,
     n_res: int, prio_tie: bool, strategy1: int, has_second: bool,
+    ordered_stamps: bool = False,
 ):
     r = solve_drain_fair_preempt(
         tree, local_usage, queues, victims, fair, paths, depth_of,
         weight, lendable, res_of_fr, n_segments, n_steps, max_cycles,
-        n_res, prio_tie, strategy1, has_second,
+        n_res, prio_tie, strategy1, has_second, ordered_stamps,
     )
     return jnp.concatenate(
         [
             r.status.reshape(-1),
             r.admitted_k.reshape(-1),
             r.admitted_cycle.reshape(-1),
+            r.admitted_order.reshape(-1),
             r.evicted.astype(jnp.int32).reshape(-1),
             r.evicted_cycle.reshape(-1),
             r.evicted_by.reshape(-1),
@@ -2915,7 +2992,7 @@ solve_drain_fair_preempt_packed_jit = jax.jit(
     _solve_drain_fair_preempt_packed,
     static_argnames=(
         "n_segments", "n_steps", "max_cycles", "n_res", "prio_tie",
-        "strategy1", "has_second",
+        "strategy1", "has_second", "ordered_stamps",
     ),
 )
 
@@ -2923,16 +3000,18 @@ solve_drain_fair_preempt_packed_jit = jax.jit(
 def _solve_drain_preempt_packed(
     tree, local_usage, queues, victims, paths,
     n_segments: int, n_steps: int, max_cycles: int, search_width: int,
+    ordered_stamps: bool = False,
 ):
     r = solve_drain_preempt(
         tree, local_usage, queues, victims, paths, n_segments, n_steps,
-        max_cycles, search_width,
+        max_cycles, search_width, ordered_stamps,
     )
     return jnp.concatenate(
         [
             r.status.reshape(-1),
             r.admitted_k.reshape(-1),
             r.admitted_cycle.reshape(-1),
+            r.admitted_order.reshape(-1),
             r.evicted.astype(jnp.int32).reshape(-1),
             r.evicted_cycle.reshape(-1),
             r.evicted_by.reshape(-1),
@@ -2945,32 +3024,44 @@ def _solve_drain_preempt_packed(
 
 solve_drain_preempt_packed_jit = jax.jit(
     _solve_drain_preempt_packed,
-    static_argnames=("n_segments", "n_steps", "max_cycles", "search_width"),
+    static_argnames=(
+        "n_segments", "n_steps", "max_cycles", "search_width",
+        "ordered_stamps",
+    ),
 )
+
+
+def _pack_drain_result(r):
+    """(int32 decision vector, int64 final leaf usage, int32 cycles):
+    admitted_k, admitted_cycle, cursor, stuck and the heads' resumed
+    flavor walks (g_start) flattened into one vector so the host
+    retrieves the whole drain in one fetch. The
+    usage stays its own int64 output: packing it into the same vector
+    makes the partitioner gather an int64 concatenation across a mesh,
+    which the TPU compiler cannot lower."""
+    decisions = jnp.concatenate(
+        [
+            r.admitted_k.reshape(-1),
+            r.admitted_cycle.reshape(-1),
+            r.cursor,
+            r.stuck.astype(jnp.int32),
+            r.g_start.reshape(-1),
+        ]
+    )
+    return decisions, r.local_usage, r.cycles
 
 
 def _solve_drain_packed(
     tree, local_usage, queues, paths, n_segments: int, n_steps: int, max_cycles: int
 ):
-    """solve_drain with the decision tensors flattened into one vector
-    so the host retrieves the whole drain in a single fetch. The final
-    leaf usage rides along (promoting the vector to int64): the
-    pipelined drain loop launches round t+1's solve against it as the
-    speculative post-apply snapshot while the host still applies round
-    t (core/pipeline.py)."""
+    """solve_drain packed for a single fetch (``_pack_drain_result``).
+    The final leaf usage rides along: the pipelined drain loop launches
+    round t+1's solve against it as the speculative post-apply snapshot
+    while the host still applies round t (core/pipeline.py)."""
     r = solve_drain(
         tree, local_usage, queues, paths, n_segments, n_steps, max_cycles
     )
-    return jnp.concatenate(
-        [
-            r.admitted_k.reshape(-1).astype(jnp.int64),
-            r.admitted_cycle.reshape(-1).astype(jnp.int64),
-            r.cursor.astype(jnp.int64),
-            r.stuck.astype(jnp.int64),
-            r.local_usage.reshape(-1),
-            r.cycles[None].astype(jnp.int64),
-        ]
-    )
+    return _pack_drain_result(r)
 
 
 solve_drain_packed_jit = jax.jit(

@@ -54,6 +54,7 @@ class DrainResultNP(NamedTuple):
     cycles: int
     local_usage: np.ndarray  # int64[N,FR]
     stuck: np.ndarray  # bool[Q]
+    g_start: np.ndarray  # int32[Q,P,G] heads' resumed flavor walks
 
 
 def _avail_along_path_np(
@@ -302,6 +303,7 @@ def solve_drain_np(
     paths: np.ndarray,  # int32[N, D+1]
     max_depth: int,
     max_cycles: int,
+    g_start0: np.ndarray = None,  # int32[Q,P,G] walks carried in
 ) -> DrainResultNP:
     """The plain multi-cycle drain on the host, bit-for-bit."""
     subtree, guaranteed = subtree_quota_np(parent, level_mask, nominal, lending)
@@ -322,7 +324,10 @@ def solve_drain_np(
 
     local = local_usage.copy()
     cursor = np.zeros(q, dtype=np.int32)
-    g_start = np.zeros((q, pmax, g), dtype=np.int32)
+    g_start = (
+        np.zeros((q, pmax, g), dtype=np.int32)
+        if g_start0 is None else g_start0.astype(np.int32)
+    )
     retries = np.zeros(q, dtype=np.int32)
     stuck = np.zeros(q, dtype=bool)
     no_prog = 0
@@ -419,7 +424,7 @@ def solve_drain_np(
         lost = active & is_fit & (~admitted)
         g_start = np.where(
             advance[:, None, None],
-            0,
+            -1,
             np.where((lost | retrying)[:, None, None], walk_next, g_start),
         ).astype(np.int32)
         cursor = cursor + advance.astype(np.int32)
@@ -432,4 +437,5 @@ def solve_drain_np(
         cycles=cycle,
         local_usage=local,
         stuck=stuck,
+        g_start=g_start,
     )

@@ -1,9 +1,9 @@
 """Device-resident admission megaloop — K drain rounds in ONE launch.
 
 The pipelined drain loop (core/pipeline.py) still pays one host↔device
-round trip per drain round: BENCH_r05 measured a ~138 ms fixed dispatch
-floor on a remote-attached TPU, and even double-buffering overlaps the
-host apply with only ONE in-flight launch. This kernel fuses K
+round trip per drain round (a fixed dispatch floor per launch), and
+even double-buffering overlaps the host apply with only ONE in-flight
+launch. This kernel fuses K
 consecutive rounds into a single dispatch: an outer ``lax.while_loop``
 (bounded at ``max_rounds`` or quiescence) over the SAME per-cycle body
 ``solve_drain`` runs (ops/drain_kernel._plain_cycle — one definition,
@@ -65,6 +65,10 @@ class MegaloopResult(NamedTuple):
                     speculative post-apply snapshot of the NEXT round
                     (the host's conflict check compares the real
                     post-apply state against it);
+    round_gstart:   int32[R,Q,P,G] each head's flavor walk at each
+                    round's end, carried into the next round as a
+                    re-plan would resume it from the head's
+                    LastAssignment;
     rounds:         int32 scalar — rounds actually executed;
     cycles:         int32 scalar — total kernel cycles."""
 
@@ -75,6 +79,7 @@ class MegaloopResult(NamedTuple):
     round_stuck: jnp.ndarray
     round_cycles: jnp.ndarray
     round_usage: jnp.ndarray
+    round_gstart: jnp.ndarray
     rounds: jnp.ndarray
     cycles: jnp.ndarray
 
@@ -113,7 +118,7 @@ def solve_drain_megaloop(
     def body(state):
         (local, cursor, g_start, retries, stuck, no_prog, adm_k,
          adm_cycle, adm_round, alive, cap_eff, round_idx, round_cycle,
-         r_cursor, r_stuck, r_cycles, r_usage, cycle) = state
+         r_cursor, r_stuck, r_cycles, r_usage, r_gstart, cycle) = state
 
         # one plain drain cycle, bit-for-bit solve_drain's, with the
         # per-round dynamic retry budget and the retired-queue mask
@@ -149,12 +154,15 @@ def solve_drain_megaloop(
         r_usage = r_usage.at[ri].set(
             jnp.where(boundary, local, r_usage[ri])
         )
+        r_gstart = r_gstart.at[ri].set(
+            jnp.where(boundary, g_start, r_gstart[ri])
+        )
         # a queue stuck at the boundary retires: the serial loop
         # reports its unprocessed entries as fallback and never feeds
         # them to the next round's launch
         alive = jnp.where(boundary, alive & ~stuck, alive)
-        # fresh-plan walk state for the next round
-        g_start = jnp.where(boundary, 0, g_start)
+        # fresh-plan state for the next round; the heads' flavor walks
+        # carry over, as the re-plan resumes them from LastAssignment
         retries = jnp.where(boundary, 0, retries)
         no_prog = jnp.where(boundary, 0, no_prog)
         stuck = jnp.where(boundary, jnp.zeros_like(stuck), stuck)
@@ -164,11 +172,12 @@ def solve_drain_megaloop(
 
         return (local, cursor, g_start, retries, stuck, no_prog, adm_k,
                 adm_cycle, adm_round, alive, cap_eff, round_idx,
-                round_cycle, r_cursor, r_stuck, r_cycles, r_usage, cycle)
+                round_cycle, r_cursor, r_stuck, r_cycles, r_usage, r_gstart,
+                cycle)
 
     def cond(state):
         (_, cursor, _, _, stuck, _, _, _, _, alive, _, round_idx, _,
-         _, _, _, _, _) = state
+         _, _, _, _, _, _) = state
         more = jnp.any((cursor < queues.qlen) & ~stuck & alive)
         return more & (round_idx < max_rounds)
 
@@ -191,12 +200,12 @@ def solve_drain_megaloop(
         jnp.zeros((max_rounds, q), dtype=bool),
         jnp.zeros(max_rounds, dtype=jnp.int32),
         jnp.zeros((max_rounds, n, fr), dtype=jnp.int64),
+        jnp.zeros((max_rounds, q, pmax, g), dtype=jnp.int32),
         jnp.int32(0),
     )
     (local_f, cursor_f, _, _, _, _, adm_k, adm_cycle, adm_round, _, _,
-     rounds_f, _, r_cursor, r_stuck, r_cycles, r_usage, cycles_f) = (
-        lax.while_loop(cond, body, init)
-    )
+     rounds_f, _, r_cursor, r_stuck, r_cycles, r_usage, r_gstart,
+     cycles_f) = lax.while_loop(cond, body, init)
     return MegaloopResult(
         admitted_k=adm_k,
         admitted_cycle=adm_cycle,
@@ -205,6 +214,7 @@ def solve_drain_megaloop(
         round_stuck=r_stuck,
         round_cycles=r_cycles,
         round_usage=r_usage,
+        round_gstart=r_gstart,
         rounds=rounds_f,
         cycles=cycles_f,
     )
@@ -214,26 +224,29 @@ def _solve_drain_megaloop_packed(
     tree, local_usage, queues, paths, cap_suffix,
     n_segments: int, n_steps: int, chunk_cycles: int, max_rounds: int,
 ):
-    """solve_drain_megaloop with the whole round-stamped log flattened
-    into ONE int64 vector — K rounds of decisions retrieved in a single
-    fetch (the entire point of the fusion)."""
+    """solve_drain_megaloop with the whole round-stamped log packed for
+    ONE fetch — K rounds of decisions retrieved together (the entire
+    point of the fusion): an int32 vector of the decisions and counts,
+    and the int64 per-round usage as its own output (an int64
+    concatenation gathered across a mesh does not lower on the TPU)."""
     r = solve_drain_megaloop(
         tree, local_usage, queues, paths, cap_suffix,
         n_segments, n_steps, chunk_cycles, max_rounds,
     )
-    return jnp.concatenate(
+    log = jnp.concatenate(
         [
-            r.admitted_k.reshape(-1).astype(jnp.int64),
-            r.admitted_cycle.reshape(-1).astype(jnp.int64),
-            r.admitted_round.reshape(-1).astype(jnp.int64),
-            r.round_cursor.reshape(-1).astype(jnp.int64),
-            r.round_stuck.reshape(-1).astype(jnp.int64),
-            r.round_cycles.reshape(-1).astype(jnp.int64),
-            r.round_usage.reshape(-1),
-            r.rounds[None].astype(jnp.int64),
-            r.cycles[None].astype(jnp.int64),
+            r.admitted_k.reshape(-1).astype(jnp.int32),
+            r.admitted_cycle.reshape(-1).astype(jnp.int32),
+            r.admitted_round.reshape(-1).astype(jnp.int32),
+            r.round_cursor.reshape(-1).astype(jnp.int32),
+            r.round_stuck.reshape(-1).astype(jnp.int32),
+            r.round_cycles.reshape(-1).astype(jnp.int32),
+            r.round_gstart.reshape(-1),
+            r.rounds[None].astype(jnp.int32),
+            r.cycles[None].astype(jnp.int32),
         ]
     )
+    return log, r.round_usage
 
 
 solve_drain_megaloop_packed_jit = jax.jit(

@@ -5,7 +5,8 @@ the serial chunked drain — one ``solve_drain_np`` call per round over
 queue tensors suffix-trimmed to exactly what a fresh host re-plan over
 the round's undecided backlog would ship (entries repacked from the
 previous round's cursor, stuck queues dropped, per-queue retry budgets
-re-derived from the remaining suffix). Kernel-vs-mirror parity
+re-derived from the remaining suffix, each head's flavor walk resumed
+from where the previous round left it). Kernel-vs-mirror parity
 (tests/test_megaloop.py) is therefore a direct machine-checked proof of
 the megaloop's load-bearing claim: K fused rounds decide bit-for-bit
 what K serial launches would have decided, round stamps, in-round cycle
@@ -54,6 +55,7 @@ class MegaloopResultNP(NamedTuple):
     round_stuck: np.ndarray  # bool[R,Q]
     round_cycles: np.ndarray  # int32[R]
     round_usage: np.ndarray  # int64[R,N,FR]
+    round_gstart: np.ndarray  # int32[R,Q,P,G]
     rounds: int
     cycles: int
 
@@ -131,6 +133,9 @@ def solve_megaloop_np(
     r_stuck = np.zeros((max_rounds, q), dtype=bool)
     r_cycles = np.zeros(max_rounds, dtype=np.int32)
     r_usage = np.zeros((max_rounds, n, fr), dtype=np.int64)
+    g = queues_np["gidx"].shape[-1]
+    g_start = np.zeros((q, pmax, g), dtype=np.int32)
+    r_gstart = np.zeros((max_rounds, q, pmax, g), dtype=np.int32)
 
     rounds = 0
     cycles = 0
@@ -138,7 +143,7 @@ def solve_megaloop_np(
         trimmed = _trim_queues(queues_np, cursor, dead, cap_suffix)
         res = solve_drain_np(
             parent, level_mask, nominal, lending, borrowing, local,
-            trimmed, paths, max_depth, chunk_cycles,
+            trimmed, paths, max_depth, chunk_cycles, g_start0=g_start,
         )
         for qi in range(q):
             start = int(cursor[qi])
@@ -156,6 +161,8 @@ def solve_megaloop_np(
         r_stuck[rounds] = res.stuck | dead
         r_cycles[rounds] = res.cycles
         r_usage[rounds] = local
+        g_start = res.g_start
+        r_gstart[rounds] = g_start
         dead = dead | res.stuck
         cycles += int(res.cycles)
         rounds += 1
@@ -168,6 +175,7 @@ def solve_megaloop_np(
         round_stuck=r_stuck,
         round_cycles=r_cycles,
         round_usage=r_usage,
+        round_gstart=r_gstart,
         rounds=rounds,
         cycles=cycles,
     )

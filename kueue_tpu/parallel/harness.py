@@ -5,9 +5,9 @@ place every drain-family kernel (plain / contended-preempt / fair /
 TAS) routes its mesh concerns through:
 
   - **mesh resolution** (``resolve_mesh``): the server's ``--mesh
-    auto|N|off`` spec -> a ``jax.sharding.Mesh`` (or None when the
-    machine has fewer than 2 devices — sharding a 1-device "mesh"
-    would only add partitioner overhead);
+    auto|N|off`` spec -> a ``jax.sharding.Mesh`` (None for ``auto`` on
+    a one-device machine — sharding a 1-device "mesh" would only add
+    partitioner overhead; an explicit N the machine lacks raises);
   - **size-bucketed jit-cache accounting** (``note_bucket``): every
     sharded solve registers its (kernel, padded static shapes, mesh)
     key — exactly the tuple ``jax.jit`` caches executables on — so the
@@ -74,20 +74,25 @@ SHARDED_KERNELS = {
 def resolve_mesh(spec, fr_parallel: bool = False):
     """Operator spec -> Mesh or None.
 
-    ``None``/``"off"``/``""`` -> None; ``"auto"`` -> all local devices;
-    ``N`` (int or digit string) -> the first N devices. Any resolution
-    with fewer than 2 devices returns None — a 1-device mesh buys
-    nothing and pays the partitioner."""
+    ``None``/``"off"``/``""`` -> None. ``"auto"`` -> all local devices,
+    or None (single-device) on a host with one: a 1-device mesh buys
+    nothing and pays the partitioner. ``N`` (int or digit string) -> the
+    first N devices; N < 2 is single-device, and an N the host cannot
+    supply raises ValueError instead of quietly running on fewer."""
     if spec is None or spec in ("off", ""):
         return None
     from kueue_tpu._jax import jax
     from kueue_tpu.parallel.sharded_solver import make_mesh
 
+    have = len(jax.devices())
     if spec == "auto":
-        n = len(jax.devices())
+        n = have
     else:
         n = int(spec)
-        n = min(n, len(jax.devices()))
+        if n > have:
+            raise ValueError(
+                f"mesh of {n} devices requested; this host has {have}"
+            )
     if n < 2:
         return None
     return make_mesh(n, fr_parallel=fr_parallel)

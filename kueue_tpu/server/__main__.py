@@ -319,9 +319,9 @@ def main(argv=None) -> int:
         "auto = all local devices, N = the first N, off = "
         "single-device (the default). Composes with --pipeline "
         "(prefetched launches ride the same sharded path) and with "
-        "the solver guard (host mirrors are mesh-agnostic). Falls "
-        "back to single-device when fewer than 2 devices resolve; "
-        "multi-host meshes need jax.distributed.initialize() before "
+        "the solver guard (host mirrors are mesh-agnostic). auto on "
+        "a one-device host stays single-device; an N the host cannot "
+        "supply refuses to start. Multi-host meshes need jax.distributed.initialize() before "
         "startup (deploy/README 'Multi-chip admission')",
     )
     parser.add_argument(
@@ -614,11 +614,13 @@ def main(argv=None) -> int:
     if args.mesh and args.mesh != "off":
         from kueue_tpu.parallel import mesh_shape_str, resolve_mesh
 
-        mesh = resolve_mesh(args.mesh)
+        try:
+            mesh = resolve_mesh(args.mesh)
+        except ValueError as e:
+            parser.error(f"--mesh {args.mesh}: {e}")
         if mesh is None:
             print(
-                f"--mesh {args.mesh}: fewer than 2 devices resolve; "
-                "running single-device",
+                f"--mesh {args.mesh}: one device; running single-device",
                 flush=True,
             )
         else:
@@ -1085,4 +1087,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from kueue_tpu._jax import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())

@@ -6,11 +6,19 @@ import time
 
 
 class Clock:
+    # this clock runs with the wall: time passes during a blocking call
+    # (the solver guard takes XLA compiles out of its deadlines) and
+    # between two admissions (the preempt drain ranks its admissions as
+    # the host cycle loop stamps them)
+    tracks_wall = True
+
     def now(self) -> float:
         return time.time()
 
 
 class FakeClock(Clock):
+    tracks_wall = False
+
     def __init__(self, start: float = 0.0):
         self._now = start
 

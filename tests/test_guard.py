@@ -765,3 +765,75 @@ class TestQuarantineList:
         q2 = QuarantineList()
         q2.restore(**d)
         assert q2.get("a").until == q.get("a").until
+
+
+# ---- XLA compile time and the device deadline ----
+class TestCompileOutsideDeadline:
+    """On a running clock the guard takes XLA compile seconds out of
+    its device deadline, and nothing else: a cold launch whose compile
+    alone outlasts the deadline is kept, a warm launch that runs past
+    it is still a failure."""
+
+    DEADLINE_S = 0.05
+
+    @staticmethod
+    def _lowered(n):
+        """A jit lowered (outside the window) for a shape no other test
+        compiles, slow enough to compile that it outlasts DEADLINE_S."""
+        from jax import lax
+
+        from kueue_tpu._jax import jax, jnp
+
+        def f(x):
+            def body(i, x):
+                return jnp.sin(x @ x.T) * 0.5 + x
+
+            return lax.fori_loop(0, 600, body, x, unroll=True)
+
+        x = jnp.ones((n, n), jnp.float32)
+        return jax.jit(f).lower(x), x
+
+    @staticmethod
+    def _guarded(guard, fn, path):
+        if path == "call":
+            return guard.device_call(fn, label="t")
+        return guard.device_join(
+            guard.device_launch(fn, label="t"), lambda h: np.asarray(h)
+        )
+
+    @pytest.mark.parametrize("path, n", [("call", 5), ("launch", 7)])
+    def test_cold_compile_is_not_counted(self, path, n):
+        from kueue_tpu.core.guard import SolverGuard, compile_seconds
+        from kueue_tpu.utils.clock import Clock
+
+        guard = SolverGuard(
+            clock=Clock(), config=GuardConfig(device_deadline_s=self.DEADLINE_S)
+        )
+        lowered, x = self._lowered(n)
+        c0 = compile_seconds()
+        out = self._guarded(guard, lambda: lowered.compile()(x), path)
+        compiled_s = compile_seconds() - c0
+        assert compiled_s > self.DEADLINE_S  # counted, this would fail
+        assert out.result is not None
+        assert guard.failovers == 0 and guard.device_solves == 1
+
+    @pytest.mark.parametrize("path, n", [("call", 6), ("launch", 9)])
+    def test_slow_warm_launch_still_fails(self, path, n):
+        import time
+
+        from kueue_tpu.core.guard import SolverGuard
+        from kueue_tpu.utils.clock import Clock
+
+        guard = SolverGuard(
+            clock=Clock(), config=GuardConfig(device_deadline_s=self.DEADLINE_S)
+        )
+        lowered, x = self._lowered(n)
+        compiled = lowered.compile()
+
+        def slow():
+            time.sleep(3 * self.DEADLINE_S)
+            return compiled(x)
+
+        out = self._guarded(guard, slow, path)
+        assert out.result is None
+        assert guard.failovers == 1 and guard.device_solves == 0

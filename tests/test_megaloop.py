@@ -77,7 +77,24 @@ def _kernel_env(spec):
 def _round_view(outcome):
     sig = outcome_signature(outcome)
     sig["undecided"] = [(wl.key, cq) for wl, cq in outcome.undecided]
+    sig["resume"] = [(wl.key, g.tolist()) for wl, _, g in outcome.resume]
     return sig
+
+
+def _resume_walks(snapshot, outcome):
+    """What the runtime does between serial rounds: each nominated,
+    undecided head keeps its flavor walk as its LastAssignment."""
+    from kueue_tpu.core.solver import resumed_assignment
+
+    for wl, cq, walks in outcome.resume:
+        wl.last_assignment = (
+            resumed_assignment(
+                snapshot.cq_models[cq], wl, walks,
+                snapshot.generations.get(cq, 0),
+            )
+            if walks.max() > 0
+            else None
+        )
 
 
 class TestKernelSerialEquivalence:
@@ -104,6 +121,7 @@ class TestKernelSerialEquivalence:
             ), r
             if not serial.undecided:
                 break
+            _resume_walks(s, serial)
             s = speculative_snapshot(s, serial.final_usage)
             p = serial.undecided
 
@@ -112,8 +130,8 @@ class TestKernelSerialEquivalence:
         """KERNEL_MIRRORS parity: the device log equals the numpy
         mirror's — and the mirror IS the serial loop over trimmed
         tensors, so this is the serial==megaloop proof at the tensor
-        level (multi-flavor specs exercise the per-round g_start /
-        retry-budget resets)."""
+        level (multi-flavor specs exercise the flavor walks carried
+        across rounds and the per-round retry-budget resets)."""
         snapshot, pending, flavors, ts_fn = _kernel_env(
             random_spec(seed, workloads_per_cq=8)
         )
@@ -169,6 +187,7 @@ class TestKernelSerialEquivalence:
             assert _round_view(serial) == _round_view(round_out), r
             if not serial.undecided:
                 break
+            _resume_walks(s, serial)
             s = speculative_snapshot(s, serial.final_usage)
             p = serial.undecided
 

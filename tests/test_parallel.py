@@ -46,6 +46,16 @@ def test_padding_to_axis_multiple():
     assert not np.asarray(got.admitted)[13:].any()
 
 
+def test_resolve_mesh_refuses_devices_the_host_lacks():
+    from kueue_tpu.parallel import resolve_mesh
+
+    assert resolve_mesh("auto").size == jax.device_count() == 8
+    with pytest.raises(ValueError, match="16 devices requested"):
+        resolve_mesh(16)
+    with pytest.raises(ValueError, match="16 devices requested"):
+        resolve_mesh("16")
+
+
 def test_mesh_shapes():
     assert make_mesh(8).axis_names == ("wl",)
     assert make_mesh(8, fr_parallel=True).axis_names == ("wl", "fr")

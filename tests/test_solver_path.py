@@ -452,7 +452,7 @@ class TestDeviceResolution:
         sched, _, _, _ = build_env(spec, use_solver=None)
         sched.solver_threshold = 4
         sched._host_assign_ema = 1e-4
-        sched._device_dispatch_est.observe(0.05)  # 50 ms tunnel dispatch
+        sched._device_dispatch_est.observe(0.05)  # 50 ms device dispatch
         assert not sched._solver_enabled(10)  # 1 ms host < 50 ms device
         assert sched._solver_enabled(10_000)  # 1 s host > 50 ms device
         # no measurement yet -> probe the device once
