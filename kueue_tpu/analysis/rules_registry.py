@@ -94,44 +94,55 @@ class SpanNameRule(Rule):
     name = "span-name"
     description = (
         "literal span names at recording call sites must belong to "
-        "tracing.names.SPAN_NAMES"
+        "tracing.names.SPAN_NAMES, and literal Phase(...) names to "
+        "tracing.names.PHASE_NAMES"
     )
 
     _CALL_ATTRS = {
         "add_cycle_span", "add_workload_span", "record_span", "_trace_span",
     }
+    _PHASE = "Phase"
 
-    def _names(self, ctx: AnalysisContext) -> Set[str]:
-        names = ctx.config.get("span_names")
+    def _names(self, ctx: AnalysisContext, key: str) -> Set[str]:
+        names = ctx.config.get(key)
         if names is None:
-            from kueue_tpu.tracing.names import SPAN_NAMES
+            from kueue_tpu.tracing import names as registry
 
-            names = SPAN_NAMES
+            names = (
+                registry.SPAN_NAMES if key == "span_names"
+                else registry.PHASE_NAMES
+            )
         return set(names)
 
     def check(self, src: SourceFile, ctx: AnalysisContext) -> List[Finding]:
-        names = self._names(ctx)
+        registries = {
+            "SPAN_NAMES": self._names(ctx, "span_names"),
+            "PHASE_NAMES": self._names(ctx, "phase_names"),
+        }
         findings: List[Finding] = []
         matched = ctx.config.setdefault("_span_sites", [])
         for node in ast.walk(src.tree):
             if not isinstance(node, ast.Call):
                 continue
             fn = node.func
-            if not (
-                isinstance(fn, ast.Attribute)
-                and fn.attr in self._CALL_ATTRS
-            ):
+            if isinstance(fn, ast.Attribute) and fn.attr in self._CALL_ATTRS:
+                registry = "SPAN_NAMES"
+            elif (
+                isinstance(fn, ast.Name) and fn.id == self._PHASE
+            ) or (isinstance(fn, ast.Attribute) and fn.attr == self._PHASE):
+                registry = "PHASE_NAMES"
+            else:
                 continue
             s = _first_str_arg(node)
             if s is None or not _SPANISH.match(s):
                 continue
             matched.append(s)
-            if s not in names:
+            if s not in registries[registry]:
                 findings.append(
                     Finding(
                         self.name, src.rel, node.lineno,
                         f"ad-hoc span name {s!r} — add it to "
-                        "SPAN_NAMES or fix the call site",
+                        f"{registry} or fix the call site",
                     )
                 )
         return findings

@@ -32,9 +32,19 @@ from kueue_tpu.controllers.workload_controller import (
     WaitForPodsReadyConfig,
     WorkloadReconciler,
 )
+from kueue_tpu.tracing.phases import Phase, PhaseTotals, phase
 from kueue_tpu.utils.clock import Clock
 
 __all__ = ["ClusterRuntime", "Event"]
+
+# the runtime loop's phases and the drain's (tracing/phases.py)
+_FINGERPRINT = Phase("loop.fingerprint")
+_RECONCILE = Phase("loop.reconcile")
+_DRAIN_GATE = Phase("loop.drain_gate")
+_REPORT = Phase("loop.report")
+_CLASSIFY = Phase("cycle.classify")
+_SOLVE = Phase("cycle.solve")
+_APPLY = Phase("cycle.apply")
 
 
 def _parse_megaloop(spec) -> tuple:
@@ -109,7 +119,7 @@ class ClusterRuntime:
         mesh=None,
         # Distributed tracing (kueue_tpu/tracing): always-on span
         # subsystem — workload lifecycle traces + per-cycle span trees.
-        # False = no-op tracer (the bench.py --trace baseline).
+        # False = no-op tracer (the untraced baseline).
         tracing: bool = True,
         # Admission policy (kueue_tpu/policy): a registered policy name
         # ("first-fit" | "gavel" | "prema" | "deadline" |
@@ -146,6 +156,9 @@ class ClusterRuntime:
         self.tracer = Tracer(
             clock=self.clock, metrics=self.metrics, enabled=tracing
         )
+        # cumulative seconds of the loop around the cycles and of the
+        # server's batch apply (phase_totals; /debug/cycles)
+        self.phases = PhaseTotals()
         # per-workload decision audit trail (core/audit.py): every
         # admission decision — host cycle, device cycle, bulk drain —
         # lands here; served at /debug/workloads/<ns>/<name>/decisions
@@ -273,6 +286,7 @@ class ClusterRuntime:
             policy=self.policy,
         )
         self.scheduler.on_quarantine = self._on_workload_quarantined
+        self.scheduler.phases = self.phases
         if self.scheduler.preemptor is not None:
             self.scheduler.preemptor.policy = self.policy
         self._report_policy_metrics()
@@ -1330,7 +1344,8 @@ class ClusterRuntime:
 
         t0 = time.perf_counter()
         result = self.scheduler.schedule()
-        self._report_cycle_metrics(result, time.perf_counter() - t0)
+        with phase(_REPORT, self.phases):
+            self._report_cycle_metrics(result, time.perf_counter() - t0)
         return result
 
     def run_until_idle(self, max_iterations: int = 50) -> int:
@@ -1344,16 +1359,34 @@ class ClusterRuntime:
         parked entries below threshold — run through the normal cycle.
         """
         cycles = 0
+        totals = self.phases
         for _ in range(max_iterations):
-            before = self._state_fingerprint()
-            self.reconcile_once()
-            if self.bulk_drain() is None:
+            totals.add_count("loop.iterations")
+            with phase(_FINGERPRINT, totals):
+                before = self._state_fingerprint()
+            with phase(_RECONCILE, totals):
+                self.reconcile_once()
+            with phase(_DRAIN_GATE) as gate:
+                drained = self.bulk_drain()
+            if drained is None:
+                # only a call that decided nothing is gate time: a drain
+                # that ran is its own cycle trace
+                totals.add_phase(_DRAIN_GATE, gate.seconds)
                 self.schedule_once()
-            self.reconcile_once()
+            with phase(_RECONCILE, totals):
+                self.reconcile_once()
             cycles += 1
-            if self._state_fingerprint() == before:
+            with phase(_FINGERPRINT, totals):
+                after = self._state_fingerprint()
+            if after == before:
                 break
         return cycles
+
+    def phase_totals(self) -> dict:
+        """Cumulative milliseconds of the runtime's own phases (the loop
+        around the cycles, the server's batch apply) and their counts —
+        ``PhaseTotals.snapshot``. Readers difference two snapshots."""
+        return self.phases.snapshot()
 
     # ---- the bulk path: device drains as the service (north star) ----
     def drain_backlog(self, snapshot):
@@ -1447,11 +1480,11 @@ class ClusterRuntime:
             if self.cache.tas_cache is not None
             else set()
         )
-        t1 = _time.perf_counter()
-        kind, pending = classify_drain_scope(
-            snapshot, pending, tas_flavors, sched.fair_sharing
-        )
-        t_classify = _time.perf_counter() - t1
+        with phase(_CLASSIFY) as classify:
+            kind, pending = classify_drain_scope(
+                snapshot, pending, tas_flavors, sched.fair_sharing
+            )
+        t_classify = classify.seconds
         sched.guard.phase_checkpoint("drain.classify")
         if len(pending) < self.bulk_drain_threshold:
             return None  # TAS heads dropped to the cycle loop shrank it
@@ -1472,31 +1505,35 @@ class ClusterRuntime:
                 snapshot, pending, ts_fn, t_snapshot, t_classify,
                 prefetch=self.drain_pipeline == "on",
             )
-        t1 = _time.perf_counter()
         # the drain launch runs under the same guard as the cycle
         # dispatch: a raising or deadline-late solve is contained,
         # strikes the breaker, and this iteration's backlog falls back
         # to the per-head cycle loop instead of a crashed drain
-        guarded = sched.guard.device_call(
-            lambda: run_drain_for_scope(
-                kind, snapshot, pending, self.cache.flavors,
-                tas_cache=self.cache.tas_cache,
-                fs_strategies=getattr(sched.preemptor, "fs_strategies", None),
-                timestamp_fn=ts_fn,
-                mesh=self.mesh,
-                policy=self.policy,
-                now=self.clock.now(),
-                # a running clock stamps the host loop's admissions one
-                # after another; the drain must rank its own the same way
-                ordered_stamps=getattr(self.clock, "tracks_wall", False),
-            ),
-            label="bulk drain",
-        )
+        with phase(_SOLVE) as solve:
+            guarded = sched.guard.device_call(
+                lambda: run_drain_for_scope(
+                    kind, snapshot, pending, self.cache.flavors,
+                    tas_cache=self.cache.tas_cache,
+                    fs_strategies=getattr(
+                        sched.preemptor, "fs_strategies", None
+                    ),
+                    timestamp_fn=ts_fn,
+                    mesh=self.mesh,
+                    policy=self.policy,
+                    now=self.clock.now(),
+                    # a running clock stamps the host loop's admissions
+                    # one after another; the drain must rank its own the
+                    # same way
+                    ordered_stamps=getattr(self.clock, "tracks_wall", False),
+                ),
+                label="bulk drain",
+            )
+            if guarded.result is not None:
+                self._resume_walks(guarded.result)
         if guarded.result is None:
             return None
         outcome = guarded.result
-        self._resume_walks(outcome)
-        t_solve = _time.perf_counter() - t1
+        t_solve = solve.seconds
         sched.guard.phase_checkpoint("drain.solve", device_used=True)
         from kueue_tpu.testing import faults
 
@@ -1516,7 +1553,6 @@ class ClusterRuntime:
             # or run_until_idle would see an unchanged fingerprint and
             # stop with the whole backlog still pending
             return None
-        t1 = _time.perf_counter()
         # the drain IS this iteration's cycle: number it before the
         # apply so its decision records carry the right cycle id — and
         # open its span-tree buffer so those records (and any guard/
@@ -1524,7 +1560,8 @@ class ClusterRuntime:
         sched.scheduling_cycle += 1
         sched.tracer.next_cycle(sched.scheduling_cycle)
         try:
-            result = self._apply_drain_outcome(outcome, snapshot)
+            with phase(_APPLY) as apply:
+                result = self._apply_drain_outcome(outcome, snapshot)
         except faults.InjectedCrash:
             raise  # simulated power loss: the recovery chaos suite's window
         except Exception as exc:  # noqa: BLE001 — contained apply: the
@@ -1533,7 +1570,7 @@ class ClusterRuntime:
             sched.guard.note_contained_cycle(exc)
             sched.tracer.discard_cycle()
             return None
-        t_apply = _time.perf_counter() - t1
+        t_apply = apply.seconds
         sched.guard.phase_checkpoint("drain.apply", device_used=True)
         self._note_mesh_metrics()
         dt = _time.perf_counter() - t0
@@ -1802,7 +1839,6 @@ class ClusterRuntime:
                     break
 
                 sched.guard.begin_cycle()
-                t1 = _time.perf_counter()
                 sched.scheduling_cycle += 1
                 sched.tracer.next_cycle(sched.scheduling_cycle)
                 if committed == 0:
@@ -1814,17 +1850,18 @@ class ClusterRuntime:
                         t_solve,
                         attrs={"k": k, "rounds": len(log.rounds)},
                     )
-                try:
-                    result = self._apply_drain_outcome(outcome, snapshot)
-                except faults.InjectedCrash:
-                    raise  # simulated power loss: the chaos window
-                except Exception as exc:  # noqa: BLE001 — contained
-                    sched.guard.note_contained_cycle(exc)
-                    sched.tracer.discard_cycle()
-                    _set_inflight(0)
-                    return last_result
-                self._resume_walks(outcome)
-                t_apply = _time.perf_counter() - t1
+                with phase(_APPLY) as apply:
+                    try:
+                        result = self._apply_drain_outcome(outcome, snapshot)
+                    except faults.InjectedCrash:
+                        raise  # simulated power loss: the chaos window
+                    except Exception as exc:  # noqa: BLE001 — contained
+                        sched.guard.note_contained_cycle(exc)
+                        sched.tracer.discard_cycle()
+                        _set_inflight(0)
+                        return last_result
+                    self._resume_walks(outcome)
+                t_apply = apply.seconds
                 pstats.note_apply(t_apply, overlapped=pf is not None)
                 self.metrics.pipeline_overlap_ratio.set(
                     pstats.overlap_ratio
@@ -2040,9 +2077,9 @@ class ClusterRuntime:
         rounds = 0
         while True:
             rounds += 1
-            t1 = _time.perf_counter()
-            out_g = sched.guard.device_join(glaunch, lambda h: h.fetch())
-            t_solve = t_dispatch + (_time.perf_counter() - t1)
+            with phase(_SOLVE) as join:
+                out_g = sched.guard.device_join(glaunch, lambda h: h.fetch())
+            t_solve = t_dispatch + join.seconds
             stats.note_solve(t_solve)
             _set_inflight(0)
             if out_g.result is None:
@@ -2111,7 +2148,6 @@ class ClusterRuntime:
 
             # ---- apply round t (the overlapped host stage) ----
             sched.guard.begin_cycle()
-            t1 = _time.perf_counter()
             sched.scheduling_cycle += 1
             # the round's span-tree buffer: decision records from the
             # apply, discard markers and guard/journal spans land here;
@@ -2119,7 +2155,8 @@ class ClusterRuntime:
             # crash at any fault point in between drops it whole
             sched.tracer.next_cycle(sched.scheduling_cycle)
             try:
-                result = self._apply_drain_outcome(outcome, snapshot)
+                with phase(_APPLY) as apply:
+                    result = self._apply_drain_outcome(outcome, snapshot)
             except faults.InjectedCrash:
                 raise  # simulated power loss: the chaos suite's window
             except Exception as exc:  # noqa: BLE001 — contained apply
@@ -2127,7 +2164,7 @@ class ClusterRuntime:
                 sched.tracer.discard_cycle()
                 _set_inflight(0)
                 return last_result
-            t_apply = _time.perf_counter() - t1
+            t_apply = apply.seconds
             stats.note_apply(t_apply, overlapped=pf is not None)
             self.metrics.pipeline_overlap_ratio.set(stats.overlap_ratio)
             sched.guard.phase_checkpoint("drain.apply", device_used=True)

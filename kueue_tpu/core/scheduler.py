@@ -41,8 +41,25 @@ from kueue_tpu.core.flavor_assigner import (
 )
 from kueue_tpu.core.queue_manager import QueueManager, RequeueReason, queue_order_timestamp
 from kueue_tpu.core.snapshot import Snapshot, WorkloadSnapshot, take_snapshot
+from kueue_tpu.tracing.names import CYCLE_PHASE_SPANS
+from kueue_tpu.tracing.phases import Phase, PhaseTotals, phase
 from kueue_tpu.utils.clock import Clock
 from kueue_tpu.utils.priority import priority_of
+
+# the cycle's phases and sub-phases (tracing/phases.py)
+_HEADS = Phase("cycle.heads")
+_SNAPSHOT = Phase("cycle.snapshot")
+_NOMINATE = Phase("cycle.nominate")
+_ADMIT = Phase("cycle.admit")
+_PREVALIDATE = Phase("cycle.prevalidate", counter="prevalidated")
+_ASSIGN = Phase("cycle.assign", counter="walked")
+_VICTIMS = Phase("cycle.victims", counter="deferred")
+_ENCODE = Phase("cycle.encode", counter="lowered")
+_DECODE = Phase("cycle.decode")
+_PARK = Phase("cycle.park", counter="parked")
+# after the cycle's span tree is flushed: its decision audit and the
+# cycle observers
+_AUDIT = Phase("loop.audit")
 
 
 class EntryStatus(str, Enum):
@@ -131,7 +148,15 @@ class CycleTrace:
     dumps, pkg/scheduler/logging.go; the scalability harness' CPU
     profiles). Kept in Scheduler.last_traces (ring buffer), observed
     into the phase-duration histogram by the runtime, dumped by the
-    debugger and served at /debug/cycles."""
+    debugger and served at /debug/cycles.
+
+    ``resolution`` says who decided the cycle's admissions: "device"
+    (the device's phase-2 scan, replayed), "host-mirror" (the guard's
+    numpy twin) or "host" (the host admit loop), and "drain" for a bulk
+    drain round. It does not say whether the cycle launched a device
+    solve: a cycle whose heads all come back from the kernel non-Fit is
+    decided by the host loop and reads "host" with ``counts["launches"]``
+    at 1."""
 
     cycle: int = 0
     heads: int = 0
@@ -142,6 +167,16 @@ class CycleTrace:
     # phase -> seconds: snapshot / nominate / admit on the cycle path,
     # snapshot / classify / solve / apply on the bulk-drain path
     spans: Dict[str, float] = field(default_factory=dict)
+    # sub-phase -> seconds, inside nominate (prevalidate, the host
+    # flavor walk "assign", victim search "victims", the per-cycle
+    # solve's encode / launch / fetch / decode) and admit ("park");
+    # tracing.names.CYCLE_SUBPHASE_SPANS maps each to its parent
+    sub: Dict[str, float] = field(default_factory=dict)
+    # what the phases counted: heads prevalidated, walked on the host,
+    # deferred to victim search, parked; heads lowered, unrepresentable
+    # (lowering's fallback), device launches, heads the kernel returned
+    # non-Fit
+    counts: Dict[str, int] = field(default_factory=dict)
     # device vs host attribution: time spent inside device dispatches
     # (assign/victim kernels, the drain solve) vs everything else
     device_s: float = 0.0
@@ -166,10 +201,22 @@ class CycleTrace:
             "hostMs": round(self.host_s * 1e3, 3),
             "mesh": self.mesh,
             "spansMs": {k: round(v * 1e3, 3) for k, v in self.spans.items()},
+            "subMs": {k: round(v * 1e3, 3) for k, v in self.sub.items()},
+            "counts": dict(self.counts),
         }
         if self.trace_id:
             out["traceId"] = self.trace_id
         return out
+
+    # the tracing.phase sink
+    def add_phase(self, p, seconds: float, count: int = 0) -> None:
+        bucket = self.spans if p.key in CYCLE_PHASE_SPANS else self.sub
+        bucket[p.key] = bucket.get(p.key, 0.0) + seconds
+        if p.counter:
+            self.add_count(p.counter, count)
+
+    def add_count(self, name: str, n: int) -> None:
+        self.counts[name] = self.counts.get(name, 0) + n
 
 
 @dataclass
@@ -321,27 +368,37 @@ class Scheduler:
         # device time accumulated by the CURRENT cycle's dispatches
         # (assign + victim kernels), folded into its CycleTrace
         self._cycle_device_s = 0.0
+        # the CURRENT cycle's trace, the sink of its sub-phase regions
+        # (None between cycles: helpers the runtime calls outside a
+        # cycle, like _prevalidate for the drain, record nothing)
+        self._trace: Optional[CycleTrace] = None
+        # the runtime's phase totals (ClusterRuntime sets its own):
+        # the sink of the work a cycle does after its trace is flushed
+        self.phases: Optional[PhaseTotals] = None
 
     # ---- the cycle (scheduler.go:176-310) ----
     def schedule(self) -> CycleResult:
         self.scheduling_cycle += 1
         result = CycleResult()
         trace = CycleTrace(cycle=self.scheduling_cycle)
+        self._trace = trace
         self._cycle_device_s = 0.0
         self._cycle_scores.clear()
         t0 = _time.perf_counter()
-        self.guard.begin_cycle()
-
-        heads = self.queues.heads()
+        with phase(_HEADS, trace):
+            self.guard.begin_cycle()
+            heads = self.queues.heads()
+            if heads:
+                # open the cycle span-tree buffer: mid-cycle spans
+                # (divergence checks, fsyncs) and decision records
+                # reference its trace id; _finish_trace flushes it
+                # atomically, a crashed cycle drops it
+                self.tracer.next_cycle(self.scheduling_cycle)
         trace.heads = len(heads)
         if not heads:
+            self._trace = None
             self.notify_cycle(result)
             return result
-        # open the cycle span-tree buffer: mid-cycle spans (divergence
-        # checks, fsyncs) and decision records reference its trace id;
-        # _finish_trace flushes it atomically, a crashed cycle drops it
-        self.tracer.next_cycle(self.scheduling_cycle)
-        trace.spans["heads"] = _time.perf_counter() - t0
         try:
             return self._schedule_guarded(heads, result, trace, t0)
         except Exception as exc:  # noqa: BLE001 — the cycle guard's
@@ -352,13 +409,11 @@ class Scheduler:
             return self._contain_cycle_failure(heads, result, trace, t0, exc)
 
     def _schedule_guarded(self, heads, result, trace, t0) -> CycleResult:
-        t1 = _time.perf_counter()
-        snapshot = take_snapshot(self.cache)
-        trace.spans["snapshot"] = _time.perf_counter() - t1
+        with phase(_SNAPSHOT, trace):
+            snapshot = take_snapshot(self.cache)
         self.guard.phase_checkpoint("snapshot")
-        t1 = _time.perf_counter()
-        entries, device_plan = self._nominate(heads, snapshot)
-        trace.spans["nominate"] = _time.perf_counter() - t1
+        with phase(_NOMINATE, trace):
+            entries, device_plan = self._nominate(heads, snapshot)
         self.guard.phase_checkpoint(
             "nominate", device_used=self._cycle_device_s > 0
         )
@@ -367,18 +422,28 @@ class Scheduler:
         from kueue_tpu.testing import faults
 
         faults.fire("cycle.post_solve_pre_apply")
-        if device_plan is not None:
-            t2 = _time.perf_counter()
-            out = self._finalize_device(entries, device_plan, snapshot, result)
-            trace.spans["admit"] = _time.perf_counter() - t2
-            self.guard.phase_checkpoint(
-                "admit", device_used=self._cycle_device_s > 0
-            )
-            self._finish_trace(trace, out, t0)
+        with phase(_ADMIT, trace):
+            if device_plan is not None:
+                out = self._finalize_device(
+                    entries, device_plan, snapshot, result
+                )
+            else:
+                out = self._admit_host(entries, snapshot, result)
+        self.guard.phase_checkpoint(
+            "admit", device_used=self._cycle_device_s > 0
+        )
+        self._finish_trace(trace, out, t0)
+        with phase(_AUDIT, self.phases):
             self._audit_cycle(entries, out)
             self.notify_cycle(out)
-            return out
-        t2 = _time.perf_counter()  # 'admit' includes the entry ordering
+        return out
+
+    def _admit_host(
+        self, entries: List[Entry], snapshot: Snapshot, result: CycleResult
+    ) -> CycleResult:
+        """The host admit loop (scheduler.go:211-292): order the
+        entries, then admit one by one with usage re-checks, so parallel
+        nominations can't double-book quota; park the rest."""
         ordered = self._iterate(entries, snapshot)
 
         preempted_keys: Dict[str, WorkloadSnapshot] = {}
@@ -507,18 +572,17 @@ class Scheduler:
             if self._admit(e, snapshot):
                 result.admitted.append(e)
 
-        for e in entries:
-            if e.status != EntryStatus.ASSUMED:
-                self._requeue_and_update(e)
-                result.requeued.append(e)
-        trace.spans["admit"] = _time.perf_counter() - t2
-        self.guard.phase_checkpoint(
-            "admit", device_used=self._cycle_device_s > 0
-        )
-        self._finish_trace(trace, result, t0)
-        self._audit_cycle(entries, result)
-        self.notify_cycle(result)
+        self._park(entries, result)
         return result
+
+    def _park(self, entries: List[Entry], result: CycleResult) -> None:
+        """Requeue every entry the cycle did not admit."""
+        with phase(_PARK, self._trace) as park:
+            for e in entries:
+                if e.status != EntryStatus.ASSUMED:
+                    self._requeue_and_update(e)
+                    result.requeued.append(e)
+                    park.count += 1
 
     # ---- cycle guard: containment + poison attribution ----
     def _contain_cycle_failure(
@@ -622,6 +686,7 @@ class Scheduler:
         # atomic flush — a cycle that never reaches here leaks nothing)
         self.tracer.record_cycle(trace)
         self.last_traces.append(trace)
+        self._trace = None
 
     # ---- decision audit (core/audit.py) ----
     def _audit_cycle(self, entries: List[Entry], result: CycleResult) -> None:
@@ -756,21 +821,24 @@ class Scheduler:
     def _nominate(
         self, heads: List[Workload], snapshot: Snapshot
     ) -> Tuple[List[Entry], Optional[DevicePlan]]:
-        entries, to_assign = self._prevalidate(heads, snapshot)
+        with phase(_PREVALIDATE, self._trace) as pv:
+            entries, to_assign = self._prevalidate(heads, snapshot)
+            pv.count = len(heads)
         if self._solver_enabled(len(to_assign)):
             plan = self._assign_with_solver(to_assign, snapshot)
             return entries, plan
         assigner = self._make_assigner(snapshot)
         deferred: List[Entry] = []
-        t_host = _time.perf_counter()
-        for e in to_assign:
-            try:
-                self._host_assign(assigner, e, snapshot, deferred)
-            except Exception as exc:  # noqa: BLE001 — per-head guard:
-                # the raising head costs itself, never the cycle
-                self._contain_head_failure(e, exc)
+        with phase(_ASSIGN, self._trace) as walk:
+            for e in to_assign:
+                try:
+                    self._host_assign(assigner, e, snapshot, deferred)
+                except Exception as exc:  # noqa: BLE001 — per-head guard:
+                    # the raising head costs itself, never the cycle
+                    self._contain_head_failure(e, exc)
+            walk.count = len(to_assign)
         if to_assign:
-            per_head = (_time.perf_counter() - t_host) / len(to_assign)
+            per_head = walk.seconds / len(to_assign)
             self._host_assign_ema = (
                 per_head
                 if self._host_assign_ema is None
@@ -858,10 +926,17 @@ class Scheduler:
     def _resolve_deferred(
         self, assigner: FlavorAssigner, deferred: List[Entry], snapshot: Snapshot
     ) -> None:
-        """Victim search for every deferred preempt-mode entry —
-        batched on device above the threshold, host loop otherwise."""
         if not deferred:
             return
+        with phase(_VICTIMS, self._trace) as vs:
+            self._search_victims(assigner, deferred, snapshot)
+            vs.count = len(deferred)
+
+    def _search_victims(
+        self, assigner: FlavorAssigner, deferred: List[Entry], snapshot: Snapshot
+    ) -> None:
+        """Victim search for every deferred preempt-mode entry —
+        batched on device above the threshold, host loop otherwise."""
         batch_on = self.use_preempt_solver is True or (
             self.use_preempt_solver is None
             and len(deferred) >= self.preempt_solver_threshold
@@ -995,28 +1070,37 @@ class Scheduler:
         from kueue_tpu.core.solver import dispatch_lowered, lower_heads
 
         heads = [(e.workload, e.cq_name) for e in to_assign]
-        try:
-            lowered = lower_heads(
-                snapshot,
-                heads,
-                self.cache.flavors,
-                timestamp_fn=lambda wl: queue_order_timestamp(wl, self.queues._ts_policy),
-                transform=self.transform_config,
-            )
-        except Exception as exc:  # noqa: BLE001 — batch-level lowering
-            # failure: bisect to the poison head(s), host path for the
-            # rest (per-head contained)
-            self._bisect_lowering_failure(to_assign, snapshot, exc)
-            return None
-        if self.policy is not None and not self.policy.is_default:
-            # compile the policy's score tensors onto the batch BEFORE
-            # the guard sees it: the device kernel and the host mirror
-            # both read lowered.score, so divergence checks stay sound
-            from kueue_tpu.policy import annotate_lowered
+        lowering_error: Optional[Exception] = None
+        with phase(_ENCODE, self._trace) as enc:
+            try:
+                lowered = lower_heads(
+                    snapshot,
+                    heads,
+                    self.cache.flavors,
+                    timestamp_fn=lambda wl: queue_order_timestamp(wl, self.queues._ts_policy),
+                    transform=self.transform_config,
+                )
+            except Exception as exc:  # noqa: BLE001 — batch-level
+                # lowering failure: bisected below
+                lowering_error = exc
+            else:
+                enc.count = len(heads)
+                if self.policy is not None and not self.policy.is_default:
+                    # compile the policy's score tensors onto the batch
+                    # BEFORE the guard sees it: the device kernel and the
+                    # host mirror both read lowered.score, so divergence
+                    # checks stay sound
+                    from kueue_tpu.policy import annotate_lowered
 
-            annotate_lowered(self.policy, lowered, now=self.clock.now())
-            self._record_cycle_scores(lowered)
+                    annotate_lowered(self.policy, lowered, now=self.clock.now())
+                    self._record_cycle_scores(lowered)
+        if lowering_error is not None:
+            # bisect to the poison head(s), host path for the rest
+            # (per-head contained)
+            self._bisect_lowering_failure(to_assign, snapshot, lowering_error)
+            return None
         fallback = set(lowered.fallback)
+        self._count("unrepresentable", len(fallback))
         if len(fallback) == len(to_assign):
             # nothing representable: skip the device dispatch entirely
             self._host_assign_contained(to_assign, snapshot)
@@ -1029,7 +1113,8 @@ class Scheduler:
             snapshot,
             lowered,
             dispatch=lambda: dispatch_lowered(
-                snapshot, lowered, resident=self._resident_state
+                snapshot, lowered, resident=self._resident_state,
+                phases=self._trace,
             ),
         )
         if outcome.result is None:
@@ -1047,19 +1132,23 @@ class Scheduler:
             for i in range(len(to_assign))
             if i in fallback or chosen[i] < 0
         ]
+        # heads the solve handed back non-Fit (unrepresentable heads
+        # never reached it)
+        self._count("returned", len(host_idx) - len(fallback))
         if host_idx:
             self._host_assign_contained(
                 [to_assign[i] for i in host_idx], snapshot
             )
         host_set = set(host_idx)
-        for i, e in enumerate(to_assign):
-            if i in host_set:
-                continue
-            e.nominated_via = outcome.via
-            e.assignment = self._assignment_from_device(
-                lowered, i, int(chosen[i]), snapshot
-            )
-            e.workload.last_assignment = e.assignment.last_state
+        with phase(_DECODE, self._trace):
+            for i, e in enumerate(to_assign):
+                if i in host_set:
+                    continue
+                e.nominated_via = outcome.via
+                e.assignment = self._assignment_from_device(
+                    lowered, i, int(chosen[i]), snapshot
+                )
+                e.workload.last_assignment = e.assignment.last_state
 
         # Pure cycle: nothing host-side can mutate usage, so the device
         # scan's admitted flags ARE the cycle outcome.
@@ -1089,12 +1178,19 @@ class Scheduler:
         host-side companion for unrepresentable heads."""
         assigner = self._make_assigner(snapshot)
         deferred: List[Entry] = []
-        for e in entries:
-            try:
-                self._host_assign(assigner, e, snapshot, deferred)
-            except Exception as exc:  # noqa: BLE001 — per-head guard
-                self._contain_head_failure(e, exc)
+        with phase(_ASSIGN, self._trace) as walk:
+            for e in entries:
+                try:
+                    self._host_assign(assigner, e, snapshot, deferred)
+                except Exception as exc:  # noqa: BLE001 — per-head guard
+                    self._contain_head_failure(e, exc)
+            walk.count = len(entries)
         self._resolve_deferred(assigner, deferred, snapshot)
+
+    def _count(self, name: str, n: int) -> None:
+        """Add ``n`` to the current cycle's ``counts[name]``."""
+        if self._trace is not None:
+            self._trace.add_count(name, n)
 
     def _bisect_lowering_failure(
         self, to_assign: List[Entry], snapshot: Snapshot, exc: Exception
@@ -1219,10 +1315,7 @@ class Scheduler:
                 e.inadmissible_msg = (
                     "Workload no longer fits after processing another workload"
                 )
-        for e in entries:
-            if e.status != EntryStatus.ASSUMED:
-                self._requeue_and_update(e)
-                result.requeued.append(e)
+        self._park(entries, result)
         return result
 
     def _is_admitted(self, wl: Workload) -> bool:

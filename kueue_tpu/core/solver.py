@@ -32,7 +32,12 @@ from kueue_tpu.models.resource_flavor import flavor_eligible, group_label_keys
 from kueue_tpu.core.snapshot import Snapshot
 from kueue_tpu.core.workload_info import effective_podset_count, quota_per_pod
 from kueue_tpu.resources import PODS, FlavorResource
+from kueue_tpu.tracing.phases import Phase, phase
 from kueue_tpu.utils.priority import priority_of
+
+# the per-cycle solve's device round trip (tracing/phases.py)
+_LAUNCH = Phase("cycle.launch", counter="launches")
+_FETCH = Phase("cycle.fetch")
 
 
 @dataclass
@@ -614,6 +619,7 @@ def dispatch_lowered(
     pad_heads: bool = True,
     mesh=None,  # jax.sharding.Mesh: shard heads along "wl"
     resident: Optional[ResidentCycleState] = None,
+    phases=None,  # tracing.phase sink (the cycle's CycleTrace)
 ):
     """Ship an already-lowered batch to the segmented device solver.
 
@@ -632,6 +638,10 @@ def dispatch_lowered(
     all per-head outputs come back in one packed fetch, because every
     device->host retrieval pays a full round trip and the scheduler
     reads several fields per cycle.
+
+    ``phases`` receives two regions: ``cycle.launch`` (the resident
+    refresh, packing, transfers and the launch, up to the return of the
+    jitted call) and ``cycle.fetch`` (the blocking fetch).
     """
     import numpy as np
 
@@ -642,39 +652,41 @@ def dispatch_lowered(
         solve_cycle_segmented_packed_jit,
     )
 
-    w = len(lowered.heads)
-    w_pad = _bucket(w) if pad_heads else w
-    if mesh is not None:
-        # W must divide the mesh's wl axis (uneven device_put shards
-        # are rejected); power-of-two buckets already do for
-        # power-of-two meshes, this covers the rest
-        from kueue_tpu.parallel.sharded_solver import pad_w_multiple
+    with phase(_LAUNCH, phases) as launch:
+        w = len(lowered.heads)
+        w_pad = _bucket(w) if pad_heads else w
+        if mesh is not None:
+            # W must divide the mesh's wl axis (uneven device_put
+            # shards are rejected); power-of-two buckets already do
+            # for power-of-two meshes, this covers the rest
+            from kueue_tpu.parallel.sharded_solver import pad_w_multiple
 
-        w_pad = pad_w_multiple(w_pad, mesh.shape["wl"])
-    usage_resident = None
-    if resident is not None and mesh is None:
-        tree, paths, roots, usage_resident = resident.refresh(snapshot)
-    else:
-        tree, paths, roots = tree_arrays(snapshot)
-    batch_np, seg_id, n_segments, n_steps = pack_heads(lowered, roots, w_pad)
-    if mesh is not None:
-        # numpy -> device_put straight onto the shards (one transfer,
-        # no staging of the full batch on a single device)
-        from kueue_tpu.parallel.sharded_solver import place_cycle_inputs
+            w_pad = pad_w_multiple(w_pad, mesh.shape["wl"])
+        usage_resident = None
+        if resident is not None and mesh is None:
+            tree, paths, roots, usage_resident = resident.refresh(snapshot)
+        else:
+            tree, paths, roots = tree_arrays(snapshot)
+        batch_np, seg_id, n_segments, n_steps = pack_heads(
+            lowered, roots, w_pad
+        )
+        if mesh is not None:
+            # numpy -> device_put straight onto the shards (one
+            # transfer, no staging of the full batch on a single device)
+            from kueue_tpu.parallel.sharded_solver import place_cycle_inputs
 
-        tree, usage_in, batch, paths, seg_in = place_cycle_inputs(
-            mesh, tree, snapshot.local_usage, batch_np, paths, seg_id
-        )
-    else:
-        batch = HeadsBatch(*(jnp.asarray(x) for x in batch_np))
-        usage_in = (
-            usage_resident
-            if usage_resident is not None
-            else jnp.asarray(snapshot.local_usage)
-        )
-        seg_in = jnp.asarray(seg_id)
-    packed = np.asarray(
-        solve_cycle_segmented_packed_jit(
+            tree, usage_in, batch, paths, seg_in = place_cycle_inputs(
+                mesh, tree, snapshot.local_usage, batch_np, paths, seg_id
+            )
+        else:
+            batch = HeadsBatch(*(jnp.asarray(x) for x in batch_np))
+            usage_in = (
+                usage_resident
+                if usage_resident is not None
+                else jnp.asarray(snapshot.local_usage)
+            )
+            seg_in = jnp.asarray(seg_id)
+        out = solve_cycle_segmented_packed_jit(
             tree,
             usage_in,
             batch,
@@ -683,7 +695,10 @@ def dispatch_lowered(
             n_segments=n_segments,
             n_steps=n_steps,
         )
-    )  # ONE device->host round trip for the whole cycle outcome
+        launch.count = 1
+    with phase(_FETCH, phases):
+        # ONE device->host round trip for the whole cycle outcome
+        packed = np.asarray(out)
     return SolveResult(
         chosen=packed[0].astype(np.int32),
         admitted=packed[1].astype(bool),

@@ -80,6 +80,11 @@ from kueue_tpu.models.constants import (
     AdmissionCheckStateType,
     WorkloadConditionType,
 )
+from kueue_tpu.tracing.phases import Phase, phase
+
+# the batch apply's phases (tracing/phases.py), summed on the runtime
+_INGEST_DECODE = Phase("ingest.decode")
+_INGEST_APPLY = Phase("ingest.apply", counter="ingest.objects")
 
 
 class _ServingHTTPServer(ThreadingHTTPServer):
@@ -515,16 +520,20 @@ class KueueServer:
         rejected: Dict[str, int] = {}
         first_error: Optional[str] = None
         any_applied = False
-        for section, objs in body.items():
-            for i, obj in enumerate(objs):
-                try:
-                    self.apply(section, obj, reconcile=False)
-                    applied[section] = applied.get(section, 0) + 1
-                    any_applied = True
-                except ApiError as e:
-                    rejected[section] = rejected.get(section, 0) + 1
-                    if first_error is None:
-                        first_error = f"{section}[{i}]: {e.message}"
+        with phase(
+            _INGEST_APPLY, getattr(self.runtime, "phases", None)
+        ) as ingest:
+            for section, objs in body.items():
+                for i, obj in enumerate(objs):
+                    try:
+                        self.apply(section, obj, reconcile=False)
+                        applied[section] = applied.get(section, 0) + 1
+                        any_applied = True
+                    except ApiError as e:
+                        rejected[section] = rejected.get(section, 0) + 1
+                        if first_error is None:
+                            first_error = f"{section}[{i}]: {e.message}"
+            ingest.count = sum(applied.values())
         if self.auto_reconcile and any_applied:
             with self.lock:
                 self.runtime.run_until_idle()
@@ -1258,7 +1267,10 @@ def _make_handler(srv: KueueServer):
             self._send_json({"applied": obj})
 
         def _h_apply_batch(self, query):
-            body = self._body()
+            with phase(
+                _INGEST_DECODE, getattr(srv.runtime, "phases", None)
+            ):
+                body = self._body()
             for obj in body.get("workloads", []) or []:
                 self._propagate_traceparent("workloads", obj)
             if srv.gateway is not None:
@@ -1419,12 +1431,14 @@ def _make_handler(srv: KueueServer):
             self._send_json({"cycles": cycles})
 
         def _h_debug_cycles(self, query):
-            # per-cycle phase attribution (the pprof-ish surface)
+            # per-cycle phase attribution (the pprof-ish surface), plus
+            # the runtime's cumulative loop and batch-apply phases
             with srv.lock:
                 traces = [
                     t.to_dict() for t in srv.runtime.scheduler.last_traces
                 ]
-            self._send_json({"cycles": traces})
+                totals = srv.runtime.phase_totals()
+            self._send_json({"cycles": traces, "runtimePhases": totals})
 
         def _h_debug_traces(self, query):
             """Bounded in-memory trace store: newest traces first

@@ -10,15 +10,24 @@ W3C-traceparent-style: as an HTTP header on client requests and as the
 ``kueue.x-k8s.io/traceparent`` workload label across MultiKueue
 dispatch, so one trace id follows a workload from the manager through
 the winning worker's admission cycle onto a tailing replica.
+
+``phase`` (phases.py) times the hot path's coarse regions — the cycle
+phases and their sub-phases, the runtime loop, the batch apply — into a
+``CycleTrace`` or the runtime's ``PhaseTotals`` and marks each as a
+``kueue.<name>`` profiler annotation on the device trace's clock.
 """
 
 from kueue_tpu.tracing.names import (
     CYCLE_PHASE_SPANS,
     CYCLE_SPAN_NAMES,
+    CYCLE_SUBPHASE_SPANS,
     EVENT_SPANS,
+    PHASE_NAMES,
+    RUNTIME_PHASE_NAMES,
     SPAN_NAMES,
     WORKLOAD_SPAN_NAMES,
 )
+from kueue_tpu.tracing.phases import Phase, PhaseTotals, phase
 from kueue_tpu.tracing.tracer import (
     TRACEPARENT_LABEL,
     Span,
@@ -31,9 +40,15 @@ from kueue_tpu.tracing.export import to_chrome_trace
 __all__ = [
     "CYCLE_PHASE_SPANS",
     "CYCLE_SPAN_NAMES",
+    "CYCLE_SUBPHASE_SPANS",
     "EVENT_SPANS",
+    "PHASE_NAMES",
+    "RUNTIME_PHASE_NAMES",
     "SPAN_NAMES",
     "WORKLOAD_SPAN_NAMES",
+    "Phase",
+    "PhaseTotals",
+    "phase",
     "TRACEPARENT_LABEL",
     "Span",
     "Tracer",

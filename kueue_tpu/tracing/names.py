@@ -4,9 +4,10 @@ The same low-cardinality contract ``EVENT_REASONS`` and
 ``InadmissibleReason`` enforce on the event/audit surfaces applies to
 spans: ``kueue_trace_spans_total{name=...}`` is labeled by span name,
 so the set must stay closed. ``Tracer`` rejects names outside this
-registry at the call site, and tests/test_tracing.py lints every
-literal ``span("...")`` / ``add_cycle_span("...")`` in the source tree
-against it — the reason-enum lint pattern applied to tracing.
+registry at the call site, ``Phase`` where a phase is defined, and the
+kueuelint ``span-name`` rule checks every literal ``add_cycle_span("...")``
+/ ``Phase("...")`` in the source tree against it — the reason-enum lint
+pattern applied to tracing.
 """
 
 from __future__ import annotations
@@ -23,7 +24,6 @@ WORKLOAD_SPAN_NAMES = frozenset(
         "workload.flavor_assign",
         "workload.victim_search",
         "workload.quota_reserve",
-        "workload.admission_check",
         "workload.admit",
         "workload.preempt",
         "workload.evict",
@@ -54,11 +54,19 @@ CYCLE_SPAN_NAMES = frozenset(
         "cycle.nominate",
         "cycle.admit",
         "cycle.classify",
-        "cycle.encode",
         "cycle.solve",
         "cycle.apply",
         "cycle.prefetch",
         "cycle.commit",
+        # sub-phases of a scheduler cycle (children of nominate/admit)
+        "cycle.prevalidate",
+        "cycle.assign",
+        "cycle.victims",
+        "cycle.park",
+        "cycle.encode",
+        "cycle.launch",
+        "cycle.fetch",
+        "cycle.decode",
         "cycle.discard",
         "cycle.megaloop",
         "cycle.mesh_place",
@@ -68,15 +76,27 @@ CYCLE_SPAN_NAMES = frozenset(
     }
 )
 
-# replica tail spans (the read-replica's own apply work)
-REPLICA_SPAN_NAMES = frozenset(
+SPAN_NAMES = WORKLOAD_SPAN_NAMES | CYCLE_SPAN_NAMES
+
+# runtime phases: regions of the runtime loop and the server's batch
+# apply. They are never stored as spans (one per loop iteration would
+# flood the store); they live as cumulative totals on the runtime
+# (``ClusterRuntime.phase_totals``) and as ``kueue.<name>`` profiler
+# annotations.
+RUNTIME_PHASE_NAMES = frozenset(
     {
-        "replica.poll",
-        "replica.apply",
+        "loop.fingerprint",
+        "loop.reconcile",
+        "loop.drain_gate",
+        "loop.report",
+        "loop.audit",
+        "ingest.decode",
+        "ingest.apply",
     }
 )
 
-SPAN_NAMES = WORKLOAD_SPAN_NAMES | CYCLE_SPAN_NAMES | REPLICA_SPAN_NAMES
+# every name a ``tracing.Phase`` may carry
+PHASE_NAMES = CYCLE_SPAN_NAMES | RUNTIME_PHASE_NAMES
 
 # CycleTrace phase key -> cycle span name (the lowering used by
 # Tracer.record_cycle; a phase without a registry entry is a bug in
@@ -87,11 +107,23 @@ CYCLE_PHASE_SPANS = {
     "nominate": "cycle.nominate",
     "admit": "cycle.admit",
     "classify": "cycle.classify",
-    "encode": "cycle.encode",
     "solve": "cycle.solve",
     "apply": "cycle.apply",
     "prefetch": "cycle.prefetch",
     "commit": "cycle.commit",
+}
+
+# CycleTrace sub-phase key -> (span name, parent phase key): record_cycle
+# lowers each as a child of its parent phase's span
+CYCLE_SUBPHASE_SPANS = {
+    "prevalidate": ("cycle.prevalidate", "nominate"),
+    "assign": ("cycle.assign", "nominate"),
+    "victims": ("cycle.victims", "nominate"),
+    "encode": ("cycle.encode", "nominate"),
+    "launch": ("cycle.launch", "nominate"),
+    "fetch": ("cycle.fetch", "nominate"),
+    "decode": ("cycle.decode", "nominate"),
+    "park": ("cycle.park", "admit"),
 }
 
 # event reason -> workload lifecycle span (ClusterRuntime.event funnel;

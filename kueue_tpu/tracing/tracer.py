@@ -10,9 +10,12 @@ Two correlated trace families live here:
   ``kueuectl explain``, the journal feed and read replicas all render
   the same causality.
 - **cycle span trees** — one trace per scheduling cycle / drain round:
-  the root ``cycle`` span plus phase children (snapshot/encode/solve/
-  apply, pipeline prefetch/commit/discard, divergence checks, journal
-  fsyncs) carrying real measured durations. Decision spans reference
+  the root ``cycle`` span plus phase children (snapshot/nominate/admit
+  or classify/solve/apply, pipeline prefetch/commit/discard, divergence
+  checks, journal fsyncs) carrying real measured durations, and the
+  sub-phases of nominate and admit (prevalidate, the host flavor walk,
+  victim search, the per-cycle solve's encode/launch/fetch/decode,
+  parking) as children of their phase. Decision spans reference
   their cycle trace through the ``cycleTrace`` attr, which is how "900
   ms between enqueue and admit" decomposes into the cycles that spent
   it.
@@ -30,8 +33,9 @@ ships the delta on the leader's journal feed and ``ingest`` upserts it
 on a replica, preserving trace/span ids so a waterfall rendered on the
 replica is the leader's.
 
-Overhead contract: always-on must stay under 2 % of cycle time
-(``bench.py --trace``). The hot path STORES almost nothing per
+Overhead contract: always-on must stay under 2 % of cycle time, read
+from ``self_time_s`` and from the traced and untraced runs of
+``python3 -m benchmark``. The hot path STORES almost nothing per
 workload: decision and lifecycle-event spans are synthesized at read
 time from the audit ring and event ring (which already carry the trace
 id and a timestamp — storing them twice would double the cost of every
@@ -52,7 +56,11 @@ from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
 
-from kueue_tpu.tracing.names import CYCLE_PHASE_SPANS, SPAN_NAMES
+from kueue_tpu.tracing.names import (
+    CYCLE_PHASE_SPANS,
+    CYCLE_SUBPHASE_SPANS,
+    SPAN_NAMES,
+)
 
 #: workload label carrying the W3C traceparent across control planes
 #: (the MultiKueue dispatcher stamps it on mirrored copies; a worker's
@@ -143,7 +151,7 @@ class Tracer:
     Thread-safe: the scheduler writes under the server lock, but the
     journal-feed reader, debug routes and replica ingest may race it.
     ``enabled=False`` turns every recording call into a no-op (the
-    ``bench.py --trace`` baseline); ``passive=True`` keeps ingest and
+    untraced baseline); ``passive=True`` keeps ingest and
     reads working while local recording no-ops (read replicas render
     the LEADER's spans, never their own)."""
 
@@ -197,9 +205,8 @@ class Tracer:
         self._pending_n = 0  # guarded by: _lock
         # exact self-accounting: wall seconds spent inside the tracer's
         # recording entry points (the guard.divergence_check_s pattern)
-        # — bench.py --trace asserts the <2% overhead budget on THIS,
-        # which a noisy shared-CPU host cannot corrupt the way a wall
-        # A/B can
+        # — the <2% overhead budget is read on THIS, which a noisy
+        # shared-CPU host cannot corrupt the way a wall A/B can
         self.self_time_s = 0.0
         # batched queue-to-admission waits (cq -> [seconds]), same
         # rationale: one histogram label resolution per flush, not per
@@ -556,8 +563,14 @@ class Tracer:
         with self._lock:
             self._store(root)
             # phase children in CycleTrace order, laid end-to-start so
-            # the waterfall reads like the cycle executed
+            # the waterfall reads like the cycle executed; each
+            # sub-phase is a child of its parent phase's span, laid
+            # end-to-start from that span's start. A contained cycle
+            # can raise out of a phase after some of its sub-phases
+            # closed: the phase never recorded, so those sub-phases
+            # hang off the root where it would have started.
             offset = root.start
+            parents: Dict[str, Span] = {}
             for phase, seconds in trace.spans.items():
                 name = CYCLE_PHASE_SPANS.get(phase)
                 if name is None:
@@ -565,7 +578,7 @@ class Tracer:
                         f"cycle phase {phase!r} has no span mapping "
                         "(tracing/names.CYCLE_PHASE_SPANS)"
                     )
-                self._store(
+                parents[phase] = self._store(
                     Span(
                         trace_id=tid,
                         span_id=self._next_id(16),
@@ -577,6 +590,30 @@ class Tracer:
                     )
                 )
                 offset += max(seconds, 0.0)
+            sub_offset: Dict[str, float] = {}
+            for sub, seconds in trace.sub.items():
+                name, parent_key = CYCLE_SUBPHASE_SPANS.get(sub, (None, ""))
+                if name is None:
+                    raise ValueError(
+                        f"cycle sub-phase {sub!r} has no span mapping "
+                        "(tracing/names.CYCLE_SUBPHASE_SPANS)"
+                    )
+                parent = parents.get(parent_key)
+                start = sub_offset.get(
+                    parent_key, parent.start if parent else offset
+                )
+                self._store(
+                    Span(
+                        trace_id=tid,
+                        span_id=self._next_id(16),
+                        parent_id=parent.span_id if parent else root_id,
+                        name=name,
+                        start=start,
+                        duration=max(seconds, 0.0),
+                        attrs={"cycle": cycle},
+                    )
+                )
+                sub_offset[parent_key] = start + max(seconds, 0.0)
             for span in buf:
                 self._store(span)
             self._cycle_index[cycle] = tid

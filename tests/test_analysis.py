@@ -839,6 +839,29 @@ class TestRegistryRules:
             tmp_path, {"b.py": good}, rules=["span-name"], config=dict(cfg)
         ) == []
 
+    def test_span_name_phase_literals(self, tmp_path):
+        cfg = {"span_names": {"cycle.solve"},
+               "phase_names": {"cycle.solve", "loop.report"}}
+        bad = (
+            "from kueue_tpu.tracing import Phase\n"
+            'P = Phase("loop.bogus")\n'
+            'Q = tracing.Phase("cycle.bogus")\n'
+        )
+        good = 'P = Phase("loop.report")\nQ = Phase("cycle.solve")\n'
+        findings = run_fixture(
+            tmp_path, {"a.py": bad}, rules=["span-name"], config=dict(cfg)
+        )
+        assert sorted(f.line for f in findings) == [2, 3]
+        assert all("PHASE_NAMES" in f.message for f in findings)
+        assert run_fixture(
+            tmp_path, {"b.py": good}, rules=["span-name"], config=dict(cfg)
+        ) == []
+        # a runtime phase is not a span: recording it is an ad-hoc span
+        span = 'def f(tr):\n    tr.add_cycle_span("loop.report")\n'
+        assert "SPAN_NAMES" in run_fixture(
+            tmp_path, {"c.py": span}, rules=["span-name"], config=dict(cfg)
+        )[0].message
+
     def test_span_name_pattern_rot_guard(self, tmp_path):
         cfg = {"span_names": {"cycle.solve"}, "require_call_sites": True}
         findings = run_fixture(
