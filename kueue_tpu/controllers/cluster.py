@@ -38,13 +38,37 @@ from kueue_tpu.utils.clock import Clock
 __all__ = ["ClusterRuntime", "Event"]
 
 # the runtime loop's phases and the drain's (tracing/phases.py)
-_FINGERPRINT = Phase("loop.fingerprint")
+_FINGERPRINT = Phase("loop.fingerprint", counter="loop.fingerprint_walks")
 _RECONCILE = Phase("loop.reconcile")
 _DRAIN_GATE = Phase("loop.drain_gate")
 _REPORT = Phase("loop.report")
 _CLASSIFY = Phase("cycle.classify")
 _SOLVE = Phase("cycle.solve")
 _APPLY = Phase("cycle.apply")
+
+
+class _LastWalk:
+    """What ``run_until_idle``'s quiescence check compares, as of its
+    last walk: each workload's and job's projection by key, and the
+    events recorder's resourceVersion. One lives for one call."""
+
+    __slots__ = ("workloads", "jobs", "events_rv")
+
+    def __init__(self):
+        self.workloads: Dict[str, tuple] = {}
+        self.jobs: Dict[str, bool] = {}
+        self.events_rv: Optional[int] = None
+
+
+def _drop_gone(held: dict, live: dict) -> int:
+    """Drop ``held``'s keys that ``live`` no longer has, which already
+    holds every key of ``live``; return how many went."""
+    if len(held) == len(live):
+        return 0
+    gone = [k for k in held if k not in live]
+    for k in gone:
+        del held[k]
+    return len(gone)
 
 
 def _parse_megaloop(spec) -> tuple:
@@ -1311,32 +1335,48 @@ class ClusterRuntime:
             )
         return v
 
-    def _state_fingerprint(self):
-        parts = []
-        for key in sorted(self.workloads):
-            wl = self.workloads[key]
-            parts.append(
-                (
-                    key,
+    def _quiescence_walk(self, last: _LastWalk) -> int:
+        """Bring ``last`` up to the present state; return how many of its
+        entries changed: workloads and jobs whose projection differs,
+        keys added or removed, and the events recorder's resourceVersion
+        as one entry. A workload projects to whether it is active, its
+        admission's ClusterQueue, each condition's status and reason
+        (not its message or transition time) and each admission check's
+        state; a job to whether it is suspended. Condition types and
+        check names are unique, so both compare as mappings. Each
+        projection is compared as it is built: an unchanged one is freed
+        at once rather than kept in a list for the collector to promote."""
+        with phase(_FINGERPRINT, self.phases) as region:
+            region.count = 1
+            changed = 0
+            held = last.workloads
+            for key, wl in self.workloads.items():
+                adm = wl.admission
+                checks = wl.admission_check_states
+                p = (
                     wl.active,
-                    wl.admission.cluster_queue if wl.admission else None,
-                    tuple(
-                        (t.value, c.status, c.reason)
-                        for t, c in sorted(wl.conditions.items())
-                    ),
-                    tuple(
-                        (n, s.state.value)
-                        for n, s in sorted(wl.admission_check_states.items())
-                    ),
+                    adm.cluster_queue if adm is not None else None,
+                    {t: (c.status, c.reason) for t, c in wl.conditions.items()},
+                    {n: s.state for n, s in checks.items()} if checks else None,
                 )
-            )
-        for key in sorted(self.jobs):
-            job = self.jobs[key]
-            parts.append((key, job.is_suspended()))
-        # the recorder's resourceVersion advances on series dedups too,
-        # so a repeated event still registers as progress (the old
-        # len(events) missed that once dedup landed)
-        return tuple(parts), self.events.resource_version
+                if held.get(key) != p:
+                    held[key] = p
+                    changed += 1
+            changed += _drop_gone(held, self.workloads)
+            held = last.jobs
+            for key, job in self.jobs.items():
+                suspended = job.is_suspended()
+                if key not in held or held[key] != suspended:
+                    held[key] = suspended
+                    changed += 1
+            changed += _drop_gone(held, self.jobs)
+            # the recorder's resourceVersion advances on series dedups
+            # too, so a repeated event still registers as progress
+            rv = self.events.resource_version
+            if rv != last.events_rv:
+                last.events_rv = rv
+                changed += 1
+            return changed
 
     def schedule_once(self):
         """One scheduler cycle with metric reporting."""
@@ -1360,10 +1400,12 @@ class ClusterRuntime:
         """
         cycles = 0
         totals = self.phases
+        # an iteration's "before" is the previous one's "after": one walk
+        # a call, then one per iteration
+        last = _LastWalk()
+        self._quiescence_walk(last)
         for _ in range(max_iterations):
             totals.add_count("loop.iterations")
-            with phase(_FINGERPRINT, totals):
-                before = self._state_fingerprint()
             with phase(_RECONCILE, totals):
                 self.reconcile_once()
             with phase(_DRAIN_GATE) as gate:
@@ -1376,9 +1418,9 @@ class ClusterRuntime:
             with phase(_RECONCILE, totals):
                 self.reconcile_once()
             cycles += 1
-            with phase(_FINGERPRINT, totals):
-                after = self._state_fingerprint()
-            if after == before:
+            changed = self._quiescence_walk(last)
+            totals.add_count("loop.fingerprint_changed", changed)
+            if not changed:
                 break
         return cycles
 

@@ -50,6 +50,8 @@ N_CQ = 12
 SUB_NAMES = {name for name, _ in CYCLE_SUBPHASE_SPANS.values()}
 LOOP_NAMES = {"loop.fingerprint", "loop.reconcile", "loop.drain_gate",
               "loop.report", "loop.audit"}
+LOOP_COUNTERS = {"loop.iterations", "loop.fingerprint_walks",
+                 "loop.fingerprint_changed"}
 
 
 @pytest.fixture(autouse=True)
@@ -146,6 +148,7 @@ def test_every_new_name_is_emitted_and_registered(scenario):
     assert LOOP_NAMES <= set(totals["spansMs"])
     assert set(totals["spansMs"]) <= RUNTIME_PHASE_NAMES
     assert totals["counts"]["loop.iterations"] >= 2
+    assert LOOP_COUNTERS <= set(totals["counts"])
     # counts and sub-phases reach the /debug/cycles wire form
     d = second[-1].to_dict()
     assert set(d["subMs"]) == set(second[-1].sub)
@@ -330,7 +333,32 @@ def test_batch_apply_phases_over_http():
     assert after["counts"]["loop.iterations"] > before["counts"].get(
         "loop.iterations", 0)
     assert served["runtimePhases"]["counts"]["ingest.objects"] == N_CQ
+    assert LOOP_COUNTERS <= set(served["runtimePhases"]["counts"])
     assert all("subMs" in c and "counts" in c for c in served["cycles"])
+
+
+def test_one_quiescence_walk_per_iteration_plus_one():
+    """Each ``run_until_idle`` call walks the state once before its
+    first iteration and once after each; an idle runtime's call changes
+    no entry, a call that admits a backlog changes some."""
+    rt = build_rt()
+
+    def counts_of_one_call():
+        before = rt.phase_totals()["counts"]
+        rt.run_until_idle()
+        after = rt.phase_totals()["counts"]
+        return {k: after[k] - before.get(k, 0) for k in LOOP_COUNTERS}
+
+    idle = counts_of_one_call()
+    assert idle == {"loop.iterations": 1, "loop.fingerprint_walks": 2,
+                    "loop.fingerprint_changed": 0}
+    add_backlog(rt, "low", 2, "1", 0, 0.0)
+    busy = counts_of_one_call()
+    assert busy["loop.iterations"] >= 2
+    assert busy["loop.fingerprint_walks"] == busy["loop.iterations"] + 1
+    # every workload changed at least once (it was admitted)
+    assert busy["loop.fingerprint_changed"] >= 2 * N_CQ
+    assert counts_of_one_call()["loop.fingerprint_changed"] == 0
 
 
 def test_phase_names_are_closed_and_checked_where_defined():
