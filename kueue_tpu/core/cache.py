@@ -140,7 +140,13 @@ class Cache:
             cached.allocatable_generation += 1
 
     # ---- CQ active status (cache/clusterqueue.go reasons) ----
-    def cluster_queue_status(self, name: str) -> CQStatus:
+    def cluster_queue_status(
+        self, name: str, cyclic: Optional[Set[str]] = None
+    ) -> CQStatus:
+        """Whether ClusterQueue ``name`` is active, and why not.
+        ``cyclic`` is the forest's ``cyclic_cohorts()`` for this state,
+        walked here if None (a caller checking every ClusterQueue walks
+        once and passes it)."""
         cached = self.cluster_queues.get(name)
         if cached is None:
             return CQStatus(active=False, reasons=("Unknown",))
@@ -165,7 +171,7 @@ class Cache:
             if flavor and flavor.topology_name and flavor.topology_name not in self.topologies:
                 reasons.append("TopologyNotFound")
                 break
-        if self.forest.cq_in_cycle(name):
+        if self.forest.cq_in_cycle(name, cyclic):
             reasons.append("CohortCycle")
         return CQStatus(active=not reasons, reasons=tuple(reasons))
 

@@ -41,6 +41,9 @@ class CohortForest:
     def __init__(self) -> None:
         self.cohorts: Dict[str, CohortNode] = {}
         self.cq_parent: Dict[str, Optional[str]] = {}
+        # cyclic_cohorts() walks made so far (the snapshot phase's
+        # ``forest_walks`` counter is the difference around it)
+        self.walks = 0
 
     # ---- ClusterQueue membership ----
     def add_cluster_queue(self, cq: str, cohort: Optional[str]) -> None:
@@ -120,7 +123,12 @@ class CohortForest:
 
     # ---- cycle detection ----
     def cyclic_cohorts(self) -> Set[str]:
-        """Names of cohorts participating in (or below) a parent cycle."""
+        """Names of cohorts participating in (or below) a parent cycle.
+
+        One walk over every cohort. It is not memoized: the forest can
+        change between any two calls, so a caller that asks many
+        questions of one state computes it once and passes it on."""
+        self.walks += 1
         state: Dict[str, int] = {}  # 0=visiting, 1=ok, 2=cyclic
 
         def visit(name: str) -> int:
@@ -140,9 +148,15 @@ class CohortForest:
 
         return {name for name in self.cohorts if visit(name) == 2}
 
-    def cq_in_cycle(self, cq: str) -> bool:
+    def cq_in_cycle(self, cq: str, cyclic: Optional[Set[str]] = None) -> bool:
+        """Whether ``cq``'s cohort is in (or below) a cycle; ``cyclic``
+        is this state's ``cyclic_cohorts()``, walked here if None."""
         parent = self.cq_parent.get(cq)
-        return parent is not None and parent in self.cyclic_cohorts()
+        if parent is None:
+            return False
+        if cyclic is None:
+            cyclic = self.cyclic_cohorts()
+        return parent in cyclic
 
     def root_of(self, cohort: str) -> str:
         seen = set()
@@ -153,15 +167,19 @@ class CohortForest:
         return cur
 
     # ---- flattening ----
-    def flatten(self, cq_names: List[str]) -> "FlatHierarchy":
+    def flatten(
+        self, cq_names: List[str], cyclic: Optional[Set[str]] = None
+    ) -> "FlatHierarchy":
         """Assign dense indices and build parent/level arrays.
 
         CQs occupy rows [0, n_cq); cohorts follow in sorted order for
         determinism. Cyclic cohorts (and their CQs) are excluded — the
         caller reports them inactive, mirroring the reference's
-        snapshot skipping cyclic CQs.
+        snapshot skipping cyclic CQs. ``cyclic`` is this state's
+        ``cyclic_cohorts()``, walked here if None.
         """
-        cyclic = self.cyclic_cohorts()
+        if cyclic is None:
+            cyclic = self.cyclic_cohorts()
         active_cqs = [cq for cq in cq_names if self.cq_parent.get(cq) not in cyclic]
         cohort_names = sorted(name for name in self.cohorts if name not in cyclic)
 

@@ -48,7 +48,7 @@ from kueue_tpu.utils.priority import priority_of
 
 # the cycle's phases and sub-phases (tracing/phases.py)
 _HEADS = Phase("cycle.heads")
-_SNAPSHOT = Phase("cycle.snapshot")
+_SNAPSHOT = Phase("cycle.snapshot", counter="forest_walks")
 _NOMINATE = Phase("cycle.nominate")
 _ADMIT = Phase("cycle.admit")
 _PREVALIDATE = Phase("cycle.prevalidate", counter="prevalidated")
@@ -172,7 +172,8 @@ class CycleTrace:
     # solve's encode / launch / fetch / decode) and admit ("park");
     # tracing.names.CYCLE_SUBPHASE_SPANS maps each to its parent
     sub: Dict[str, float] = field(default_factory=dict)
-    # what the phases counted: heads prevalidated, walked on the host,
+    # what the phases counted: cohort-cycle walks of the snapshot
+    # ("forest_walks"), heads prevalidated, walked on the host,
     # deferred to victim search, parked; heads lowered, unrepresentable
     # (lowering's fallback), device launches, heads the kernel returned
     # non-Fit
@@ -409,8 +410,11 @@ class Scheduler:
             return self._contain_cycle_failure(heads, result, trace, t0, exc)
 
     def _schedule_guarded(self, heads, result, trace, t0) -> CycleResult:
-        with phase(_SNAPSHOT, trace):
+        forest = self.cache.forest
+        with phase(_SNAPSHOT, trace) as snap_region:
+            walks = forest.walks
             snapshot = take_snapshot(self.cache)
+            snap_region.count = forest.walks - walks
         self.guard.phase_checkpoint("snapshot")
         with phase(_NOMINATE, trace):
             entries, device_plan = self._nominate(heads, snapshot)

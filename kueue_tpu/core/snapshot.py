@@ -362,17 +362,19 @@ def take_snapshot(cache: Cache) -> Snapshot:
 
     Inactive ClusterQueues (stopped, missing flavors/checks/topologies,
     cyclic cohorts) are excluded and reported, mirroring
-    InactiveClusterQueueSets.
+    InactiveClusterQueueSets. The cohort forest is walked for cycles
+    once, and every status check and the flattening share that set.
     """
+    cyclic = cache.forest.cyclic_cohorts()
     active_names: List[str] = []
     inactive: List[str] = []
     for name in sorted(cache.cluster_queues):
-        if cache.cluster_queue_status(name).active:
+        if cache.cluster_queue_status(name, cyclic).active:
             active_names.append(name)
         else:
             inactive.append(name)
 
-    flat = cache.forest.flatten(active_names)
+    flat = cache.forest.flatten(active_names, cyclic)
     inactive.extend(flat.inactive_cqs)
 
     # FR universe: every (flavor, resource) cell defined by any active CQ

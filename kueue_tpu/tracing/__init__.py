@@ -22,6 +22,7 @@ from kueue_tpu.tracing.names import (
     CYCLE_SPAN_NAMES,
     CYCLE_SUBPHASE_SPANS,
     EVENT_SPANS,
+    PHASE_COUNTERS,
     PHASE_NAMES,
     RUNTIME_PHASE_NAMES,
     SPAN_NAMES,
@@ -42,6 +43,7 @@ __all__ = [
     "CYCLE_SPAN_NAMES",
     "CYCLE_SUBPHASE_SPANS",
     "EVENT_SPANS",
+    "PHASE_COUNTERS",
     "PHASE_NAMES",
     "RUNTIME_PHASE_NAMES",
     "SPAN_NAMES",

@@ -98,6 +98,21 @@ RUNTIME_PHASE_NAMES = frozenset(
 # every name a ``tracing.Phase`` may carry
 PHASE_NAMES = CYCLE_SPAN_NAMES | RUNTIME_PHASE_NAMES
 
+# phase name -> the one counter its regions' counts are added to (a
+# ``CycleTrace.counts`` key, served on ``/debug/cycles``, or a
+# ``PhaseTotals`` counter); a phase not listed counts nothing
+PHASE_COUNTERS = {
+    "cycle.snapshot": "forest_walks",
+    "cycle.prevalidate": "prevalidated",
+    "cycle.assign": "walked",
+    "cycle.victims": "deferred",
+    "cycle.encode": "lowered",
+    "cycle.launch": "launches",
+    "cycle.park": "parked",
+    "loop.fingerprint": "loop.fingerprint_walks",
+    "ingest.apply": "ingest.objects",
+}
+
 # CycleTrace phase key -> cycle span name (the lowering used by
 # Tracer.record_cycle; a phase without a registry entry is a bug in
 # the emitting site, same contract as classify_inadmissible_message)

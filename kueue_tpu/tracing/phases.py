@@ -24,7 +24,7 @@ import threading
 import time as _time
 from typing import Dict
 
-from kueue_tpu.tracing.names import PHASE_NAMES
+from kueue_tpu.tracing.names import PHASE_COUNTERS, PHASE_NAMES
 
 
 @functools.cache
@@ -38,7 +38,8 @@ class Phase:
     """One registered program region: its registry ``name``, the
     ``key`` it accumulates under in a ``CycleTrace`` (the name after
     its family prefix), its profiler ``label`` and the ``counter`` its
-    region's count is added to ("" = none)."""
+    region's count is added to ("" = none; else the phase's entry in
+    ``PHASE_COUNTERS``)."""
 
     __slots__ = ("name", "key", "label", "counter")
 
@@ -47,6 +48,11 @@ class Phase:
             raise ValueError(
                 f"phase name {name!r} is not in the closed registry "
                 "(kueue_tpu.tracing.names.PHASE_NAMES)"
+            )
+        if counter and PHASE_COUNTERS.get(name) != counter:
+            raise ValueError(
+                f"counter {counter!r} of phase {name!r} is not its "
+                "registered one (kueue_tpu.tracing.names.PHASE_COUNTERS)"
             )
         self.name = name
         self.key = name.partition(".")[2]

@@ -1,6 +1,9 @@
 """Cache + snapshot behavior (pkg/cache parity) and np/JAX kernel parity."""
 
+import random
+
 import numpy as np
+import pytest
 
 from kueue_tpu.models import (
     AdmissionCheck,
@@ -16,6 +19,7 @@ from kueue_tpu.models import (
 from kueue_tpu.models.constants import StopPolicy
 from kueue_tpu.models.workload import PodSet
 from kueue_tpu.core.cache import Cache
+from kueue_tpu.core.hierarchy import CohortForest
 from kueue_tpu.core.snapshot import take_snapshot
 from kueue_tpu.core.workload_info import admission_usage, make_admission
 from kueue_tpu.resources import FlavorResource
@@ -194,6 +198,172 @@ def test_snapshot_cohort_members():
     assert snap.cohort_members("cq-a") == {"cq-a", "cq-b"}
     assert snap.cohort_members("lone") == {"lone"}
     assert not snap.has_cohort("lone")
+
+
+def cq_in(name, cohort, cpu="1"):
+    return ClusterQueue(
+        name=name,
+        cohort=cohort,
+        resource_groups=(
+            ResourceGroup(("cpu",), (FlavorQuotas.build("on-demand", {"cpu": cpu}),)),
+        ),
+    )
+
+
+def recorded_statuses(cache):
+    """take_snapshot(cache), and the status it used for each
+    ClusterQueue (read through a wrapper on the cache)."""
+    seen = {}
+    status = cache.cluster_queue_status
+
+    def wrapper(name, *args, **kwargs):
+        seen[name] = status(name, *args, **kwargs)
+        return seen[name]
+
+    cache.cluster_queue_status = wrapper
+    try:
+        snap = take_snapshot(cache)
+    finally:
+        del cache.cluster_queue_status
+    return snap, seen
+
+
+# cohort edges (name, parent); "c0" holds a cycle in each, and every
+# cohort named here gets two ClusterQueues
+CYCLES = {
+    "two": [("c0", "c1"), ("c1", "c0")],
+    "three": [("c0", "c1"), ("c1", "c2"), ("c2", "c0")],
+    # a tree hanging below a two-cohort cycle: c2 and c3 are children of
+    # c1, c4 a child of c3
+    "tree_below": [("c0", "c1"), ("c1", "c0"), ("c2", "c1"), ("c3", "c1"),
+                   ("c4", "c3")],
+}
+
+
+@pytest.mark.parametrize("shape", sorted(CYCLES))
+def test_cohort_cycle_makes_its_cluster_queues_inactive(shape):
+    cache = Cache()
+    cache.add_or_update_flavor(ResourceFlavor(name="on-demand"))
+    edges = CYCLES[shape]
+    for name, parent in edges:
+        cache.add_or_update_cohort(Cohort(name=name, parent=parent))
+    cyclic_cqs = set()
+    for name, _ in edges:
+        for k in range(2):
+            cache.add_or_update_cluster_queue(cq_in(f"{name}-cq{k}", name))
+            cyclic_cqs.add(f"{name}-cq{k}")
+    # a healthy cohort beside the cycle, and a ClusterQueue in no cohort
+    cache.add_or_update_cohort(Cohort(name="ok"))
+    cache.add_or_update_cluster_queue(cq_in("ok-cq", "ok"))
+    cache.add_or_update_cluster_queue(cq_in("lone", None))
+
+    snap, used = recorded_statuses(cache)
+    assert set(snap.inactive_cqs) == cyclic_cqs
+    assert not cyclic_cqs & set(snap.flat.cq_names)
+    assert set(snap.flat.cq_names) == {"ok-cq", "lone"}
+    assert not {name for name, _ in edges} & set(snap.flat.cohort_names)
+    for name in cyclic_cqs:
+        assert used[name].reasons == ("CohortCycle",)
+        assert not used[name].active
+    assert used["ok-cq"].active and used["lone"].active
+    # each status called alone equals the one the snapshot used
+    assert used == {name: cache.cluster_queue_status(name) for name in used}
+
+    # break the cycle: c0 becomes a root
+    cache.add_or_update_cohort(Cohort(name="c0"))
+    snap, used = recorded_statuses(cache)
+    assert snap.inactive_cqs == ()
+    assert cyclic_cqs <= set(snap.flat.cq_names)
+    assert all(st.active for st in used.values())
+    assert used == {name: cache.cluster_queue_status(name) for name in used}
+    # the ClusterQueues in c0's former cycle share one tree again
+    assert snap.cohort_members("c0-cq0") >= {"c0-cq0", "c1-cq0", "c1-cq1"}
+
+
+def chain_in_cycle(forest, cohort):
+    """Whether following parent edges up from ``cohort`` comes back to
+    a cohort already passed: the per-ClusterQueue check, written
+    without ``cyclic_cohorts``."""
+    seen = set()
+    cur = cohort
+    while cur in forest.cohorts and forest.cohorts[cur].parent:
+        if cur in seen:
+            return True
+        seen.add(cur)
+        cur = forest.cohorts[cur].parent
+    return False
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_hoisted_cycle_check_equals_per_cq_recomputation(seed):
+    rng = random.Random(seed)
+    cache = Cache()
+    cache.add_or_update_flavor(ResourceFlavor(name="on-demand"))
+    n_cohorts, n_cqs = rng.randint(1, 40), rng.randint(1, 120)
+    # parents may name a cohort that has no Cohort object (implicit)
+    names = [f"c{i}" for i in range(n_cohorts)]
+    parents = names + [f"ghost{i}" for i in range(4)] + [None]
+    for name in names:
+        if rng.random() < 0.8:
+            cache.add_or_update_cohort(
+                Cohort(name=name, parent=rng.choice([p for p in parents if p != name]))
+            )
+    for name in rng.sample(names, k=min(3, n_cohorts)):
+        if rng.random() < 0.5:
+            # a self-loop, as the forest takes it from an edge no model
+            # validated
+            cache.forest.update_cohort(name, name)
+    for i in range(n_cqs):
+        cache.add_or_update_cluster_queue(cq_in(f"cq{i}", rng.choice(parents)))
+    for _ in range(rng.randint(0, 10)):
+        if rng.random() < 0.5:
+            cache.delete_cluster_queue(f"cq{rng.randrange(n_cqs)}")
+        else:
+            cache.delete_cohort(rng.choice(names))
+
+    cyclic = cache.forest.cyclic_cohorts()
+    walks = cache.forest.walks
+    snap, used = recorded_statuses(cache)
+    assert cache.forest.walks == walks + 1
+    # the oracle: every ClusterQueue's status recomputed on its own
+    oracle = {name: cache.cluster_queue_status(name) for name in cache.cluster_queues}
+    assert used == oracle
+    assert sorted(snap.inactive_cqs) == sorted(
+        name for name, st in oracle.items() if not st.active
+    )
+    assert set(snap.flat.cq_names) == {n for n, st in oracle.items() if st.active}
+    for name in cache.cluster_queues:
+        parent = cache.forest.cq_parent.get(name)
+        in_cycle = parent is not None and chain_in_cycle(cache.forest, parent)
+        assert ("CohortCycle" in oracle[name].reasons) == in_cycle, name
+        assert cache.forest.cq_in_cycle(name, cyclic) == in_cycle
+    assert not cyclic & set(snap.flat.cohort_names)
+
+
+def test_one_snapshot_walks_the_forest_once_at_the_cells_shape(monkeypatch):
+    """1,002 ClusterQueues in 167 cohorts of 6: one snapshot makes one
+    cycle walk, not one per ClusterQueue."""
+    cache = Cache()
+    cache.add_or_update_flavor(ResourceFlavor(name="on-demand"))
+    for c in range(167):
+        for k in range(6):
+            cache.add_or_update_cluster_queue(cq_in(f"cq-{c}-{k}", f"cohort-{c}", "20"))
+    calls = []
+    walk = CohortForest.cyclic_cohorts
+
+    def counting(self):
+        calls.append(1)
+        return walk(self)
+
+    monkeypatch.setattr(CohortForest, "cyclic_cohorts", counting)
+    walks = cache.forest.walks
+    snap = take_snapshot(cache)
+    assert len(calls) == 1
+    assert cache.forest.walks == walks + 1
+    assert snap.flat.n_cq == 1002 and len(snap.flat.cohort_names) == 167
+    # a status asked alone still walks for itself
+    assert cache.cluster_queue_status("cq-0-0").active
+    assert len(calls) == 2
 
 
 def test_np_jax_kernel_parity():

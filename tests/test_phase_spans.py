@@ -37,6 +37,7 @@ from kueue_tpu.tracing import (
     CYCLE_SPAN_NAMES,
     CYCLE_SUBPHASE_SPANS,
     EVENT_SPANS,
+    PHASE_COUNTERS,
     PHASE_NAMES,
     RUNTIME_PHASE_NAMES,
     SPAN_NAMES,
@@ -201,7 +202,7 @@ def test_counts_equal_hand_counts(scenario):
     fifth = first[4]
     assert fifth.resolution == "host"
     assert fifth.counts == {
-        "prevalidated": N_CQ, "lowered": N_CQ, "unrepresentable": 0,
+        "forest_walks": 1, "prevalidated": N_CQ, "lowered": N_CQ, "unrepresentable": 0,
         "launches": 1, "returned": N_CQ, "walked": N_CQ,
         "deferred": N_CQ, "parked": N_CQ,
     }
@@ -217,9 +218,11 @@ def test_counts_equal_hand_counts(scenario):
     assert t.counts["lowered"] == t.counts["returned"] == N_CQ
     assert t.counts["deferred"] == N_CQ
     assert t.counts["parked"] == N_CQ  # pending the preemption
-    # over the scenario: every cycle launched once
+    # over the scenario: every cycle launched once, and its snapshot
+    # walked the cohort forest for cycles once
     cycles = first + second
     assert sum(t.counts["launches"] for t in cycles) == len(cycles)
+    assert [t.counts["forest_walks"] for t in cycles] == [1] * len(cycles)
     assert sum(t.counts["lowered"] for t in cycles) == N_CQ * len(cycles)
 
 
@@ -368,6 +371,13 @@ def test_phase_names_are_closed_and_checked_where_defined():
     assert (p.key, p.label) == ("report", "kueue.loop.report")
     assert PHASE_NAMES == CYCLE_SPAN_NAMES | RUNTIME_PHASE_NAMES
     assert not RUNTIME_PHASE_NAMES & SPAN_NAMES
+    # a phase counts under its registered counter only
+    assert set(PHASE_COUNTERS) <= PHASE_NAMES
+    assert Phase("cycle.snapshot", counter="forest_walks").counter == "forest_walks"
+    with pytest.raises(ValueError, match="PHASE_COUNTERS"):
+        Phase("cycle.snapshot", counter="walked")
+    with pytest.raises(ValueError, match="PHASE_COUNTERS"):
+        Phase("loop.report", counter="loop.reports")
 
 
 def test_region_adds_seconds_and_count_unless_it_raises():
