@@ -15,8 +15,7 @@ Everything runs in this one process, so only it holds the chip:
    so nothing fails over), then with ``solver_path="device"``, where
    device faults propagate instead of failing over to the host.
 3. Preempt phase. The contended cross-CQ-reclaim scope (5,000 pending
-   over 1,000 CQs in hoarder/reclaimer cohorts, shaped like
-   ``bench.py:contended_drain_bench``) runs through
+   over 1,000 CQs in hoarder/reclaimer cohorts) runs through
    ``ClusterRuntime.run_until_idle`` on the runtime's running clock.
 
 Each phase is held to a reference: a ``ClusterRuntime(solver_path=
@@ -90,11 +89,11 @@ def plain_deployment(
     n_flavors: int = N_FLAVORS,
     wl_per_cq: int = WL_PER_CQ,
 ) -> dict:
-    """The north-star deployment in the flat batch wire format, with the
-    shapes of ``bench.py:_build_drain_loop_rt``: per flavor cpu nominal
-    8-63 (borrowing 8-31) and memory 16-127Gi (borrowing 16-63Gi);
-    workloads of 1-4 pods asking 1-15 cpu and 1-31Gi at four priority
-    levels, spread round-robin over the LocalQueues."""
+    """The north-star deployment in the flat batch wire format: per
+    flavor cpu nominal 8-63 (borrowing 8-31) and memory 16-127Gi
+    (borrowing 16-63Gi); workloads of 1-4 pods asking 1-15 cpu and
+    1-31Gi at four priority levels, spread round-robin over the
+    LocalQueues."""
     rng = np.random.default_rng(seed)
     flavors = [f"fl-{i}" for i in range(n_flavors)]
     cpu_nom = rng.integers(8, 64, size=(n_cq, n_flavors))
@@ -167,11 +166,11 @@ def plain_deployment(
 
 def populate_contended(rt, seed: int, n_cq: int = N_CQ,
                        cohort_size: int = CONTENDED_COHORT_SIZE) -> None:
-    """The contended reclaim scope of ``bench.py:contended_drain_bench``
-    added to ``rt``: per cohort, half the CQs are hoarders admitted
-    above nominal (8 x 3 cpu against 16, never preempting) and half are
-    reclaimers (4 x 2 cpu admitted) whose higher-priority backlog of 10
-    each can only start by cross-CQ reclaim or within-CQ preemption."""
+    """The contended cross-CQ reclaim scope added to ``rt``: per
+    cohort, half the CQs are hoarders admitted above nominal (8 x 3 cpu
+    against 16, never preempting) and half are reclaimers (4 x 2 cpu
+    admitted) whose higher-priority backlog of 10 each can only start
+    by cross-CQ reclaim or within-CQ preemption."""
     from kueue_tpu.core.workload_info import make_admission
     from kueue_tpu.models import (
         ClusterQueue,

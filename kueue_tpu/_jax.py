@@ -27,7 +27,7 @@ __all__ = ["jax", "jnp", "lax", "use_compile_cache"]
 def use_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns its directory.
 
-    Called by the entry points (server, CLI, bench payload, chip smoke)
+    Called by the entry points (server, CLI, benchmark, chip smoke)
     before their first compile, never on import, so tests write no
     cache. ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting
     and wins. Otherwise the cache is ``<checkout>/.jax_cache``: a fixed

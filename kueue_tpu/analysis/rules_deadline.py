@@ -42,8 +42,8 @@ from kueue_tpu.analysis.core import (
 )
 
 #: path prefixes where the discipline is enforced: the control loops
-#: that keep running while a worker limps. CLI one-shots, tests and
-#: bench scripts stay out — a human is watching those.
+#: that keep running while a worker limps. CLI one-shots and tests
+#: stay out — a human is watching those.
 SCOPE_PREFIXES = (
     "kueue_tpu/federation/",
     "kueue_tpu/replica/",

@@ -8,8 +8,9 @@ Scheduler exactly for preemption-free, fully-representable backlogs
 (asserted in tests/test_drain.py); workloads the dense form can't
 express are reported in ``fallback`` for the normal cycle loop.
 
-Use cases: the 50k-pending north-star drain (bench.py), bulk import
-(cli import), and capacity what-if planning.
+Use cases: the runtime's bulk path (``ClusterRuntime.bulk_drain``, the
+50k-pending north-star drain of ``chip_smoke.py``), bulk import (cli
+import), and capacity what-if planning.
 """
 
 from __future__ import annotations
@@ -937,8 +938,8 @@ class PanelTuner:
                 self._clean[final] = 0
 
 
-# process-wide default tuner: the production runtime and the bench
-# share it so the shape converges to the live workload mix
+# process-wide default tuner: every drain in the process shares it so
+# the shape converges to the live workload mix
 _PANEL_TUNER = PanelTuner()
 
 # operator override (server --panel-widths): a fixed schedule replaces

@@ -378,7 +378,7 @@ class ResidentEncoder:
         self._paths = None
         self._usage = None  # device [N, FR]
         self._usage_host = None  # numpy mirror of the device content
-        # telemetry (SIGUSR2 dump / BENCH notes)
+        # telemetry (SIGUSR2 dump)
         self.full_encodes = 0
         self.delta_rounds = 0
         self.delta_rows = 0

@@ -533,10 +533,6 @@ class SolverGuard:
         self.contained_cycles = 0
         self.deadline_breaches = 0
         self.last_divergence: Optional[dict] = None
-        # wall time spent inside sampled divergence checks (the mirror
-        # re-solve + compare) — bench.py --failover reports it as a
-        # fraction of cycle time against the <=10% budget
-        self.divergence_check_s = 0.0
         # per-cycle deadline tracking (begin_cycle/phase_checkpoint)
         self._cycle_t0: Optional[float] = None
         self._cycle_compile0 = 0.0
@@ -786,7 +782,6 @@ class SolverGuard:
             self.metrics.solver_divergence_checks_total.inc()
         host_outcome, host_sig = host_solve()
         dt = _time.perf_counter() - t0
-        self.divergence_check_s += dt
         if self.tracer is not None:
             self.tracer.add_cycle_span(
                 "cycle.divergence_check", dt,
@@ -869,7 +864,6 @@ class SolverGuard:
         host = self._mirror_of(snapshot, lowered)
         bad = results_match(device_res, host)
         dt = _time.perf_counter() - t0
-        self.divergence_check_s += dt
         if self.tracer is not None:
             self.tracer.add_cycle_span(
                 "cycle.divergence_check", dt,

@@ -463,7 +463,7 @@ class ResidentCycleState:
         self._roots = None
         self._usage = None  # device [N, FR]
         self._usage_host = None  # numpy mirror of the device content
-        # telemetry (BENCH notes / debugging)
+        # telemetry (debugging)
         self.full_uploads = 0
         self.delta_cycles = 0
         self.delta_rows = 0

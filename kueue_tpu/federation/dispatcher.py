@@ -403,7 +403,7 @@ class FederationDispatcher:
     def _deadline_for(self, name: str, cap_s: Optional[float] = None):
         """Per-call adaptive deadline clamp(k*p99, floor, cap), or None
         (transport constructor default) when adaptive deadlines are
-        off — the fixed-timeout baseline the grayfail bench A/Bs."""
+        off — the fixed-timeout baseline."""
         if not self.adaptive_deadlines:
             return None  # fixed-timeout baseline: constructor default
         return self.worker_health.deadline_s(name, cap_s=cap_s)

@@ -86,8 +86,6 @@ class GlobalScheduler:
         self.last_report: Optional[dict] = None
         self.rescores = 0
         self.rebalances = 0
-        self.rescore_ms_total = 0.0  # perf accounting (bench/perf)
-        self.aggregate_ms_total = 0.0
         dispatcher.global_scheduler = self
         m = getattr(self.runtime, "metrics", None)
         if m is not None:
@@ -265,8 +263,6 @@ class GlobalScheduler:
             m.global_pending_workloads.set(len(snap.keys))
             m.global_workers_reachable.set(reachable)
         if apply:
-            self.rescore_ms_total += duration_s * 1e3
-            self.aggregate_ms_total += aggregate_s * 1e3
             self.last_report = report
         return report
 

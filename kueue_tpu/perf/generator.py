@@ -190,7 +190,7 @@ class GeneratedWorkload:
     creation_s: float
 
 
-# ---- sustained arrival streams (bench.py --serve) ----
+# ---- sustained arrival streams ----
 @dataclass
 class ArrivalProcess:
     """An open-loop arrival process for sustained-traffic serving
@@ -240,8 +240,7 @@ def arrival_stream(
     """Materialize an ArrivalProcess as creation-time-stamped
     workloads round-robined over ``lq_names`` (class round-robin like
     the batch generator). The caller replays them against a live
-    control plane at their creation offsets — perf/runner for
-    virtual-time runs, bench.py --serve for wall-clock serving."""
+    control plane at their creation offsets."""
     out: List[GeneratedWorkload] = []
     for i, t in enumerate(proc.arrival_times(rng)):
         wc = proc.classes[i % len(proc.classes)]

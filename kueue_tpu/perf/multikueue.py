@@ -347,186 +347,6 @@ def run_multikueue(
     )
 
 
-# ---- federation-at-scale: the REAL dispatcher at 50+ workers ----
-@dataclass
-class FedScaleResult:
-    """One fan-out scaling run through FederationDispatcher +
-    GlobalScheduler (not the MultiKueueController shim above): N full
-    worker control planes, planner-ranked dispatch with fanout, the
-    journaled retraction protocol, and the batched global rescore
-    loop driving rebalances as capacity frees in waves."""
-
-    wall_s: float
-    virtual_s: float
-    n_workers: int
-    total: int
-    admitted: int
-    passes: int
-    fanout_pass_ms: float  # first full dispatch pass (mirror fan-out)
-    rescore_passes: int
-    rescore_ms_per_cycle: float  # batched scoring kernel, mean
-    aggregate_ms_per_cycle: float  # snapshot aggregation, mean
-    rebalances: int
-    retractions_acked: int
-
-    @property
-    def dispatches_per_s(self) -> float:
-        return self.admitted / max(self.wall_s, 1e-9)
-
-
-def run_federation_scale(
-    n_workers: int = 50,
-    n_workloads: int = 200,
-    fanout: int = 1,
-    wl_cpu: int = 1,
-    runtime_s: float = 300.0,
-    hysteresis_s: float = 30.0,
-    max_passes: int = 400,
-) -> FedScaleResult:
-    """Drive ``n_workloads`` through the real dispatcher at
-    ``n_workers`` in-process worker planes until every workload admits.
-
-    Capacity is deliberately heterogeneous (worker i holds
-    ``1 + i % 3`` admission slots) and ``fanout`` narrow, so early
-    placements park on congested workers and the global rescore loop
-    has real rebalancing work as finished workloads free capacity in
-    waves — the fan-out scaling scenario the ROADMAP names."""
-    import heapq as _heapq
-
-    from kueue_tpu.federation import FederationDispatcher, GlobalScheduler
-
-    clock = FakeClock(0.0)
-    workers: Dict[str, ClusterRuntime] = {}
-    clusters: Dict[str, MultiKueueCluster] = {}
-    for i in range(n_workers):
-        name = f"w{i:03d}"
-        rt = ClusterRuntime(clock=clock, use_solver=False)
-        rt.add_flavor(ResourceFlavor(name="default"))
-        slots = (1 + i % 3) * wl_cpu
-        rt.add_cluster_queue(
-            ClusterQueue(
-                name="cq",
-                namespace_selector={},
-                resource_groups=(
-                    ResourceGroup(
-                        ("cpu",),
-                        (FlavorQuotas.build("default", {"cpu": str(slots)}),),
-                    ),
-                ),
-            )
-        )
-        rt.add_local_queue(
-            LocalQueue(namespace="ns", name="lq", cluster_queue="cq")
-        )
-        workers[name] = rt
-        clusters[name] = MultiKueueCluster(name=name, runtime=rt)
-    manager = ClusterRuntime(clock=clock, use_solver=False)
-    disp = FederationDispatcher(
-        manager,
-        clusters=clusters,
-        fanout=fanout,
-        drive_inprocess=True,
-        worker_lost_timeout=1e9,
-        heartbeat_interval_s=1e9,  # the pass traffic IS the probe here
-    )
-    gs = GlobalScheduler(
-        disp, hysteresis_s=hysteresis_s, rescore_interval_s=runtime_s / 4,
-    )
-    for i in range(n_workloads):
-        manager.add_workload(
-            Workload(
-                namespace="ns",
-                name=f"fs-{i:05d}",
-                queue_name="lq",
-                pod_sets=(PodSet.build("main", 1, {"cpu": str(wl_cpu)}),),
-            )
-        )
-
-    t_start = time.perf_counter()
-    t0 = time.perf_counter()
-    manager.run_until_idle()
-    fanout_pass_ms = (time.perf_counter() - t0) * 1e3
-
-    finish_events: List[tuple] = []
-    scheduled: set = set()
-    seq = 0
-    passes = 1
-    while passes < max_passes:
-        # schedule finishes for every newly reserving remote copy —
-        # freed capacity is what pulls the next wave (and what makes
-        # a parked workload's forecast beat its congested placement)
-        for name, rt in workers.items():
-            for rwl in rt.workloads.values():
-                if (
-                    rwl.has_quota_reservation
-                    and (name, rwl.key) not in scheduled
-                ):
-                    scheduled.add((name, rwl.key))
-                    _heapq.heappush(
-                        finish_events,
-                        (clock.now() + runtime_s, seq, name, rwl.key),
-                    )
-                    seq += 1
-        if all(w.is_finished for w in manager.workloads.values()):
-            break
-        if finish_events:
-            t = max(clock.now(), finish_events[0][0])
-            clock.set(t)
-            while finish_events and finish_events[0][0] <= clock.now():
-                _, _, name, key = _heapq.heappop(finish_events)
-                rwl = workers[name].workloads.get(key)
-                if rwl is None or rwl.is_finished:
-                    continue
-                rwl.set_condition(
-                    WorkloadConditionType.FINISHED, True, "JobFinished",
-                    "Job finished successfully", now=clock.now(),
-                )
-                workers[name].on_workload_finished(rwl)
-        else:
-            clock.advance(runtime_s / 2)
-        manager.run_until_idle()
-        passes += 1
-    wall_s = time.perf_counter() - t_start
-    admitted = sum(
-        1
-        for w in manager.workloads.values()
-        if w.is_finished or w.is_admitted
-    )
-    return FedScaleResult(
-        wall_s=wall_s,
-        virtual_s=clock.now(),
-        n_workers=n_workers,
-        total=n_workloads,
-        admitted=admitted,
-        passes=passes,
-        fanout_pass_ms=fanout_pass_ms,
-        rescore_passes=gs.rescores,
-        rescore_ms_per_cycle=(
-            gs.rescore_ms_total / gs.rescores if gs.rescores else 0.0
-        ),
-        aggregate_ms_per_cycle=(
-            gs.aggregate_ms_total / gs.rescores if gs.rescores else 0.0
-        ),
-        rebalances=gs.rebalances,
-        retractions_acked=_acked_retractions(manager),
-    )
-
-
-def _acked_retractions(manager) -> int:
-    """Cumulative acked retractions from the metrics surface (the
-    in-memory maps are GCd with their finished dispatch states)."""
-    import re as _re
-
-    m = getattr(manager, "metrics", None)
-    if m is None:
-        return 0
-    match = _re.search(
-        r'kueue_multikueue_retractions_total\{outcome="acked"\} (\d+)',
-        m.registry.expose(),
-    )
-    return int(match.group(1)) if match else 0
-
-
 @dataclass
 class MKRangeSpec:
     """Floors for the at-scale dispatch run (the multikueue e2e's

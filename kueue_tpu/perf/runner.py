@@ -5,8 +5,8 @@ scheduling core only (queue manager + cache + scheduler — no webhooks
 or job integrations) through a discrete-event simulation: workload
 creations at generator timestamps, finishes at admission + runtime.
 Virtual time decouples the measured admission ORDER/latency semantics
-from host speed; wall time of the solve itself is measured separately
-(bench.py).
+from host speed; it measures no wall time (``python3 -m benchmark``
+does, on the served path).
 """
 
 from __future__ import annotations

@@ -90,8 +90,7 @@ class RecordingTransport(_ChaosTransport):
     """Passive shim: appends the injected-clock duration of every
     exchange (including ones that raise) to ``sink`` — wrap it OUTSIDE
     the chaos wrappers so the recorded latency is exactly what the
-    dispatcher observed, injected delay and all. The grayfail bench
-    A/B reads its dispatch p95 from these sinks."""
+    dispatcher observed, injected delay and all."""
 
     def __init__(self, inner, clock, sink=None, default_deadline_s=10.0):
         super().__init__(inner, clock, default_deadline_s)

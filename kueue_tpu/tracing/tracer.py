@@ -204,9 +204,9 @@ class Tracer:
         self._pending_counts: Dict[str, int] = {}  # guarded by: _lock
         self._pending_n = 0  # guarded by: _lock
         # exact self-accounting: wall seconds spent inside the tracer's
-        # recording entry points (the guard.divergence_check_s pattern)
-        # — the <2% overhead budget is read on THIS, which a noisy
-        # shared-CPU host cannot corrupt the way a wall A/B can
+        # recording entry points — the <2% overhead budget is read on
+        # THIS, which a noisy shared-CPU host cannot corrupt the way a
+        # wall A/B can
         self.self_time_s = 0.0
         # batched queue-to-admission waits (cq -> [seconds]), same
         # rationale: one histogram label resolution per flush, not per

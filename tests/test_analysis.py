@@ -1017,8 +1017,8 @@ class TestDeadlineDisciplineRule:
         ) == []
 
     def test_out_of_scope_files_are_ignored(self, tmp_path):
-        # the discipline binds control loops; CLI one-shots, bench
-        # scripts and the server glue stay out of scope
+        # the discipline binds control loops; CLI one-shots and the
+        # server glue stay out of scope
         assert run_fixture(
             tmp_path,
             {"kueue_tpu/cli/x.py": DEADLINE_BAD},

@@ -202,17 +202,20 @@ class TestDeltaChain:
             assert_chain_matches_live(rt, state_dir)
         journal.close()
 
-    def test_delta_serializes_only_changed(self, tmp_path):
-        """O(changed): 60 live workloads, 2 touched — the delta must
-        carry 2 objects, not 60."""
-        rt, journal, ckpt, state_dir = chain_rt(tmp_path, n_wl=60)
+    @pytest.mark.parametrize("n_live", [60, 500, 2_500])
+    def test_delta_serializes_only_changed(self, tmp_path, n_live):
+        """O(changed): the same 32 touched workloads at 60, 500 and
+        2,500 live — the delta carries 32 objects at every size, not
+        the live count."""
+        touched = 32
+        rt, journal, ckpt, state_dir = chain_rt(tmp_path, n_wl=n_live)
         assert ckpt.checkpoint(rt)
-        for k in range(2):
+        for k in range(touched):
             wl = rt.workloads[f"ns/wl-{k}"]
             rt.add_workload(dataclasses.replace(wl, priority=7))
         assert ckpt.checkpoint(rt)
         assert ckpt.last_kind == "delta"
-        assert ckpt.last_objects == 2
+        assert ckpt.last_objects == touched
         assert_chain_matches_live(rt, state_dir)
         journal.close()
 

@@ -1,13 +1,13 @@
 """Ahead-of-time compiles of the drain entry points for a v5e chip.
 
-Each test builds one drain problem at the size ``bench.py`` (and so
-``chip_smoke.py``) runs it, catches the arguments the lowering hands to
-the jitted kernel instead of running it on the CPU, and compiles that
-call for one chip of a described ``v5e:2x2`` topology — or, for the
-mesh path, for all four chips with the shardings the drain would place
-its inputs with: the TPU compiler refuses here what it would refuse on
-the chip, at no chip time. Nothing runs, so these say nothing about
-results or speed.
+Each test builds one drain problem at the size ``chip_smoke.py`` runs
+it (the others from ``tests/drain_scopes.py``), catches the arguments
+the lowering hands to the jitted kernel instead of running it on the
+CPU, and compiles that call for one chip of a described ``v5e:2x2``
+topology — or, for the mesh path, for all four chips with the
+shardings the drain would place its inputs with: the TPU compiler
+refuses here what it would refuse on the chip, at no chip time.
+Nothing runs, so these say nothing about results or speed.
 
 Only one process at a time may load libtpu, so the topology is described
 inside a module fixture of this one file (never at import time): the
@@ -20,17 +20,34 @@ import os
 import pytest
 
 from kueue_tpu._jax import jax
+from tests import drain_scopes
 
-_BENCH = os.path.join(os.path.dirname(__file__), os.pardir, "bench.py")
+_SMOKE = os.path.join(os.path.dirname(__file__), os.pardir, "chip_smoke.py")
 DEVICE_BYTES = 16 << 30  # one v5e chip's HBM
 
 
 @pytest.fixture(scope="module")
-def bench():
-    spec = importlib.util.spec_from_file_location("bench_compile", _BENCH)
+def smoke():
+    spec = importlib.util.spec_from_file_location("chip_smoke", _SMOKE)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@pytest.fixture(scope="module")
+def north_star(smoke):
+    """A runtime holding the seeded 50k x 1,000-CQ north-star backlog,
+    batch-applied from ``chip_smoke.plain_deployment``."""
+    from kueue_tpu.controllers import ClusterRuntime
+    from kueue_tpu.server import KueueServer
+
+    rt = ClusterRuntime()
+    applied = KueueServer(runtime=rt, auto_reconcile=False).apply_batch(
+        smoke.plain_deployment(0)
+    )
+    assert not applied["rejected"], applied
+    rt.reconcile_once()
+    return rt
 
 
 @pytest.fixture(scope="module")
@@ -146,59 +163,76 @@ def _assert_fits(nbytes):
     assert 0 < nbytes < DEVICE_BYTES, f"{nbytes} bytes do not fit one v5e"
 
 
-def _north_star_backlog(bench):
-    """(snapshot, pending, flavors) of the seeded 50k x 1,000-CQ
-    backlog as the chunked drain loop sees it."""
+@pytest.fixture(scope="module")
+def contended(smoke):
+    """A runtime holding the contended 1,000-CQ reclaim scope of
+    ``chip_smoke.populate_contended``."""
+    from kueue_tpu.controllers import ClusterRuntime
+
+    rt = ClusterRuntime()
+    smoke.populate_contended(rt, 1)
+    rt.reconcile_once()
+    return rt
+
+
+def _backlog(rt):
+    """(snapshot, pending, flavors) of ``rt``'s backlog as the bulk
+    path hands it to a drain."""
     from kueue_tpu.core.snapshot import take_snapshot
 
-    rt = bench._build_drain_loop_rt("on", 0, chunk=4, megaloop="16")
-    rt.reconcile_once()
     snapshot = take_snapshot(rt.cache)
     return snapshot, rt.drain_backlog(snapshot), rt.cache.flavors
 
 
-def test_plain_drain_compiles(bench, one_chip, monkeypatch):
-    """The 50k x 1,000-CQ headline drain (``solve_drain_packed_jit``)."""
-    import numpy as np
+def _preempt_launch(rt, mesh=None):
+    """The contended reclaim drain (classic preemption), as a server
+    on a running clock launches it."""
+    from kueue_tpu.core.drain import run_drain_preempt
+    from kueue_tpu.core.queue_manager import queue_order_timestamp
 
+    snapshot, pending, flavors = _backlog(rt)
+    run_drain_preempt(
+        snapshot, pending, flavors,
+        timestamp_fn=lambda wl: queue_order_timestamp(
+            wl, rt.queues._ts_policy
+        ),
+        search_width=64, mesh=mesh, ordered_stamps=True,
+    )
+
+
+def test_plain_drain_compiles(smoke, north_star, one_chip, monkeypatch):
+    """The 50k x 1,000-CQ headline drain (``solve_drain_packed_jit``)."""
     from kueue_tpu.core.drain import run_drain
-    from kueue_tpu.core.snapshot import take_snapshot
     from kueue_tpu.ops import drain_kernel
 
-    def build():
-        rng = np.random.default_rng(0)
-        cache, _ = bench.build_cluster(rng)
-        pending = bench.build_backlog(rng)
-        run_drain(take_snapshot(cache), pending, cache.flavors, max_cells=3)
-
+    snapshot, pending, flavors = _backlog(north_star)
     fn, args, kw = _capture(
-        monkeypatch, drain_kernel, "solve_drain_packed_jit", build
+        monkeypatch, drain_kernel, "solve_drain_packed_jit",
+        lambda: run_drain(snapshot, pending, flavors, max_cells=3),
     )
-    assert args[2].cells.shape[0] >= bench.N_CQ  # one queue row per CQ
+    assert args[2].cells.shape[0] >= smoke.N_CQ  # one queue row per CQ
     _assert_fits(_compile_for_chip(fn, args, kw, one_chip))
 
 
-def test_preempt_drain_compiles(bench, one_chip, monkeypatch):
+def test_preempt_drain_compiles(contended, one_chip, monkeypatch):
     """The contended 1,000-CQ reclaim drain (classic preemption), as a
     server on a running clock launches it."""
-    import numpy as np
-
     from kueue_tpu.ops import drain_kernel
 
     fn, args, kw = _capture(
         monkeypatch, drain_kernel, "solve_drain_preempt_packed_jit",
-        lambda: bench.contended_drain_bench(np.random.default_rng(1)),
+        lambda: _preempt_launch(contended),
     )
-    kw["ordered_stamps"] = True
+    assert kw["ordered_stamps"]
     _assert_fits(_compile_for_chip(fn, args, kw, one_chip))
 
 
-def test_megaloop_compiles(bench, one_chip, monkeypatch):
+def test_megaloop_compiles(north_star, one_chip, monkeypatch):
     """The fused K-round drain over the north-star backlog."""
     from kueue_tpu.core.drain import launch_drain_megaloop
     from kueue_tpu.ops import megaloop_kernel
 
-    snapshot, pending, flavors = _north_star_backlog(bench)
+    snapshot, pending, flavors = _backlog(north_star)
     fn, args, kw = _capture(
         monkeypatch, megaloop_kernel, "solve_drain_megaloop_packed_jit",
         lambda: launch_drain_megaloop(
@@ -208,10 +242,9 @@ def test_megaloop_compiles(bench, one_chip, monkeypatch):
     _assert_fits(_compile_for_chip(fn, args, kw, one_chip))
 
 
-def _mesh_launch(bench, monkeypatch, mesh, kind):
-    """Capture the launch of one drain kind placed over ``mesh``."""
-    import numpy as np
-
+def _mesh_launch(rt, monkeypatch, mesh, kind):
+    """Capture the launch of one drain kind over ``rt``'s backlog,
+    placed over ``mesh``."""
     from kueue_tpu.core.drain import launch_drain_megaloop, run_drain
     from kueue_tpu.ops import drain_kernel, megaloop_kernel
     from kueue_tpu.parallel import harness
@@ -220,15 +253,11 @@ def _mesh_launch(bench, monkeypatch, mesh, kind):
         # the narrow-panel canary would run on the mesh; compile the
         # exact width alone
         monkeypatch.setattr(harness, "mesh_safe_widths", lambda m, w: w[-1:])
-        fn, args, kw = _capture(
+        return _capture(
             monkeypatch, drain_kernel, "solve_drain_preempt_packed_jit",
-            lambda: bench.contended_drain_bench(
-                np.random.default_rng(1), mesh=mesh
-            ),
+            lambda: _preempt_launch(rt, mesh),
         )
-        kw["ordered_stamps"] = True
-        return fn, args, kw
-    snapshot, pending, flavors = _north_star_backlog(bench)
+    snapshot, pending, flavors = _backlog(rt)
     if kind == "plain":
         return _capture(
             monkeypatch, drain_kernel, "solve_drain_packed_jit",
@@ -244,31 +273,65 @@ def _mesh_launch(bench, monkeypatch, mesh, kind):
 
 
 @pytest.mark.parametrize("kind", ["plain", "megaloop", "preempt"])
-def test_mesh_drain_compiles(bench, four_chips, monkeypatch, kind):
+def test_mesh_drain_compiles(
+    north_star, contended, four_chips, monkeypatch, kind
+):
     """The ``--mesh auto`` drains sharded over the four chips of a
     v5e:2x2 host — where the TPU compiler once refused the plain
     drain's packed int64 output."""
-    fn, args, kw = _mesh_launch(bench, monkeypatch, four_chips, kind)
+    rt = contended if kind == "preempt" else north_star
+    fn, args, kw = _mesh_launch(rt, monkeypatch, four_chips, kind)
     _assert_fits(_compile_placed(fn, args, kw, four_chips.size))
 
 
-@pytest.mark.parametrize(
-    "kernel, stage",
-    [
-        ("solve_drain_fair_packed_jit", "fair_drain_bench"),
-        ("solve_drain_fair_preempt_packed_jit", "fair_preempt_drain_bench"),
-        ("solve_drain_tas_packed_jit", "tas_drain_bench"),
-    ],
-)
-def test_scope_drain_compiles(bench, one_chip, monkeypatch, kernel, stage):
-    """The fair, fair-preempt and TAS drains at their bench sizes."""
-    import numpy as np
+def _fair(problem):
+    from kueue_tpu.core.drain import run_drain
 
+    run_drain(
+        problem.snapshot, problem.pending, problem.flavors,
+        timestamp_fn=problem.timestamp_fn, fair_sharing=True,
+    )
+
+
+def _fair_preempt(problem):
+    from kueue_tpu.core.drain import run_drain_fair_preempt
+
+    run_drain_fair_preempt(
+        problem.snapshot, problem.pending, problem.flavors,
+        timestamp_fn=problem.timestamp_fn,
+    )
+
+
+def _tas(problem):
+    from kueue_tpu.core.drain import run_drain_tas
+
+    run_drain_tas(
+        problem.snapshot, problem.pending, problem.flavors, problem.tas,
+        timestamp_fn=problem.timestamp_fn,
+    )
+
+
+@pytest.mark.parametrize(
+    "kernel, build, launch",
+    [
+        ("solve_drain_fair_packed_jit", drain_scopes.fair_problem, _fair),
+        (
+            "solve_drain_fair_preempt_packed_jit",
+            drain_scopes.fair_preempt_problem,
+            _fair_preempt,
+        ),
+        ("solve_drain_tas_packed_jit", drain_scopes.tas_problem, _tas),
+    ],
+    ids=["fair", "fair_preempt", "tas"],
+)
+def test_scope_drain_compiles(one_chip, monkeypatch, kernel, build, launch):
+    """The fair, fair-preempt and TAS drains at their ``drain_scopes``
+    sizes."""
     from kueue_tpu.ops import drain_kernel
 
+    problem = build()
     fn, args, kw = _capture(
-        monkeypatch, drain_kernel, kernel,
-        lambda: getattr(bench, stage)(np.random.default_rng(4)),
+        monkeypatch, drain_kernel, kernel, lambda: launch(problem)
     )
     _assert_fits(_compile_for_chip(fn, args, kw, one_chip))
 

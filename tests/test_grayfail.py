@@ -940,7 +940,7 @@ class TestLimpingWorkerAcceptance:
         )
 
     def test_immunity_beats_fixed_timeouts_on_wall_cost(self):
-        """The A/B the bench publishes, at test scale: the fixed
+        """The fixed vs adaptive A/B, at test scale: the fixed
         10 s-timeout configuration burns far more simulated time on
         the limping wire than the adaptive+probation configuration."""
         _passes_f, sim_fixed, a_fixed = self._run(

@@ -26,7 +26,7 @@ class TestGenerator:
 
 
 class TestArrivalProcess:
-    """The open-loop arrival-stream config (bench.py --serve)."""
+    """The open-loop arrival-stream config."""
 
     def test_uniform_spacing(self):
         import numpy as np

@@ -704,7 +704,7 @@ class TestHTTPReplica:
         assert all(ev["resourceVersion"] > 0 for ev in got)
 
 
-# ---- serve-bench plumbing (unit level; the full A/B runs in bench) ----
+# ---- serve plumbing (unit level) ----
 class TestServeBenchPlumbing:
     def test_http_source_against_live_leader(self, http_pair):
         p = http_pair

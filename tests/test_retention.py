@@ -2,16 +2,13 @@
 and on-disk structure the state plane grows under sustained
 arrival + completion churn must hold its configured bound — RSS and
 journal size flat at steady state is the million-workload operating
-contract (ISSUE: sustained operation, not just a burst).
+contract (sustained operation, not just a burst).
 
-Plus the soak smoke: a short deterministic run of ``bench.py``'s
-``--soak`` stage (gateway ingest + delta checkpoints + journal
-compaction + shared-volume replica) asserting the same flatness the
-hours-long ``@slow`` variant checks at scale.
+Plus a fixed run of churn through the whole durable stack (gateway
+ingest, delta checkpoints, journal compaction, a shared-volume
+replica), counted in operations on a FakeClock, not in wall time.
 """
 
-import dataclasses
-import importlib.util
 import os
 
 import numpy as np
@@ -206,68 +203,125 @@ class TestRingBoundsUnderChurn:
         journal.close()
 
 
-def _load_bench():
-    spec = importlib.util.spec_from_file_location(
-        "bench_module",
-        os.path.join(os.path.dirname(__file__), "..", "bench.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+SOAK_TICKS = 400  # 40 s of simulated churn at 0.1 s a tick
+SOAK_RATE = 15  # mean arrivals and completions a tick
 
 
-class TestSoakSmoke:
-    def test_soak_smoke_flat_and_converged(self):
-        """Deterministic short soak (same machinery as
-        ``bench.py --soak``): RSS and journal flat across windows,
-        replica converged, delta cost O(changed) across a 5x live-set
-        spread."""
-        bench = _load_bench()
-        r = bench.soak_bench(
-            np.random.default_rng(7),
-            wall_budget_s=4.0,
-            windows=2,
-            rate_per_s=150.0,
-            n_cq=4,
-            scale_live=(500, 2_500),
-            scale_touch=32,
+class TestDurableChurn:
+    def test_churn_replica_chain_and_slo_hold(self, tmp_path):
+        """A fixed run of Poisson arrival and completion churn through
+        the durable stack on a FakeClock: gateway ingest, the journal,
+        delta checkpoints every 2 simulated seconds whose commits
+        compact the journal, and a journal-tailing replica that
+        re-anchors from the chain. The chain stays within one anchor
+        plus its deltas at every checkpoint, the SLO plane is never
+        degraded, and at the end the replica's state is the leader's."""
+        from kueue_tpu import serialization as ser
+        from kueue_tpu.gateway import WriteGateway
+        from kueue_tpu.server import KueueServer
+
+        rng = np.random.default_rng(7)
+        clock = FakeClock(0.0)
+        rt = ClusterRuntime(
+            clock=clock, use_solver=False, bulk_drain_threshold=None
         )
-        assert len(r["windows"]) == 2
-        assert r["arrived"] > 0 and r["completed"] > 0
-        assert r["replica_converged"]
-        # flatness: RSS growth across the run stays in noise territory
-        assert r["rss_mb_last"] <= r["rss_mb_first"] * 1.25 + 32
-        # chain GC held the checkpoint dir bounded
-        assert r["chain_files"] <= 1 + 8
-        # O(changed): same touch count at 5x the live set must not
-        # scale the delta (generous 3x guard for CI noise)
-        assert r["scale_ratio_delta"] < 3.0
-        for s in r["scale"]:
-            assert s["delta_objects"] == 32
-        # SLO plane stayed live and green through the churn
-        for w in r["windows"]:
-            assert w["slo_attainment_min"] >= 0.0
-            assert not w["slo_degraded"]
-
-    @pytest.mark.slow
-    def test_soak_sustained_hours(self):
-        """The hours-long variant (opt-in: ``-m slow``), sized by
-        KUEUE_SOAK_S (default one hour of wall time). Same assertions,
-        tighter flatness: at steady state nothing may trend."""
-        bench = _load_bench()
-        wall_s = float(os.environ.get("KUEUE_SOAK_S", "3600"))
-        r = bench.soak_bench(
-            np.random.default_rng(7),
-            wall_budget_s=wall_s,
-            windows=max(4, int(wall_s / 300)),
-            rate_per_s=300.0,
-            n_cq=8,
-            scale_live=(10_000, 100_000),
-            scale_touch=64,
+        journal = Journal(
+            str(tmp_path / "journal"), fsync_policy="interval",
+            segment_max_bytes=256 * 1024, clock=clock,
+        ).open()
+        rt.attach_journal(journal)
+        rt.add_flavor(ResourceFlavor(name="default"))
+        n_cq = 4
+        for i in range(n_cq):
+            rt.add_cluster_queue(
+                ClusterQueue(
+                    name=f"scq-{i}", namespace_selector={},
+                    resource_groups=(
+                        ResourceGroup(
+                            ("cpu",),
+                            (FlavorQuotas.build("default", {"cpu": "128"}),),
+                        ),
+                    ),
+                )
+            )
+            rt.add_local_queue(
+                LocalQueue(
+                    namespace="soak", name=f"lq-scq-{i}",
+                    cluster_queue=f"scq-{i}",
+                )
+            )
+        state_dir = tmp_path / "state"
+        state_dir.mkdir()
+        anchor_every = 8
+        ckpt = DeltaCheckpointer(str(state_dir), anchor_every=anchor_every)
+        rt.checkpointer = ckpt.open()
+        rt.slo.configure(default_target_s=30.0)
+        gateway = WriteGateway(max_batch=4096, max_queue=65536, clock=clock)
+        # the server attaches itself as the gateway's sink
+        KueueServer(runtime=rt, auto_reconcile=True, gateway=gateway)
+        tailer = JournalTailer(
+            LocalTailSource(
+                str(tmp_path / "journal"), state_path=str(state_dir),
+                now_fn=clock.now,
+            ),
+            now_fn=clock.now,
         )
-        assert r["replica_converged"]
-        assert r["rss_mb_last"] <= r["rss_mb_first"] * 1.15 + 16
-        assert r["journal_mb_peak"] <= 64
-        assert r["scale_ratio_delta"] < 2.0
-        for w in r["windows"]:
-            assert not w["slo_degraded"]
+        tailer.ensure_runtime()
+
+        def assert_replica_is_leader():
+            journal.sync()
+            for _ in range(64):
+                tailer.poll_once()
+                if tailer.applied_seq >= journal.last_seq:
+                    break
+            with tailer.lock:
+                replica = ser.runtime_to_state(tailer.runtime)
+            leader = ser.runtime_to_state(rt)
+            replica.pop("persistence")
+            leader.pop("persistence")
+            assert replica == leader
+
+        arrived = completed = deltas = peak_chain = 0
+        for tick in range(SOAK_TICKS):
+            for _ in range(int(rng.poisson(SOAK_RATE))):
+                gateway._enqueue(
+                    "workloads",
+                    ser.workload_to_dict(
+                        Workload(
+                            namespace="soak", name=f"swl-{arrived}",
+                            queue_name=f"lq-scq-{arrived % n_cq}",
+                            priority=int(rng.integers(0, 4)) * 10,
+                            creation_time=clock.now(),
+                            pod_sets=(PodSet.build("main", 1, {"cpu": "1"}),),
+                        )
+                    ),
+                )
+                arrived += 1
+            admitted = [wl for wl in rt.workloads.values() if wl.is_admitted]
+            n_done = min(len(admitted), int(rng.poisson(SOAK_RATE)))
+            for i in rng.permutation(len(admitted))[:n_done]:
+                rt.delete_workload(admitted[int(i)])
+                completed += 1
+            clock.advance(0.1)
+            gateway.flush_once()
+            if tick % 20 == 19:
+                # the replica has tailed 20 ticks since its last re-anchor
+                assert_replica_is_leader()
+                assert ckpt.checkpoint(rt)
+                deltas += ckpt.last_kind == "delta"
+                peak_chain = max(peak_chain, ckpt.status()["chainFiles"])
+                rt.slo.refresh()
+                assert not rt.slo.report()["degraded"], rt.slo.report()
+            journal.sync()
+            tailer.poll_once()
+
+        gateway.flush_once()
+        assert_replica_is_leader()
+        assert arrived > 0 and completed > 0 and deltas > anchor_every
+        # chain GC: one anchor and the deltas chained off it
+        assert peak_chain <= 1 + anchor_every
+        assert journal.stats().reclaimed_bytes > 0
+        rt.slo.refresh()
+        report = rt.slo.report()
+        assert report["clusterQueues"] and not report["degraded"]
+        journal.close()
