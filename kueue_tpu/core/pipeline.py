@@ -208,6 +208,7 @@ def speculative_snapshot(snapshot, final_usage: np.ndarray):
     spec._usage_cache = None
     spec._avail_cache = None
     spec._drs_cache = None
+    spec._pi_cache = None
     spec._tree_usage = None
     spec._usage_version = snapshot._usage_version + 1
     return spec

@@ -80,10 +80,13 @@ class _SubtreeIndex:
 def _head_candidates(snapshot: Snapshot, item, preemptor):
     """Shared per-head discovery: build the preemption context and the
     sorted candidate list (preemption.go:111-115), used by both the
-    classic and the fair lowerings."""
+    classic and the fair lowerings. A head the snapshot's candidate
+    index rules out gets (None, []) without a context."""
     from kueue_tpu.core.preemption import _Ctx
 
     wl, cq_name, assignment = item
+    if not preemptor.may_have_candidates(wl, cq_name, snapshot):
+        return None, []
     ctx = _Ctx(
         preemptor=wl,
         cq_name=cq_name,

@@ -101,6 +101,8 @@ class Preemptor(PreemptorBase):
     def get_targets(
         self, wl: Workload, cq_name: str, assignment: AssignmentResult, snapshot: Snapshot
     ) -> List[PreemptionTarget]:
+        if not self.may_have_candidates(wl, cq_name, snapshot):
+            return []
         frs = self._frs_need_preemption(assignment)
         ctx = _Ctx(
             preemptor=wl,
@@ -205,6 +207,33 @@ class Preemptor(PreemptorBase):
         return True
 
     # ---- candidates (preemption.go:480-547) ----
+    def may_have_candidates(
+        self, wl: Workload, cq_name: str, snapshot: Snapshot
+    ) -> bool:
+        """A necessary condition for _find_candidates to return anyone,
+        read from the snapshot's per-version index before any context is
+        built. False means no candidate exists in any cell: nothing
+        admitted in the CQ at or below the head's priority, and no other
+        member of its cohort tree above nominal. Counts each False in
+        ``no_candidates``."""
+        from kueue_tpu.utils.priority import priority_of
+
+        cq = snapshot.cq_models[cq_name]
+        if cq.preemption.within_cluster_queue != PreemptionPolicy.NEVER:
+            lowest = snapshot.min_admitted_priority(cq_name)
+            if lowest is not None and lowest <= priority_of(
+                wl, snapshot.priority_classes
+            ):
+                return True
+        if (
+            cq.preemption.reclaim_within_cohort != ReclaimWithinCohortPolicy.NEVER
+            and snapshot.has_cohort(cq_name)
+            and snapshot.other_member_above_nominal(cq_name)
+        ):
+            return True
+        self.no_candidates += 1
+        return False
+
     def _frs_need_preemption(self, assignment: AssignmentResult) -> Set[FlavorResource]:
         out: Set[FlavorResource] = set()
         for ps in assignment.pod_sets:

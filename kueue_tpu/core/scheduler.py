@@ -94,6 +94,10 @@ class Preemptor:
     """Interface the scheduler drives; ops implementation in
     core/preemption.py (classic + fair sharing)."""
 
+    # running count of heads whose victim search was answered empty
+    # without a candidate scan; each cycle's trace counts its share
+    no_candidates = 0
+
     def get_targets(
         self, wl: Workload, cq_name: str, assignment: AssignmentResult, snapshot: Snapshot
     ) -> List[PreemptionTarget]:
@@ -176,7 +180,8 @@ class CycleTrace:
     # ("forest_walks"), heads prevalidated, walked on the host,
     # deferred to victim search, parked; heads lowered, unrepresentable
     # (lowering's fallback), device launches, heads the kernel returned
-    # non-Fit
+    # non-Fit, heads whose victim search the snapshot's candidate index
+    # answered empty ("no_candidates")
     counts: Dict[str, int] = field(default_factory=dict)
     # device vs host attribution: time spent inside device dispatches
     # (assign/victim kernels, the drain solve) vs everything else
@@ -417,7 +422,11 @@ class Scheduler:
             snap_region.count = forest.walks - walks
         self.guard.phase_checkpoint("snapshot")
         with phase(_NOMINATE, trace):
+            answered = self.preemptor.no_candidates
             entries, device_plan = self._nominate(heads, snapshot)
+            self._count(
+                "no_candidates", self.preemptor.no_candidates - answered
+            )
         self.guard.phase_checkpoint(
             "nominate", device_used=self._cycle_device_s > 0
         )

@@ -84,6 +84,7 @@ class Snapshot:
     _avail_cache: Optional[tuple] = None
     _pa_cache: Optional[np.ndarray] = None
     _drs_cache: Optional[tuple] = None
+    _pi_cache: Optional[tuple] = None
     # incrementally-maintained tree usage (usage_tree_np semantics):
     # updated along the mutated row's ancestor path in O(depth*FR)
     # instead of re-running the full level-scheduled reduction, so the
@@ -257,18 +258,50 @@ class Snapshot:
         return self._roots
 
     def cohort_members(self, cq_name: str) -> Set[str]:
-        """All CQ names in the same cohort tree (incl. cq_name)."""
+        """All CQ names in the same cohort tree (incl. cq_name). The
+        first call groups every CQ by its root in one pass."""
         roots = self.roots()
-        me = int(roots[self.row(cq_name)])
-        cached = self._members.get(me)
-        if cached is None:
-            cached = {
-                name
-                for name in self.flat.cq_names
-                if int(roots[self.flat.index[name]]) == me
-            }
-            self._members[me] = cached
-        return cached
+        if not self._members:
+            index = self.flat.index
+            for name in self.flat.cq_names:
+                self._members.setdefault(int(roots[index[name]]), set()).add(name)
+        return self._members[int(roots[self.row(cq_name)])]
+
+    # ---- preemption-candidate index ----
+    def _preempt_index(self) -> tuple:
+        """(version, CQ rows above nominal in some cell grouped by
+        cohort root, lowest admitted priority per CQ filled on demand),
+        rebuilt lazily once per usage version."""
+        if self._pi_cache is None or self._pi_cache[0] != self._usage_version:
+            n_cq = self.flat.n_cq
+            over = np.flatnonzero(
+                (self.local_usage[:n_cq] > self.nominal[:n_cq]).any(axis=1)
+            )
+            roots = self.roots()
+            by_root: Dict[int, List[int]] = {}
+            for r in over.tolist():
+                by_root.setdefault(int(roots[r]), []).append(r)
+            self._pi_cache = (self._usage_version, by_root, {})
+        return self._pi_cache
+
+    def other_member_above_nominal(self, cq_name: str) -> bool:
+        """Is some other CQ of cq_name's cohort tree above its nominal
+        quota in any cell? Every cohort reclaim candidate lives in such
+        a CQ."""
+        r = self.row(cq_name)
+        over = self._preempt_index()[1].get(int(self.roots()[r]))
+        return over is not None and (len(over) > 1 or over[0] != r)
+
+    def min_admitted_priority(self, cq_name: str) -> Optional[int]:
+        """The lowest priority among cq_name's admitted workloads, None
+        when it has none."""
+        mins = self._preempt_index()[2]
+        if cq_name not in mins:
+            mins[cq_name] = min(
+                (ws.priority for ws in self._by_cq.get(cq_name, {}).values()),
+                default=None,
+            )
+        return mins[cq_name]
 
     def has_cohort(self, cq_name: str) -> bool:
         return self.flat.parent[self.row(cq_name)] >= 0

@@ -1,5 +1,6 @@
 """Cache + snapshot behavior (pkg/cache parity) and np/JAX kernel parity."""
 
+import dataclasses
 import random
 
 import numpy as np
@@ -364,6 +365,57 @@ def test_one_snapshot_walks_the_forest_once_at_the_cells_shape(monkeypatch):
     # a status asked alone still walks for itself
     assert cache.cluster_queue_status("cq-0-0").active
     assert len(calls) == 2
+
+
+class _CountingNames(tuple):
+    """A tuple of names that counts the passes made over it."""
+
+    passes = 0
+
+    def __iter__(self):
+        type(self).passes += 1
+        return super().__iter__()
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_cohort_members_grouped_in_one_pass(seed):
+    """On a multi-level forest with cohort-less ClusterQueues, every
+    group equals a scan for the ClusterQueues under the same root, and
+    one pass over the names fills them all."""
+    rng = random.Random(seed)
+    cache = Cache()
+    cache.add_or_update_flavor(ResourceFlavor(name="on-demand"))
+    names = [f"c{i}" for i in range(rng.randint(3, 12))]
+    for i, name in enumerate(names):
+        # parents come earlier in the list: a forest with no cycle, and
+        # c0 > c1 > c2 a chain three levels deep
+        parent = names[i - 1] if 0 < i < 3 else rng.choice([None] + names[:i])
+        cache.add_or_update_cohort(Cohort(name=name, parent=parent))
+    cache.add_or_update_cluster_queue(cq_in("deep", "c2"))
+    cache.add_or_update_cluster_queue(cq_in("alone", None))
+    for i in range(rng.randint(4, 40)):
+        cache.add_or_update_cluster_queue(cq_in(f"cq{i}", rng.choice(names + [None])))
+    snap = take_snapshot(cache)
+    assert snap.flat.max_depth >= 3
+    roots = snap.roots()
+    flat = snap.flat
+    oracle = {
+        name: {
+            other
+            for other in flat.cq_names
+            if roots[flat.index[other]] == roots[flat.index[name]]
+        }
+        for name in flat.cq_names
+    }
+    snap.flat = dataclasses.replace(flat, cq_names=_CountingNames(flat.cq_names))
+    _CountingNames.passes = 0
+    got = {name: snap.cohort_members(name) for name in flat.cq_names}
+    assert got == oracle
+    assert _CountingNames.passes == 1
+    for name in flat.cq_names:
+        assert (got[name] == {name}) == (
+            not snap.has_cohort(name) or oracle[name] == {name}
+        )
 
 
 def test_np_jax_kernel_parity():

@@ -29,7 +29,7 @@ from kueue_tpu.models import (
     Workload,
 )
 from kueue_tpu.models.cluster_queue import Preemption
-from kueue_tpu.models.constants import PreemptionPolicy
+from kueue_tpu.models.constants import PreemptionPolicy, ReclaimWithinCohortPolicy
 from kueue_tpu.models.workload import PodSet
 from kueue_tpu.testing import faults
 from kueue_tpu.tracing import (
@@ -198,13 +198,15 @@ def test_counts_equal_hand_counts(scenario):
     # cycle 5: every head comes back from the kernel non-Fit (preempt
     # mode, with no lower-priority candidate), the host walk defers each
     # to victim search and parks it: the host loop decides the cycle,
-    # whose resolution reads "host" although it launched
+    # whose resolution reads "host" although it launched. The admitted
+    # workloads share the heads' priority, so the candidate index cannot
+    # rule them out and each takes the full candidate scan.
     fifth = first[4]
     assert fifth.resolution == "host"
     assert fifth.counts == {
         "forest_walks": 1, "prevalidated": N_CQ, "lowered": N_CQ, "unrepresentable": 0,
         "launches": 1, "returned": N_CQ, "walked": N_CQ,
-        "deferred": N_CQ, "parked": N_CQ,
+        "deferred": N_CQ, "parked": N_CQ, "no_candidates": 0,
     }
     assert {"assign", "victims", "launch", "fetch"} <= set(fifth.sub)
     # the higher-priority heads: launched, all handed back, every one
@@ -224,6 +226,69 @@ def test_counts_equal_hand_counts(scenario):
     assert sum(t.counts["launches"] for t in cycles) == len(cycles)
     assert [t.counts["forest_walks"] for t in cycles] == [1] * len(cycles)
     assert sum(t.counts["lowered"] for t in cycles) == N_CQ * len(cycles)
+
+
+@pytest.mark.parametrize("victim_threshold", [1, 10_000], ids=["batched", "host"])
+def test_no_candidates_equals_deferred_when_the_large_fills_nominal(victim_threshold):
+    """Two cohorts of six ClusterQueues (20 cpu nominal, 100 borrowing,
+    reclaim Any, LowerPriority), each with one large that fills its
+    nominal quota, two mediums and three smalls. Once the larges are
+    in, every head outranked by its large is deferred to victim search
+    with nothing borrowed in its cohort, and the snapshot's candidate
+    index answers each one."""
+    rt = ClusterRuntime(
+        clock=FakeClock(0.0),
+        use_solver=True,
+        preempt_solver_threshold=victim_threshold,
+        bulk_drain_threshold=None,
+    )
+    rt.add_flavor(ResourceFlavor(name="default"))
+    prem = Preemption(
+        within_cluster_queue=PreemptionPolicy.LOWER_PRIORITY,
+        reclaim_within_cohort=ReclaimWithinCohortPolicy.ANY,
+    )
+    for i in range(N_CQ):
+        rt.add_cluster_queue(
+            ClusterQueue(
+                name=f"cq-{i}",
+                cohort=f"cohort-{i // 6}",
+                namespace_selector={},
+                preemption=prem,
+                resource_groups=(
+                    ResourceGroup(
+                        ("cpu",),
+                        (FlavorQuotas.build("default", {"cpu": ("20", "100", None)}),),
+                    ),
+                ),
+            )
+        )
+        rt.add_local_queue(
+            LocalQueue(namespace="ns", name=f"lq-{i}", cluster_queue=f"cq-{i}")
+        )
+    classes = [("large", 1, 200, "20"), ("medium", 2, 100, "5"), ("small", 3, 50, "1")]
+    t = 0.0
+    for cls, count, priority, cpu in classes:
+        for j in range(count):
+            for i in range(N_CQ):
+                t += 1.0
+                rt.add_workload(
+                    Workload(
+                        namespace="ns", name=f"{cls}-{i}-{j}", queue_name=f"lq-{i}",
+                        priority=priority, creation_time=t,
+                        pod_sets=(PodSet.build("main", 1, {"cpu": cpu}),),
+                    )
+                )
+    rt.run_until_idle()
+    traces = list(rt.scheduler.last_traces)
+    assert sorted(k for k, w in rt.workloads.items() if w.has_quota_reservation) == sorted(
+        f"ns/large-{i}-0" for i in range(N_CQ)
+    )
+    assert traces[0].admitted == N_CQ and traces[0].counts["no_candidates"] == 0
+    deferring = [tr for tr in traces if tr.counts.get("deferred")]
+    assert len(deferring) == 5  # two mediums and three smalls a queue
+    for tr in deferring:
+        assert tr.counts["no_candidates"] == tr.counts["deferred"] == N_CQ
+        assert tr.to_dict()["counts"]["no_candidates"] == N_CQ
 
 
 def test_admitted_sets_identical_with_tracing_on_and_off(tmp_path):

@@ -30,6 +30,7 @@ from kueue_tpu.core.preempt_batch import batched_get_targets
 from kueue_tpu.core.preemption import Preemptor
 from kueue_tpu.core.snapshot import take_snapshot
 from kueue_tpu.core.workload_info import make_admission
+from kueue_tpu.resources import FlavorResource
 from kueue_tpu.utils.clock import FakeClock
 
 from tests.test_preemption import admit, build_cache, cq_one_flavor, pending
@@ -288,3 +289,205 @@ class TestRandomizedParity:
                 targets_set(got),
                 targets_set(want),
             )
+
+
+# ---- the snapshot's preemption-candidate index ----
+def _cq(name, cohort=None, prem=None, resources=("cpu",), quota="5"):
+    return ClusterQueue(
+        name=name,
+        cohort=cohort,
+        namespace_selector={},
+        resource_groups=(
+            ResourceGroup(
+                resources,
+                (FlavorQuotas.build("default", {r: quota for r in resources}),),
+            ),
+        ),
+        preemption=prem or Preemption(),
+    )
+
+
+def _head(name, cq, requests, prio, t=0.0):
+    return Workload(
+        namespace="ns", name=name, queue_name=f"lq-{cq}", priority=prio,
+        creation_time=t, pod_sets=(PodSet.build("main", 1, requests),),
+    )
+
+
+_LOWER = Preemption(within_cluster_queue=PreemptionPolicy.LOWER_PRIORITY)
+
+
+def _reclaim(policy, within=PreemptionPolicy.NEVER):
+    return Preemption(within_cluster_queue=within, reclaim_within_cohort=policy)
+
+
+def _index_no_cohort_lower():
+    cache = build_cache(_cq("cq", prem=_LOWER, quota="10"))
+    admit(cache, "low", "cq", "10", prio=1)
+    return cache, [(_head("new", "cq", {"cpu": "5"}, 50), "cq", True)]
+
+
+def _index_no_cohort_higher():
+    cache = build_cache(_cq("cq", prem=_LOWER, quota="10"))
+    admit(cache, "high", "cq", "10", prio=100)
+    return cache, [(_head("new", "cq", {"cpu": "5"}, 50), "cq", False)]
+
+
+def _index_other_fr_borrower():
+    # cq-b borrows memory only; the head needs preemption in cpu
+    prem = _reclaim(ReclaimWithinCohortPolicy.ANY, PreemptionPolicy.LOWER_PRIORITY)
+    res = ("cpu", "memory")
+    cache = build_cache(
+        _cq("cq-a", "team", prem, res), _cq("cq-b", "team", None, res)
+    )
+    admit_multi(cache, "own", "cq-a", {"cpu": "5", "memory": "1"}, prio=100)
+    admit_multi(cache, "memhog", "cq-b", {"cpu": "5", "memory": "8"}, prio=0)
+    head = _head("new", "cq-a", {"cpu": "3", "memory": "1"}, 50)
+    return cache, [(head, "cq-a", True)]
+
+
+def _index_own_fr_borrower():
+    cache = build_cache(
+        _cq("cq-a", "team", _reclaim(ReclaimWithinCohortPolicy.ANY)),
+        _cq("cq-b", "team"),
+    )
+    admit(cache, "borrower", "cq-b", "8", prio=100)
+    return cache, [(_head("new", "cq-a", {"cpu": "5"}, 0), "cq-a", True)]
+
+
+def _index_equal_priority(policy):
+    def build():
+        cache = build_cache(
+            _cq("cq", prem=Preemption(within_cluster_queue=policy), quota="10")
+        )
+        peer = admit(cache, "peer", "cq", "10", prio=50)
+        cache.cluster_queues["cq"].workloads[peer.key].creation_time = 10.0
+        return cache, [(_head("new", "cq", {"cpu": "5"}, 50, t=-100.0), "cq", True)]
+
+    return build
+
+
+def _index_within_never():
+    cache = build_cache(_cq("cq", quota="10"))
+    admit(cache, "low", "cq", "10", prio=1)
+    return cache, [(_head("new", "cq", {"cpu": "5"}, 50), "cq", False)]
+
+
+def _index_reclaim(policy):
+    def build():
+        cache = build_cache(_cq("cq-a", "team", _reclaim(policy)), _cq("cq-b", "team"))
+        admit(cache, "borrower", "cq-b", "8", prio=10)
+        maybe = policy != ReclaimWithinCohortPolicy.NEVER
+        return cache, [
+            (_head("low", "cq-a", {"cpu": "5"}, 0), "cq-a", maybe),
+            (_head("high", "cq-a", {"cpu": "5"}, 50), "cq-a", maybe),
+        ]
+
+    return build
+
+
+def _index_fair_borrowers():
+    prem = _reclaim(ReclaimWithinCohortPolicy.ANY)
+    cache = build_cache(
+        _cq("cq-a", "team", prem, quota="4"), _cq("cq-b", "team", quota="4"),
+        _cq("cq-c", "team", quota="4"),
+    )
+    admit(cache, "hog", "cq-b", "8", prio=0, reserved_at=1.0)
+    admit(cache, "slight", "cq-c", "4", prio=0, reserved_at=2.0)
+    return cache, [(_head("new", "cq-a", {"cpu": "4"}, 0), "cq-a", True)]
+
+
+def _index_fair_at_nominal():
+    prem = _reclaim(ReclaimWithinCohortPolicy.ANY, PreemptionPolicy.LOWER_PRIORITY)
+    cache = build_cache(
+        _cq("cq-a", "team", prem, quota="4"), _cq("cq-b", "team", quota="4")
+    )
+    admit(cache, "own", "cq-a", "4", prio=100)
+    admit(cache, "peer", "cq-b", "4", prio=0)
+    return cache, [(_head("new", "cq-a", {"cpu": "2"}, 50), "cq-a", False)]
+
+
+def _index_invalidated():
+    # cq-b sits at nominal until an in-cycle add_usage pushes it over;
+    # removing its workload brings it back under
+    cache = build_cache(
+        _cq("cq-a", "team", _reclaim(ReclaimWithinCohortPolicy.ANY, PreemptionPolicy.LOWER_PRIORITY)),
+        _cq("cq-b", "team"),
+    )
+    admit(cache, "keep", "cq-a", "5", prio=100)
+    admit(cache, "b1", "cq-b", "5", prio=0)
+    return cache, [(_head("new", "cq-a", {"cpu": "3"}, 50), "cq-a", False)]
+
+
+def _push_cq_b_over(snap):
+    vec = np.zeros(len(snap.fr_list), dtype=np.int64)
+    vec[snap.fr_index[FlavorResource("default", "cpu")]] = 3000
+    snap.add_usage("cq-b", vec)
+    return True
+
+
+def _remove_b1(snap):
+    snap.remove_workload("ns/b1")
+    return False
+
+
+# case -> (builder, fair sharing, in-cycle mutations each with the
+# index's answer it leads to)
+INDEX_CASES = {
+    "no_cohort_lower_priority": (_index_no_cohort_lower, False, ()),
+    "no_cohort_higher_priority": (_index_no_cohort_higher, False, ()),
+    "borrower_in_other_flavor_resource": (_index_other_fr_borrower, False, ()),
+    "borrower_in_own_flavor_resource": (_index_own_fr_borrower, False, ()),
+    "equal_priority_lower_or_newer": (
+        _index_equal_priority(PreemptionPolicy.LOWER_OR_NEWER_EQUAL_PRIORITY), False, ()),
+    "equal_priority_lower": (
+        _index_equal_priority(PreemptionPolicy.LOWER_PRIORITY), False, ()),
+    "within_cluster_queue_never": (_index_within_never, False, ()),
+    "reclaim_never": (_index_reclaim(ReclaimWithinCohortPolicy.NEVER), False, ()),
+    "reclaim_lower_priority": (
+        _index_reclaim(ReclaimWithinCohortPolicy.LOWER_PRIORITY), False, ()),
+    "reclaim_any": (_index_reclaim(ReclaimWithinCohortPolicy.ANY), False, ()),
+    "fair_sharing_borrowers": (_index_fair_borrowers, True, ()),
+    "fair_sharing_at_nominal": (_index_fair_at_nominal, True, ()),
+    "invalidated_in_cycle": (_index_invalidated, False, (_push_cq_b_over, _remove_b1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INDEX_CASES))
+def test_candidate_index_keeps_decisions(case):
+    """The index only rules heads out: host get_targets and the batched
+    search equal the full candidate scan, and a head it rules out has
+    no candidate at all."""
+    from kueue_tpu.core.preemption import _Ctx
+
+    build, fair, mutations = INDEX_CASES[case]
+    cache, heads = build()
+    snap = take_snapshot(cache)
+    assigner = FlavorAssigner(snap, cache.flavors)
+    preemptor = Preemptor(FakeClock(), enable_fair_sharing=fair)
+    items, expected = [], []
+    for wl, cq_name, maybe in heads:
+        assignment = assigner.assign(wl, cq_name)
+        assert assignment.representative_mode() == Mode.PREEMPT, wl.name
+        items.append((wl, cq_name, assignment))
+        expected.append(maybe)
+    for step in (None,) + tuple(mutations):
+        if step is not None:
+            expected = [step(snap)] * len(items)
+        batched = batched_get_targets(snap, items, preemptor)
+        for (wl, cq_name, assignment), maybe, got in zip(items, expected, batched):
+            ctx = _Ctx(
+                preemptor=wl, cq_name=cq_name, cq_row=snap.row(cq_name),
+                snapshot=snap,
+                frs_need_preemption=preemptor._frs_need_preemption(assignment),
+                usage_vec=snap.vector_of(assignment.usage),
+            )
+            candidates = preemptor._find_candidates(ctx)
+            full = targets_set(preemptor._get_targets(ctx))
+            answered = preemptor.no_candidates
+            host = targets_set(preemptor.get_targets(wl, cq_name, assignment, snap))
+            assert preemptor.no_candidates - answered == (0 if maybe else 1)
+            assert preemptor.may_have_candidates(wl, cq_name, snap) == maybe
+            if not maybe:
+                assert candidates == []
+            assert host == full == targets_set(got), (wl.name, host, full)
