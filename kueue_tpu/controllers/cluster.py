@@ -1636,6 +1636,8 @@ class ClusterRuntime:
             host_s=dt - t_solve,
             mesh=self._mesh_label,
         )
+        if outcome.resumed:
+            trace.add_count("walks_resumed", outcome.resumed)
         sched.tracer.record_cycle(trace)
         sched.last_traces.append(trace)
         self._report_cycle_metrics(result, dt)
@@ -1939,6 +1941,8 @@ class ClusterRuntime:
                     host_s=dt - (t_solve if committed == 1 else 0.0),
                     mesh=self._mesh_label,
                 )
+                if outcome.resumed:
+                    trace.add_count("walks_resumed", outcome.resumed)
                 sched.tracer.record_cycle(trace)
                 sched.last_traces.append(trace)
                 self._report_cycle_metrics(result, dt)
@@ -2287,6 +2291,8 @@ class ClusterRuntime:
                 host_s=dt - t_solve,
                 mesh=self._mesh_label,
             )
+            if outcome.resumed:
+                trace.add_count("walks_resumed", outcome.resumed)
             sched.tracer.record_cycle(trace)
             sched.last_traces.append(trace)
             self._report_cycle_metrics(result, dt)

@@ -71,6 +71,9 @@ class DrainOutcome:
     resume: List[Tuple[Workload, str, np.ndarray]] = field(
         default_factory=list
     )
+    # heads the plan lowered with a resume cursor (its round's
+    # ``walks_resumed``)
+    resumed: int = 0
 
 
 def _resume_of(plan, cursor, stuck, g_start) -> List[Tuple[Workload, str, np.ndarray]]:
@@ -124,6 +127,11 @@ def plan_drain(
     ``pending`` must be in per-CQ heap order (priority desc, timestamp
     asc — use QueueManager pending snapshots); relative order across
     CQs is irrelevant.
+
+    Every drain kernel starts each queue's walk at ``g_start0``: every
+    queue's first head is lowered over its whole flavor walk and its
+    LastAssignment goes to ``g_start0``, so a head a round cut off
+    mid-walk can still restart at the first flavor in the next round.
     """
     from kueue_tpu.ops.assign_kernel import build_roots
 
@@ -136,6 +144,23 @@ def plan_drain(
 
         annotate_multi(policy, lowered, now)
     fallback = set(lowered.fallback)
+    if fallback:
+        # a head the drain cannot represent still takes its turn in its
+        # ClusterQueue and competes for its cohort's quota cycle by
+        # cycle: the whole cohort tree goes to the cycle loop with it
+        roots = build_roots(snapshot.flat.parent)
+        held = {
+            int(roots[snapshot.row(lowered.cq_names[i])])
+            for i in fallback
+            if lowered.cq_names[i] in snapshot.cq_models
+        }
+        if held:
+            fallback.update(
+                i
+                for i, cq_name in enumerate(lowered.cq_names)
+                if cq_name in snapshot.cq_models
+                and int(roots[snapshot.row(cq_name)]) in held
+            )
 
     by_cq: Dict[str, List[int]] = {}
     for i, cq_name in enumerate(lowered.cq_names):
@@ -182,6 +207,9 @@ def plan_drain(
     score = np.zeros((q, l, pdim, k), dtype=np.int64)
     ffb = np.ones(q, dtype=bool)
     ffp = np.zeros(q, dtype=bool)
+    # each queue's first flavor walk: its head's resume cursor per
+    # podset and group, -1 where the head carries none
+    g_start0 = np.full((q, pdim, g), -1, dtype=np.int32)
     # convergent-retry budget per queue: the max joint cursor-odometer
     # size of its entries (clamped; see drain_kernel stuck machinery)
     retry_cap = np.full(q, 2 * max_candidates + 2, dtype=np.int32)
@@ -212,6 +240,8 @@ def plan_drain(
             score[qi, :n] = lowered.score[idx_arr, :pdim]
         priority[qi, :n] = lowered.priority[idx_arr]
         timestamp[qi, :n] = lowered.timestamp[idx_arr]
+        for p, starts in enumerate(lowered.head_starts.get(idxs[0], ())):
+            g_start0[qi, p, : len(starts)] = starts
         for pos, i in enumerate(idxs):
             head_of[(qi, pos)] = i
             for pp in range(int(lowered.n_podsets[i])):
@@ -278,6 +308,7 @@ def plan_drain(
             timestamp=timestamp,
             no_reclaim=no_reclaim,
             score=score,
+            g_start0=g_start0,
         ),
         head_of=head_of,
         lowered=lowered,
@@ -1336,6 +1367,7 @@ def _preempt_outcome(
         fallback=fb,
         cycles=cycles,
         truncated=truncated,
+        resumed=lowered.resumed,
         preempted=preempted,
         evictions=evictions,
     )
@@ -2093,6 +2125,7 @@ def run_drain_tas(
         fallback=fb,
         cycles=cycles,
         truncated=truncated,
+        resumed=lowered.resumed,
         assignments=assignments,
     )
 
@@ -2415,6 +2448,8 @@ def _map_megaloop_rounds(
                     if ran and undecided
                     else []
                 ),
+                # later rounds resume on the device, not in a lowering
+                resumed=lowered.resumed if r == 0 else 0,
             )
         )
         prev_cursor = cursor_r.astype(np.int64).copy()
@@ -2852,4 +2887,5 @@ def _map_drain_result(
         admitted=admitted, parked=parked, fallback=fb, cycles=cycles,
         truncated=truncated, undecided=undecided, final_usage=final_usage,
         resume=_resume_of(plan, cursor, stuck_q, g_start) if undecided else [],
+        resumed=lowered.resumed,
     )

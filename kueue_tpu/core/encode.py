@@ -407,7 +407,10 @@ class ResidentEncoder:
         enc = encode_snapshot(snapshot)
         if not self._structure_matches(enc):
             self._tree, self._paths, _ = device_arrays(enc)
-            self._usage = jnp.asarray(enc.local_usage)
+            # a copy: on the CPU backend jnp.asarray may alias the numpy
+            # buffer (the snapshot's usage), which later changes while
+            # this buffer stays resident
+            self._usage = jnp.asarray(enc.local_usage.copy())
             self._usage_host = enc.local_usage.copy()
             self._names = tuple(enc.cq_names) + tuple(enc.cohort_names)
             self._parent = np.array(enc.parent, copy=True)
@@ -424,8 +427,9 @@ class ResidentEncoder:
         idx = np.flatnonzero(changed)
         if idx.size:
             if idx.size > max(16, new.shape[0] // 4):
-                # bulk change: a fresh upload beats a huge scatter
-                self._usage = jnp.asarray(new)
+                # bulk change: a fresh upload beats a huge scatter (a
+                # copy, as above)
+                self._usage = jnp.asarray(new.copy())
             else:
                 # bucket the delta width (pad by repeating the first
                 # changed row — idempotent under .set) so the scatter

@@ -85,6 +85,9 @@ class FlavorChoice:
     mode: GranularMode
     tried_flavor_idx: int = -1
     borrow: bool = False
+    # whenCanBorrow TryNextFlavor walked past a flavor of this resource
+    # group that fits only by borrowing
+    skipped_borrow: bool = False
 
 
 @dataclass
@@ -141,6 +144,13 @@ class AssignmentResult:
         if not self.pod_sets:
             return Mode.NO_FIT
         return min((ps.representative_mode() for ps in self.pod_sets), default=Mode.NO_FIT)
+
+    def borrow_skipped(self) -> bool:
+        """Some group's flavor lies past one where the workload fits only
+        by borrowing (whenCanBorrow TryNextFlavor)."""
+        return any(
+            c.skipped_borrow for ps in self.pod_sets for c in ps.flavors.values()
+        )
 
     def message(self) -> str:
         parts = []
@@ -333,6 +343,10 @@ class FlavorAssigner:
         reasons: List[str] = []
         best: Dict[str, FlavorChoice] = {}
         best_mode = GranularMode.NO_FIT
+        best_idx = -1
+        # the first flavor the walk went on from although it fits by
+        # borrowing (whenCanBorrow TryNextFlavor)
+        borrow_fit_idx = -1
 
         label_keys = group_label_keys(rg.flavors, self.flavors)
 
@@ -403,13 +417,18 @@ class FlavorAssigner:
                         if representative > best_mode:
                             best = assignments
                             best_mode = representative
+                            best_idx = idx
                         continue
                     best = assignments
                     best_mode = representative
+                    best_idx = idx
                     break
+                if representative == GranularMode.FIT and borrow_fit_idx < 0:
+                    borrow_fit_idx = idx
                 if representative > best_mode:
                     best = assignments
                     best_mode = representative
+                    best_idx = idx
             else:
                 if representative > best_mode:
                     best = assignments
@@ -425,7 +444,7 @@ class FlavorAssigner:
             fit_ranked = [t for t in ranked if t[5] == GranularMode.FIT]
             pool = fit_ranked or ranked
             winner = max(pool)  # highest score, ties -> earliest flavor
-            best, best_mode = winner[4], winner[5]
+            best, best_mode, best_idx = winner[4], winner[5], winner[2]
             for t in fit_ranked:
                 if t[2] != winner[2]:
                     outranked.append(
@@ -437,8 +456,10 @@ class FlavorAssigner:
         if self.fungibility_enabled:
             n_flavors = len(rg.flavors)
             tried = -1 if attempted_idx == n_flavors - 1 else attempted_idx
+            skipped = best_mode == GranularMode.FIT and 0 <= borrow_fit_idx < best_idx
             for choice in best.values():
                 choice.tried_flavor_idx = tried
+                choice.skipped_borrow = skipped
             if best_mode == GranularMode.FIT:
                 return best, list(outranked)
         if not best and not reasons:

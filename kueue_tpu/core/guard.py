@@ -129,17 +129,20 @@ def solve_lowered_host(snapshot, lowered):
         reserved=out["reserved"].astype(bool),
         usage=None,
         order=out["order"].astype(np.int32),
+        skipped=out["skipped"].astype(bool),
     )
 
 
 def results_match(a, b) -> List[str]:
     """Bit-for-bit decision comparison of two SolveResults. Returns the
-    names of mismatching fields (empty = identical decisions)."""
+    names of mismatching fields (empty = identical decisions);
+    ``skipped`` is compared where both results carry it."""
     bad: List[str] = []
-    for name in ("chosen", "admitted", "borrows", "reserved"):
-        if not np.array_equal(
-            np.asarray(getattr(a, name)), np.asarray(getattr(b, name))
-        ):
+    for name in ("chosen", "admitted", "borrows", "reserved", "skipped"):
+        x, y = getattr(a, name), getattr(b, name)
+        if name == "skipped" and (x is None or y is None):
+            continue
+        if not np.array_equal(np.asarray(x), np.asarray(y)):
             bad.append(name)
     return bad
 

@@ -41,7 +41,7 @@ from kueue_tpu.core.flavor_assigner import (
 )
 from kueue_tpu.core.queue_manager import QueueManager, RequeueReason, queue_order_timestamp
 from kueue_tpu.core.snapshot import Snapshot, WorkloadSnapshot, take_snapshot
-from kueue_tpu.tracing.names import CYCLE_PHASE_SPANS
+from kueue_tpu.tracing.names import CYCLE_COUNTS, CYCLE_PHASE_SPANS, PHASE_COUNTERS
 from kueue_tpu.tracing.phases import Phase, PhaseTotals, phase
 from kueue_tpu.utils.clock import Clock
 from kueue_tpu.utils.priority import priority_of
@@ -57,6 +57,8 @@ _VICTIMS = Phase("cycle.victims", counter="deferred")
 _ENCODE = Phase("cycle.encode", counter="lowered")
 _DECODE = Phase("cycle.decode")
 _PARK = Phase("cycle.park", counter="parked")
+# every key a CycleTrace may count
+_COUNT_NAMES = CYCLE_COUNTS | frozenset(PHASE_COUNTERS.values())
 # after the cycle's span tree is flushed: its decision audit and the
 # cycle observers
 _AUDIT = Phase("loop.audit")
@@ -222,6 +224,11 @@ class CycleTrace:
             self.add_count(p.counter, count)
 
     def add_count(self, name: str, n: int) -> None:
+        if name not in _COUNT_NAMES:
+            raise ValueError(
+                f"cycle count {name!r} is not registered "
+                "(kueue_tpu.tracing.names.CYCLE_COUNTS, PHASE_COUNTERS)"
+            )
         self.counts[name] = self.counts.get(name, 0) + n
 
 
@@ -1092,6 +1099,7 @@ class Scheduler:
                     self.cache.flavors,
                     timestamp_fn=lambda wl: queue_order_timestamp(wl, self.queues._ts_policy),
                     transform=self.transform_config,
+                    try_next=self._lowers_try_next,
                 )
             except Exception as exc:  # noqa: BLE001 — batch-level
                 # lowering failure: bisected below
@@ -1114,6 +1122,15 @@ class Scheduler:
             return None
         fallback = set(lowered.fallback)
         self._count("unrepresentable", len(fallback))
+        # counted only where they happen, so a cycle without them reads
+        # as it did before they existed
+        for name, n in (
+            ("fungible_heads", lowered.fungible_heads),
+            ("fungible_lowered", lowered.fungible_lowered),
+            ("walks_resumed", lowered.resumed),
+        ):
+            if n:
+                self._count(name, n)
         if len(fallback) == len(to_assign):
             # nothing representable: skip the device dispatch entirely
             self._host_assign_contained(to_assign, snapshot)
@@ -1159,7 +1176,8 @@ class Scheduler:
                     continue
                 e.nominated_via = outcome.via
                 e.assignment = self._assignment_from_device(
-                    lowered, i, int(chosen[i]), snapshot
+                    lowered, i, int(chosen[i]), snapshot,
+                    skipped=res.skipped is not None and bool(res.skipped[i]),
                 )
                 e.workload.last_assignment = e.assignment.last_state
 
@@ -1200,6 +1218,12 @@ class Scheduler:
             walk.count = len(entries)
         self._resolve_deferred(assigner, deferred, snapshot)
 
+    @property
+    def _lowers_try_next(self) -> bool:
+        """whenCanBorrow TryNextFlavor heads go to the cycle kernel,
+        unless a scoring admission policy ranks the flavors instead."""
+        return self.policy is None or self.policy.is_default
+
     def _count(self, name: str, n: int) -> None:
         """Add ``n`` to the current cycle's ``counts[name]``."""
         if self._trace is not None:
@@ -1222,6 +1246,7 @@ class Scheduler:
                     wl, self.queues._ts_policy
                 ),
                 transform=self.transform_config,
+                try_next=self._lowers_try_next,
             )
 
         poison = bisect_poison(to_assign, probe)
@@ -1238,10 +1263,13 @@ class Scheduler:
         i: int,
         k: int,
         snapshot: Snapshot,
+        skipped: bool = False,
     ) -> AssignmentResult:
         """Reconstruct the host-equivalent FIT AssignmentResult from the
-        kernel's chosen candidate (single podset, default fungibility —
-        lower_heads guarantees these invariants for non-fallback heads)."""
+        kernel's chosen candidate (single podset, default fungibility or
+        whenCanBorrow TryNextFlavor — lower_heads guarantees these
+        invariants for non-fallback heads). ``skipped``: the kernel chose
+        past a candidate that fits only by borrowing."""
         from kueue_tpu.core.flavor_assigner import (
             AssignmentState,
             FlavorChoice,
@@ -1262,6 +1290,12 @@ class Scheduler:
         result = AssignmentResult(pod_sets=[psr])
         cells = lowered.cells[i, k]
         qty = lowered.qty[i, k]
+        group_bits = (
+            lowered.try_next[i, k] if lowered.try_next is not None else None
+        )
+        # whenCanBorrow TryNextFlavor: a group whose flavor borrows was
+        # walked to its end, so its cursor restarts at the first flavor
+        borrowing_groups = 0
         for c in range(cells.shape[0]):
             j = int(cells[c])
             if j < 0:
@@ -1276,12 +1310,22 @@ class Scheduler:
             ) and snapshot.has_cohort(cq_name)
             if borrow:
                 result.borrowing = True
+                if group_bits is not None:
+                    borrowing_groups |= int(group_bits[c])
             psr.flavors[fr.resource] = FlavorChoice(
                 name=fr.flavor,
                 mode=GranularMode.FIT,
                 tried_flavor_idx=tried_map.get(fr.resource, -1),
                 borrow=borrow,
+                skipped_borrow=skipped,
             )
+        if borrowing_groups:
+            tried_map = dict(tried_map)
+            for c in range(cells.shape[0]):
+                if int(cells[c]) >= 0 and int(group_bits[c]) & borrowing_groups:
+                    resource = snapshot.fr_list[int(cells[c])].resource
+                    tried_map[resource] = -1
+                    psr.flavors[resource].tried_flavor_idx = -1
         result.usage = usage
         result.last_state = AssignmentState(
             last_tried_flavor_idx=[dict(tried_map)],
@@ -1494,6 +1538,8 @@ class Scheduler:
         )
         if ok:
             e.status = EntryStatus.ASSUMED
+            if e.assignment.borrow_skipped():
+                self._count("borrow_skipped", 1)
         else:
             e.inadmissible_msg = msg
             # end-of-cycle loop requeues every non-assumed entry

@@ -7,12 +7,18 @@ eligibility (taints, node-selector labels), resume-from-cursor
 (LastAssignment), default-fungibility ordering — but emits it as a
 padded (W x K x C) tensor batch the TPU consumes in one dispatch.
 
+A head of a ``whenCanBorrow: TryNextFlavor`` ClusterQueue carries one
+bit per resource group on its candidate cells (``Lowered.try_next``):
+the kernel then prefers, group by group, a flavor that fits without
+borrowing over an earlier one that fits only by borrowing.
+
 Heads the dense formulation cannot represent exactly fall back to the
 host authority path and are reported in ``Lowered.fallback``:
   - multi-podset workloads (the reference assigns flavors per podset;
     aggregation would force one flavor for all podsets),
-  - non-default flavorFungibility (changes the stop rule away from
-    "first Fit wins"),
+  - ``whenCanPreempt: Preempt`` (a walk may stop at a preempt-mode
+    flavor before a later one that fits), and ``whenCanBorrow:
+    TryNextFlavor`` under a scoring admission policy,
   - candidate fan-out beyond the static K.
 This matches the design stance in SURVEY.md §7: the batched solver
 resolves the Fit/NoFit majority; preemption-mode nomination stays host.
@@ -56,6 +62,10 @@ class Lowered:
     # default first-fit policy (pack_heads ships zeros, the kernel's
     # score-argmax then IS the first-fit argmax)
     score: Optional[np.ndarray] = None
+    # int8[W,K,C]: for a head of a whenCanBorrow TryNextFlavor queue,
+    # each candidate cell's resource-group bit (1 << group); 0 for every
+    # other head, whose first fitting candidate wins as before
+    try_next: Optional[np.ndarray] = None
 
     # per head: candidate k -> flavor name chosen per resource group
     candidate_flavors: List[List[Dict[str, str]]] = field(default_factory=list)
@@ -68,6 +78,13 @@ class Lowered:
     fallback: List[int] = field(default_factory=list)  # indices into input heads
     # per head: number of resource groups its request touches
     n_groups: List[int] = field(default_factory=list)
+    # heads of a ClusterQueue with non-default fungibility offered to
+    # the lowering, and those of them lowered onto the device
+    fungible_heads: int = 0
+    fungible_lowered: int = 0
+    # heads lowered with a resume cursor (a LastAssignment that starts
+    # some resource group's walk past its first flavor)
+    resumed: int = 0
 
 
 
@@ -77,6 +94,20 @@ def _default_fungibility(cq: ClusterQueue) -> bool:
         ff.when_can_borrow == FlavorFungibilityPolicy.BORROW
         and ff.when_can_preempt == FlavorFungibilityPolicy.TRY_NEXT_FLAVOR
     )
+
+
+def _borrow_tries_next(cq: ClusterQueue) -> bool:
+    """whenCanBorrow TryNextFlavor with whenCanPreempt TryNextFlavor:
+    the one non-default fungibility the cycle kernel represents."""
+    ff = cq.flavor_fungibility
+    return (
+        ff.when_can_borrow == FlavorFungibilityPolicy.TRY_NEXT_FLAVOR
+        and ff.when_can_preempt == FlavorFungibilityPolicy.TRY_NEXT_FLAVOR
+    )
+
+
+# a group bit must fit the int8 try_next cells
+_MAX_TRY_NEXT_GROUPS = 7
 
 
 class _Template:
@@ -96,6 +127,7 @@ class _Template:
         "valid_row",
         "qty_sel",
         "cgrp_arr",
+        "try_next_arr",
         "res_names",
         "flavor_list",
         "tried_list",
@@ -120,6 +152,9 @@ class _Template:
         self.qty_sel = None
         # int8[K,C]: resource-group index of each candidate cell (-1 pad)
         self.cgrp_arr = None
+        # int8[K,C]: each cell's resource-group bit (0 pad), the
+        # try_next rows of a whenCanBorrow TryNextFlavor head
+        self.try_next_arr = None
         self.res_names: Tuple[str, ...] = ()
         self.flavor_list: List[Dict[str, str]] = []
         self.tried_list: List[Dict[str, int]] = []
@@ -277,6 +312,10 @@ def _build_template(
         t.valid_row[ki] = True
         t.flavor_list.append(flavor_map)
         t.tried_list.append(tried_map)
+    if t.n_groups <= _MAX_TRY_NEXT_GROUPS:
+        t.try_next_arr = np.where(
+            t.cgrp_arr >= 0, np.left_shift(1, np.maximum(t.cgrp_arr, 0)), 0
+        ).astype(np.int8)
     return t
 
 
@@ -331,11 +370,14 @@ def lower_heads(
     max_cells: int = 16,
     timestamp_fn=None,
     transform=None,  # ResourceTransformConfig for the quota view
+    try_next: bool = True,  # lower whenCanBorrow TryNextFlavor heads
 ) -> Lowered:
     """Build the dense head batch for the INTERACTIVE cycle path; route
     inexpressible heads to ``fallback`` (handled by the host
     FlavorAssigner). The drain lowers via lower_heads_multi, which also
     carries multi-podset, fungibility-policy and cursor-vector inputs.
+    With ``try_next`` False (a scoring admission policy ranks the
+    flavors instead) whenCanBorrow TryNextFlavor heads fall back too.
 
     Candidate enumeration is memoized per (CQ, podset shape, cursor):
     a bulk backlog over 1k CQs lowers in O(templates + heads), not
@@ -350,9 +392,11 @@ def lower_heads(
         priority=np.zeros(w, dtype=np.int64),
         timestamp=np.zeros(w, dtype=np.int64),
         no_reclaim=np.zeros(w, dtype=bool),
+        try_next=np.zeros((w, k, c), dtype=np.int8),
     )
     templates: Dict[tuple, _Template] = {}
-    # template key -> (template, head indexes, per-head (per_pod, count))
+    # template key -> (template, head indexes, per-head (per_pod, count),
+    # whenCanBorrow TryNextFlavor)
     groups: Dict[tuple, tuple] = {}
 
     for i, (wl, cq_name) in enumerate(heads):
@@ -365,7 +409,14 @@ def lower_heads(
             out.fallback.append(i)
             continue
         cq = snapshot.cq_models[cq_name]
-        if len(wl.pod_sets) != 1 or not _default_fungibility(cq):
+        tries_next = False
+        if not _default_fungibility(cq):
+            out.fungible_heads += 1
+            tries_next = try_next and _borrow_tries_next(cq)
+            if not tries_next:
+                out.fallback.append(i)
+                continue
+        if len(wl.pod_sets) != 1:
             out.fallback.append(i)
             continue
         ps = wl.pod_sets[0]
@@ -389,9 +440,11 @@ def lower_heads(
             )
             templates[key] = t
         out.n_groups[i] = t.n_groups
-        if t.fallback:
+        if t.fallback or (tries_next and t.try_next_arr is None):
             out.fallback.append(i)
             continue
+        out.fungible_lowered += tries_next
+        out.resumed += any(starts)
 
         count = effective_podset_count(wl, ps)
 
@@ -407,15 +460,17 @@ def lower_heads(
         # per-head fills dominated bulk-drain lowering wall time)
         group = groups.get(key)
         if group is None:
-            group = groups[key] = (t, [], [])
+            group = groups[key] = (t, [], [], tries_next)
         group[1].append(i)
         group[2].append((per_pod, count))
 
-    for t, idxs, pcs in groups.values():
+    for t, idxs, pcs, tries_next in groups.values():
         ii = np.asarray(idxs, dtype=np.intp)
         out.cq_row[ii] = t.cq_row
         out.cells[ii] = t.cells_arr
         out.valid[ii] = t.valid_row
+        if tries_next:
+            out.try_next[ii] = t.try_next_arr
         # request matrix: rows = heads in this group, cols = the
         # template's resource order (+1 zero column for padding cells)
         rmat = np.zeros((len(ii), len(t.res_names) + 1), dtype=np.int64)
@@ -494,7 +549,10 @@ class ResidentCycleState:
 
         if not self._structure_matches(snapshot):
             self._tree, self._paths, self._roots = tree_arrays(snapshot)
-            self._usage = jnp.asarray(snapshot.local_usage)
+            # a copy: on the CPU backend jnp.asarray may alias the numpy
+            # buffer, and the snapshot's usage is mutated by the admit
+            # loop while this buffer stays resident
+            self._usage = jnp.asarray(snapshot.local_usage.copy())
             self._usage_host = snapshot.local_usage.copy()
             self._names = tuple(snapshot.flat.cq_names)
             self._parent = np.array(snapshot.flat.parent, copy=True)
@@ -511,8 +569,9 @@ class ResidentCycleState:
         idx = np.flatnonzero(changed)
         if idx.size:
             if idx.size > max(16, new.shape[0] // 4):
-                # bulk change: a fresh upload beats a huge scatter
-                self._usage = jnp.asarray(new)
+                # bulk change: a fresh upload beats a huge scatter (a
+                # copy, as above)
+                self._usage = jnp.asarray(new.copy())
             else:
                 # bucket the delta width (pad by repeating the first
                 # changed row — idempotent under .set) so the scatter
@@ -580,6 +639,9 @@ def pack_heads(lowered: Lowered, roots, w_pad: int):
     score = lowered.score
     if score is None:
         score = np.zeros(valid.shape, dtype=np.int64)
+    try_next = lowered.try_next
+    if try_next is None:
+        try_next = np.zeros(cells.shape, dtype=np.int8)
     if w_pad > w:
         pad = w_pad - w
         cq_row = np.concatenate([cq_row, np.full(pad, -1, dtype=np.int32)])
@@ -594,10 +656,13 @@ def pack_heads(lowered: Lowered, roots, w_pad: int):
         score = np.concatenate(
             [score, np.zeros((pad,) + score.shape[1:], dtype=np.int64)]
         )
+        try_next = np.concatenate(
+            [try_next, np.zeros((pad,) + try_next.shape[1:], dtype=np.int8)]
+        )
     batch_np = HeadsBatch(
         cq_row=cq_row, cells=cells, qty=qty, valid=valid,
         priority=priority, timestamp=timestamp, no_reclaim=no_reclaim,
-        score=score,
+        score=score, try_next=try_next,
     )
     # compact segment ids: one per LIVE root cohort; the max head count
     # within one root bounds phase-2's sequential depth
@@ -706,6 +771,7 @@ def dispatch_lowered(
         reserved=packed[3].astype(bool),
         usage=None,
         order=packed[4].astype(np.int32),
+        skipped=packed[5].astype(bool),
     )
 
 
@@ -760,6 +826,12 @@ class MultiLowered:
     # (walk length + 1); a CONVERGENT PendingFlavors retry sequence
     # cannot exceed it, so it is the sound stuck-detection budget
     walk_states: List[int] = field(default_factory=list)
+    # heads lowered with a resume cursor in some podset's walk
+    resumed: int = 0
+    # head index -> per podset, the resume cursor of each touched
+    # resource group, for each ClusterQueue's first head that carries
+    # one (its template enumerates the whole walk)
+    head_starts: Dict[int, List[Tuple[int, ...]]] = field(default_factory=dict)
 
 
 def lower_heads_multi(
@@ -778,7 +850,12 @@ def lower_heads_multi(
 
     Cursor starts resolve per (podset index, resource) from the
     workload's LastAssignment, exactly like the host's
-    AssignmentState.next_flavor_to_try."""
+    AssignmentState.next_flavor_to_try. The drain kernels start each
+    queue's walk at ``DrainQueues.g_start0``, so the first head of each
+    ClusterQueue lowers its whole walk and reports its cursor in
+    ``head_starts``: a walk that restarts at the first flavor in the
+    drain still finds it. A later head that carries a cursor falls
+    back."""
     w = len(heads)
     k, c = max_candidates, max_cells
     # size the podset axis to what the batch actually needs: the
@@ -808,8 +885,11 @@ def lower_heads_multi(
     )
     templates: Dict[tuple, _Template] = {}
     groups: Dict[tuple, tuple] = {}  # (key, p) -> (t, idxs, pcs)
+    seen_cqs = set()
 
     for i, (wl, cq_name) in enumerate(heads):
+        first = cq_name not in seen_cqs
+        seen_cqs.add(cq_name)
         out.heads.append(wl)
         out.cq_names.append(cq_name)
         # per-podset maps appended as podsets lower (indexed p <
@@ -847,6 +927,13 @@ def lower_heads_multi(
                 continue
             per_pod = quota_per_pod(ps, transform)
             starts = _resolve_starts(cq, per_pod, state, 0)
+            resumed = any(starts)
+            if resumed:
+                if not first:
+                    out.fallback.append(i)
+                    continue
+                out.head_starts[i] = [starts]
+                starts = ()
             key = (cq_name, _podset_sig(ps, per_pod), starts)
             t = templates.get(key)
             if t is None:
@@ -856,8 +943,10 @@ def lower_heads_multi(
                 )
                 templates[key] = t
             if t.fallback:
+                out.head_starts.pop(i, None)
                 out.fallback.append(i)
                 continue
+            out.resumed += resumed
             out.cq_row[i] = t.cq_row
             out.n_podsets[i] = 1
             out.no_reclaim[i] = t.no_reclaim
@@ -880,26 +969,35 @@ def lower_heads_multi(
 
         bad = False
         head_templates = []
+        head_starts = []
         for ps_idx, ps in enumerate(wl.pod_sets):
             if ps.topology_request is not None and not allow_tas:
                 bad = True  # TAS placement stays on the host path
                 break
             per_pod = quota_per_pod(ps, transform)
             starts = _resolve_starts(cq, per_pod, state, ps_idx)
-            key = (cq_name, _podset_sig(ps, per_pod), starts)
+            head_starts.append(starts)
+            # the whole walk: the kernel starts it at the cursor
+            key = (cq_name, _podset_sig(ps, per_pod), ())
             t = templates.get(key)
             if t is None:
                 t = _build_template(
-                    snapshot, cq, cq_name, ps, per_pod, starts, flavors, k, c
+                    snapshot, cq, cq_name, ps, per_pod, (), flavors, k, c
                 )
                 templates[key] = t
             if t.fallback:
                 bad = True
                 break
             head_templates.append((key, t, ps, per_pod))
+        resumed = any(any(st) for st in head_starts)
+        if resumed and not first:
+            bad = True
         if bad:
             out.fallback.append(i)
             continue
+        out.resumed += resumed
+        if resumed:
+            out.head_starts[i] = head_starts
 
         out.cq_row[i] = head_templates[0][1].cq_row
         out.n_podsets[i] = len(wl.pod_sets)

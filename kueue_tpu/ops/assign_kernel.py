@@ -83,6 +83,14 @@ class HeadsBatch(NamedTuple):
     # default; kernel-level tests build batches without one) is
     # identical to all-zero.
     score: jnp.ndarray = None
+    # int8[W,K,C] whenCanBorrow TryNextFlavor (flavorassigner.go:620-638):
+    # for such a head, each candidate cell's resource-group bit. A
+    # candidate's penalty is the OR of the bits of its cells that
+    # borrow, and the choice among fitting candidates minimizes it
+    # before the walk order: each group takes its first flavor that
+    # fits without borrowing, else its first that fits. Zeros (every
+    # other head) or None keep the first fit.
+    try_next: jnp.ndarray = None
 
 
 class SolveResult(NamedTuple):
@@ -92,7 +100,9 @@ class SolveResult(NamedTuple):
     usage: int64[N,FR] final leaf usage after all admissions;
     order: int32[W] — the admission entry order used by phase 2
     (scheduler.go:575-599), so the host can replay bookkeeping in the
-    same sequence."""
+    same sequence; skipped: bool[W] — the chosen candidate is not the
+    first that fits: a whenCanBorrow TryNextFlavor head walked past a
+    flavor where it fits only by borrowing."""
 
     chosen: jnp.ndarray
     admitted: jnp.ndarray
@@ -100,6 +110,7 @@ class SolveResult(NamedTuple):
     reserved: jnp.ndarray
     usage: jnp.ndarray
     order: jnp.ndarray
+    skipped: jnp.ndarray = None
 
 
 def build_paths(parent, max_depth: int):
@@ -205,11 +216,13 @@ def phase1_classify(
 ) -> Tuple[jnp.ndarray, ...]:
     """Pick each head's first fitting candidate against the cycle-start
     snapshot. Returns (chosen int32[W], borrows bool[W,K],
-    preempt_k int32[W]).
+    preempt_k int32[W], skipped bool[W]).
 
-    Equivalent to running FlavorAssigner.assign for every head with the
-    default fungibility policy (stop at the first Fit —
-    flavorassigner.go:620-638) before any admission mutates usage.
+    Equivalent to running FlavorAssigner.assign for every head before
+    any admission mutates usage: the default fungibility policy stops at
+    the first Fit, and whenCanBorrow TryNextFlavor (``heads.try_next``)
+    walks on past a Fit that borrows (flavorassigner.go:620-638);
+    ``skipped`` marks the heads whose choice lies past such a Fit.
     ``preempt_k`` is the representative preempt-mode candidate for
     unfit heads: the first candidate whose request fits within the
     cohort's potentialAvailable (flavorassigner.go:692-726 classifies
@@ -248,13 +261,11 @@ def phase1_classify(
         axis=-1,
     )  # [W,K]
     has_cohort = (tree.parent[cq] >= 0)[:, None]  # [W,1]
-    borrows = (
-        jnp.any(
-            jnp.where(cell_need, local_wkc + heads.qty > subtree_wkc, False),
-            axis=-1,
-        )
-        & has_cohort
-    )  # [W,K]
+    borrow_cells = (
+        jnp.where(cell_need, local_wkc + heads.qty > subtree_wkc, False)
+        & has_cohort[..., None]
+    )  # [W,K,C]
+    borrows = jnp.any(borrow_cells, axis=-1)  # [W,K]
 
     # masked score-argmax (kueue_tpu/policy): among eligible candidates
     # pick the highest score; jnp.argmax's first-max tie-break keeps
@@ -264,9 +275,18 @@ def phase1_classify(
     neg = jnp.int64(-(2**62))
     fit_ok = fits & heads.valid
     first_fit = jnp.argmax(jnp.where(fit_ok, score, neg), axis=1)
+    pick = first_fit
+    if heads.try_next is not None:
+        # one bit per resource group whose flavor borrows; a lower
+        # penalty wins before the score and the walk order
+        penalty = jnp.bitwise_or.reduce(
+            jnp.where(borrow_cells, heads.try_next, 0).astype(jnp.int8), axis=-1
+        ).astype(jnp.int64)  # [W,K]
+        pick = jnp.argmax(jnp.where(fit_ok, score - penalty, neg), axis=1)
     any_fit = jnp.any(fit_ok, axis=1)
     populated = heads.cq_row >= 0
-    chosen = jnp.where(any_fit & populated, first_fit, -1).astype(jnp.int32)
+    chosen = jnp.where(any_fit & populated, pick, -1).astype(jnp.int32)
+    skipped = (chosen >= 0) & (pick != first_fit)
 
     pre_ok = pot_fits & heads.valid
     first_pre = jnp.argmax(jnp.where(pre_ok, score, neg), axis=1)
@@ -276,7 +296,7 @@ def phase1_classify(
     ).astype(jnp.int32)
     # Per-cell masks (for the drain's resource-group walks) live in the
     # standalone cell_masks() helper above — single source of truth.
-    return chosen, borrows, preempt_k
+    return chosen, borrows, preempt_k, skipped
 
 
 def _avail_along_path(
@@ -355,7 +375,7 @@ def solve_cycle(
     """
     max_depth = tree.max_depth
     subtree, guaranteed = subtree_quota(tree)
-    chosen, borrows_wk, preempt_k = phase1_classify(
+    chosen, borrows_wk, preempt_k, skipped = phase1_classify(
         tree, subtree, guaranteed, local_usage, heads
     )
 
@@ -440,6 +460,7 @@ def solve_cycle(
         reserved=reserved,
         usage=usage_final,
         order=order.astype(jnp.int32),
+        skipped=skipped,
     )
 
 
@@ -502,7 +523,7 @@ def solve_cycle_segmented(
     """
     max_depth = tree.max_depth
     subtree, guaranteed = subtree_quota(tree)
-    chosen, borrows_wk, preempt_k = phase1_classify(
+    chosen, borrows_wk, preempt_k, skipped = phase1_classify(
         tree, subtree, guaranteed, local_usage, heads
     )
 
@@ -620,6 +641,7 @@ def solve_cycle_segmented(
         reserved=reserved,
         usage=usage_final,
         order=order.astype(jnp.int32),
+        skipped=skipped,
     )
 
 
@@ -632,7 +654,7 @@ def _solve_cycle_segmented_packed(
     tree, local_usage, heads, paths, seg_id, n_segments: int, n_steps: int
 ):
     """solve_cycle_segmented with the per-head outputs stacked into ONE
-    int64[5, W] tensor, so the host retrieves the whole cycle outcome in
+    int64[6, W] tensor, so the host retrieves the whole cycle outcome in
     a single device->host fetch (each fetch pays a full round trip)."""
     r = solve_cycle_segmented(
         tree, local_usage, heads, paths, seg_id, n_segments, n_steps
@@ -644,6 +666,7 @@ def _solve_cycle_segmented_packed(
             r.borrows.astype(jnp.int64),
             r.reserved.astype(jnp.int64),
             r.order.astype(jnp.int64),
+            r.skipped.astype(jnp.int64),
         ]
     )
     return packed

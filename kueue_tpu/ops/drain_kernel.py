@@ -119,6 +119,11 @@ class DrainQueues(NamedTuple):
     # earliest-flavor choice bit-for-bit. None (kernel-level tests)
     # is identical to all-zero; plan_drain always ships an array.
     score: jnp.ndarray = None
+    # int32[Q,P,G] each queue's first flavor walk: its first head's
+    # resume cursor per podset and group (-1 where it has none), read by
+    # every drain kernel. None (kernel-level tests) starts every walk at
+    # the first flavor.
+    g_start0: jnp.ndarray = None
 
 
 class DrainResult(NamedTuple):
@@ -598,6 +603,14 @@ def _plain_cycle(
             adm_cycle, cycle + 1)
 
 
+def first_walks(queues: DrainQueues) -> jnp.ndarray:
+    """int32[Q,P,G] the walk each queue's first head starts from."""
+    if queues.g_start0 is not None:
+        return queues.g_start0.astype(jnp.int32)
+    q, _, pmax = queues.cells.shape[:3]
+    return jnp.zeros((q, pmax, queues.gidx.shape[-1]), dtype=jnp.int32)
+
+
 def solve_drain(
     tree: QuotaTree,
     local_usage: jnp.ndarray,  # int64[N, FR] starting leaf usage
@@ -613,7 +626,6 @@ def solve_drain(
     potential = potential_available_all(tree, subtree, guaranteed)
 
     q, l, pmax, k, c = queues.cells.shape
-    g = queues.gidx.shape[-1]
 
     def cycle_body(state):
         return _plain_cycle(
@@ -628,7 +640,7 @@ def solve_drain(
     init = (
         local_usage,
         jnp.zeros(q, dtype=jnp.int32),
-        jnp.zeros((q, pmax, g), dtype=jnp.int32),
+        first_walks(queues),
         jnp.zeros(q, dtype=jnp.int32),
         jnp.zeros(q, dtype=bool),
         jnp.int32(0),
@@ -1127,12 +1139,11 @@ def solve_drain_tas(
         cycle = state[10]
         return jnp.any((cursor < queues.qlen) & ~stuck) & (cycle < max_cycles)
 
-    g = queues.gidx.shape[-1]
     init = (
         local_usage,
         tas_usage0,
         jnp.zeros(q, dtype=jnp.int32),
-        jnp.zeros((q, pmax, g), dtype=jnp.int32),
+        first_walks(queues),
         jnp.zeros(q, dtype=jnp.int32),
         jnp.zeros(q, dtype=bool),
         jnp.int32(0),
@@ -1456,11 +1467,10 @@ def solve_drain_fair(
         _, cursor, _, _, stuck, _, _, _, cycle = state
         return jnp.any((cursor < queues.qlen) & ~stuck) & (cycle < max_cycles)
 
-    g = queues.gidx.shape[-1]
     init = (
         local_usage,
         jnp.zeros(q, dtype=jnp.int32),
-        jnp.zeros((q, pmax, g), dtype=jnp.int32),
+        first_walks(queues),
         jnp.zeros(q, dtype=jnp.int32),
         jnp.zeros(q, dtype=bool),
         jnp.int32(0),
@@ -2336,11 +2346,10 @@ def solve_drain_preempt(
         )
         return has_pending & (cycle < max_cycles)
 
-    g = queues.gidx.shape[-1]
     init = (
         local_usage,
         jnp.zeros((q, l), dtype=jnp.int32),
-        jnp.zeros((q, pmax, g), dtype=jnp.int32),
+        first_walks(queues),
         jnp.zeros(q, dtype=jnp.int32),
         jnp.zeros(q, dtype=bool),
         jnp.int32(0),
@@ -2919,11 +2928,10 @@ def solve_drain_fair_preempt(
         )
         return has_pending & (cycle < max_cycles)
 
-    g = queues.gidx.shape[-1]
     init = (
         local_usage,
         jnp.zeros((q, l), dtype=jnp.int32),
-        jnp.zeros((q, pmax, g), dtype=jnp.int32),
+        first_walks(queues),
         jnp.zeros(q, dtype=jnp.int32),
         jnp.zeros(q, dtype=bool),
         jnp.int32(0),

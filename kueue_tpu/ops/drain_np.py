@@ -324,6 +324,8 @@ def solve_drain_np(
 
     local = local_usage.copy()
     cursor = np.zeros(q, dtype=np.int32)
+    if g_start0 is None:
+        g_start0 = queues_np.get("g_start0")
     g_start = (
         np.zeros((q, pmax, g), dtype=np.int32)
         if g_start0 is None else g_start0.astype(np.int32)

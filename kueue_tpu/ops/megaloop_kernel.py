@@ -48,7 +48,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 from kueue_tpu._jax import jax, jnp, lax
-from kueue_tpu.ops.drain_kernel import DrainQueues, _plain_cycle
+from kueue_tpu.ops.drain_kernel import DrainQueues, _plain_cycle, first_walks
 from kueue_tpu.ops.quota import QuotaTree, subtree_quota
 
 
@@ -185,7 +185,7 @@ def solve_drain_megaloop(
     init = (
         local_usage,
         jnp.zeros(q, dtype=jnp.int32),
-        jnp.zeros((q, pmax, g), dtype=jnp.int32),
+        first_walks(queues),
         jnp.zeros(q, dtype=jnp.int32),
         jnp.zeros(q, dtype=bool),
         jnp.int32(0),

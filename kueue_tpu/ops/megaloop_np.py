@@ -134,7 +134,11 @@ def solve_megaloop_np(
     r_cycles = np.zeros(max_rounds, dtype=np.int32)
     r_usage = np.zeros((max_rounds, n, fr), dtype=np.int64)
     g = queues_np["gidx"].shape[-1]
-    g_start = np.zeros((q, pmax, g), dtype=np.int32)
+    g_start0 = queues_np.get("g_start0")
+    g_start = (
+        np.zeros((q, pmax, g), dtype=np.int32)
+        if g_start0 is None else g_start0.astype(np.int32)
+    )
     r_gstart = np.zeros((max_rounds, q, pmax, g), dtype=np.int32)
 
     rounds = 0

@@ -61,7 +61,7 @@ def _solve_scenarios(
         subtree, guaranteed = subtree_quota(tree)
         # preempt-mode representative per head (phase 1 inside the
         # segmented solve doesn't surface it); XLA CSEs the shared work
-        _, _, preempt_k = phase1_classify(tree, subtree, guaranteed, usage, h)
+        _, _, preempt_k, _ = phase1_classify(tree, subtree, guaranteed, usage, h)
         r = solve_cycle_segmented(
             tree, usage, h, paths, seg_id, n_segments, n_steps
         )

@@ -70,6 +70,10 @@ class ShardedSolver:
             heads = heads._replace(
                 score=jnp.zeros(heads.valid.shape, dtype=jnp.int64)
             )
+        if heads.try_next is None:
+            heads = heads._replace(
+                try_next=jnp.zeros(heads.cells.shape, dtype=jnp.int8)
+            )
         fr_size = tree.nominal.shape[1]
         return (
             jax.device_put(tree, build_tree_spec(self.mesh, fr_size)),
@@ -101,6 +105,11 @@ class ShardedSolver:
             if heads.score is not None
             else jnp.zeros(heads.valid.shape, dtype=jnp.int64)
         )
+        try_next = (
+            heads.try_next
+            if heads.try_next is not None
+            else jnp.zeros(heads.cells.shape, dtype=jnp.int8)
+        )
         return HeadsBatch(
             cq_row=jnp.pad(heads.cq_row, (0, pad), constant_values=-1),
             cells=jnp.pad(
@@ -112,6 +121,7 @@ class ShardedSolver:
             timestamp=pad0(heads.timestamp),
             no_reclaim=pad0(heads.no_reclaim),
             score=pad0(score),
+            try_next=pad0(try_next),
         )
 
     def __call__(
@@ -162,6 +172,7 @@ def build_heads_spec(mesh) -> HeadsBatch:
         timestamp=_sh(mesh, "wl"),
         no_reclaim=_sh(mesh, "wl"),
         score=_sh(mesh, "wl", None),
+        try_next=_sh(mesh, "wl", None, None),
     )
 
 

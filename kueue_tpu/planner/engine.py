@@ -252,13 +252,11 @@ def solve_scenario_host(
         axis=-1,
     )
     has_cohort = (parent[cq] >= 0)[:, None]
-    borrows_wk = (
-        np.any(
-            np.where(cell_need, local_wkc + batch.qty > subtree_wkc, False),
-            axis=-1,
-        )
-        & has_cohort
+    borrow_cells = (
+        np.where(cell_need, local_wkc + batch.qty > subtree_wkc, False)
+        & has_cohort[..., None]
     )
+    borrows_wk = np.any(borrow_cells, axis=-1)
 
     populated = batch.cq_row >= 0
     # masked score-argmax (kueue_tpu/policy) — np.argmax's first-max
@@ -270,9 +268,19 @@ def solve_scenario_host(
     neg = np.int64(-(2**62))
     fit_ok = fits & batch.valid
     first_fit = np.argmax(np.where(fit_ok, score, neg), axis=1)
+    pick = first_fit
+    try_next = getattr(batch, "try_next", None)
+    if try_next is not None:
+        # whenCanBorrow TryNextFlavor: the OR of the group bits of the
+        # borrowing cells is a penalty taken before score and walk order
+        penalty = np.bitwise_or.reduce(
+            np.where(borrow_cells, try_next, 0).astype(np.int8), axis=-1
+        ).astype(np.int64)
+        pick = np.argmax(np.where(fit_ok, score - penalty, neg), axis=1)
     chosen = np.where(
-        fit_ok.any(axis=1) & populated, first_fit, -1
+        fit_ok.any(axis=1) & populated, pick, -1
     ).astype(np.int32)
+    skipped = (chosen >= 0) & (pick != first_fit)
     pre_ok = pot_fits & batch.valid
     preempt_k = np.where(
         pre_ok.any(axis=1) & populated & (chosen < 0),
@@ -347,6 +355,7 @@ def solve_scenario_host(
         "order": order.astype(np.int32),
         "preempt_k": preempt_k,
         "usage": usage_t,
+        "skipped": skipped,
     }
 
 

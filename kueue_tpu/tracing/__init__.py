@@ -18,6 +18,7 @@ phases and their sub-phases, the runtime loop, the batch apply — into a
 """
 
 from kueue_tpu.tracing.names import (
+    CYCLE_COUNTS,
     CYCLE_PHASE_SPANS,
     CYCLE_SPAN_NAMES,
     CYCLE_SUBPHASE_SPANS,
@@ -39,6 +40,7 @@ from kueue_tpu.tracing.tracer import (
 from kueue_tpu.tracing.export import to_chrome_trace
 
 __all__ = [
+    "CYCLE_COUNTS",
     "CYCLE_PHASE_SPANS",
     "CYCLE_SPAN_NAMES",
     "CYCLE_SUBPHASE_SPANS",

@@ -113,6 +113,26 @@ PHASE_COUNTERS = {
     "ingest.apply": "ingest.objects",
 }
 
+# CycleTrace.counts keys that no phase owns, each added where its event
+# happens (core/scheduler.py for a cycle, controllers/cluster.py for a
+# drain round): heads the lowering handed to the host, heads the kernel
+# returned non-Fit, heads the victim search's candidate index answered
+# empty; heads of a non-default-fungibility ClusterQueue offered to the
+# per-cycle lowering, and those lowered onto the device; admissions
+# whose flavor in some resource group lies past one where they fit only
+# by borrowing; heads lowered with a resume cursor
+CYCLE_COUNTS = frozenset(
+    {
+        "unrepresentable",
+        "returned",
+        "no_candidates",
+        "fungible_heads",
+        "fungible_lowered",
+        "borrow_skipped",
+        "walks_resumed",
+    }
+)
+
 # CycleTrace phase key -> cycle span name (the lowering used by
 # Tracer.record_cycle; a phase without a registry entry is a bug in
 # the emitting site, same contract as classify_inadmissible_message)

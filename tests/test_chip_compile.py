@@ -348,3 +348,35 @@ def test_solve_cycle_compiles(one_chip):
         sys.path.pop(0)
     fn, args = entry()
     _assert_fits(_compile_for_chip(jax.jit(fn), args, {}, one_chip))
+
+
+def test_try_next_flavor_cycle_compiles(one_chip):
+    """The segmented cycle kernel as the scheduler launches it, with a
+    whenCanBorrow TryNextFlavor batch: 1,024 heads of four candidates
+    over two resource groups (``fungibility-1k``'s shape)."""
+    import numpy as np
+
+    import sys
+
+    from kueue_tpu.ops.assign_kernel import solve_cycle_segmented_packed_jit
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
+    try:
+        from __graft_entry__ import _synthetic_problem
+    finally:
+        sys.path.pop(0)
+    w, k, c = 1024, 8, 16
+    tree, usage, batch, paths = _synthetic_problem(1002, 167, 8, w, k=k, c=c)
+    # group 0 on cells 0-1, group 1 on cell 2, the rest padding
+    bits = np.zeros((w, k, c), dtype=np.int8)
+    bits[:, :, :2] = 1
+    bits[:, :, 2] = 2
+    batch = batch._replace(
+        score=np.zeros((w, k), dtype=np.int64), try_next=bits
+    )
+    seg_id = (np.arange(w) % 167).astype(np.int32)
+    kw = {"n_segments": 256, "n_steps": 8}
+    _assert_fits(_compile_for_chip(
+        solve_cycle_segmented_packed_jit,
+        (tree, usage, batch, paths, seg_id), kw, one_chip,
+    ))

@@ -286,10 +286,18 @@ def _admit_victim(cache, mgr_clock_t, name, cq_name, flavor, cpu, prio, uid_t):
 
 def build_preempt_env(spec):
     """build_env + pre-admitted victims from spec['victims']:
-    (name, cq, flavor, cpu, prio, t) tuples."""
+    (name, cq, flavor, cpu, prio, t) tuples. The workloads named in
+    spec['resume'] carry a LastAssignment that resumes their cpu walk at
+    the second flavor, as a walk an earlier cycle cut off does."""
+    from kueue_tpu.core.flavor_assigner import AssignmentState
+
     sched, mgr, cache, workloads = build_env(spec, use_solver=False)
     for name, cq_name, flavor, cpu, prio, t in spec.get("victims", []):
         _admit_victim(cache, None, name, cq_name, flavor, cpu, prio, t)
+    for name in spec.get("resume", ()):
+        workloads[name].last_assignment = AssignmentState(
+            last_tried_flavor_idx=[{"cpu": 0}], cluster_queue_generation=2**40
+        )
     return sched, mgr, cache, workloads
 
 
@@ -601,6 +609,27 @@ class TestPreemptDrainParity:
     @pytest.mark.parametrize("seed", range(4, 16))
     def test_randomized_wide(self, seed):
         self.test_randomized(seed)
+
+    @pytest.mark.parametrize("seed", range(4))
+    def test_resumed_first_heads(self, seed):
+        # each ClusterQueue's first head resumes its walk at the second
+        # flavor; where that walk ends without a fit it restarts at the
+        # first flavor, on the host and in the drain alike
+        spec = preempt_spec(seed)
+        spec["resume"] = [
+            min((-w["prio"], w["t"], w["name"]) for w in spec["workloads"]
+                if w["queue"] == f"lq-{cq['name']}")[2]
+            for cq in spec["cqs"]
+            if len(cq["groups"][0]["flavors"]) > 1
+        ]
+        assert spec["resume"]
+        h_admitted, h_evicted, h_parked = host_preempt_drain_trace(spec)
+        admitted, evicted, parked, outcome = device_preempt_drain_trace(spec)
+        assert not outcome.fallback
+        assert outcome.resumed == len(spec["resume"])
+        assert admitted == h_admitted
+        assert evicted == h_evicted
+        assert parked == h_parked
 
     def test_reactivated_head_preempts_drain_admitted_same_cq(self):
         # Within-CQ-only cohort (no reclaim anywhere): w-hi parks (its

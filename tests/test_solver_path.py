@@ -229,6 +229,54 @@ def random_spec(seed, with_preemption=False, n_cohorts=2, cqs_per_cohort=3,
     return {"flavors": flavors, "cqs": cqs, "workloads": workloads}
 
 
+def try_next_spec(seed, n_cohorts=2, cqs_per_cohort=6, workloads_per_cq=8):
+    """Two resource groups (cpu+memory | gpu) of 2-4 flavors each (at
+    most 8 candidates a head), no borrowing limit, and whenCanBorrow
+    TryNextFlavor on a random half of the ClusterQueues."""
+    from kueue_tpu.models.cluster_queue import FlavorFungibility
+    from kueue_tpu.models.constants import FlavorFungibilityPolicy as FF
+
+    rng = np.random.default_rng(seed)
+    n_a, n_b = [(2, 2), (2, 3), (3, 2), (2, 4), (4, 2)][int(rng.integers(0, 5))]
+    cpu_fl = [f"c{i}" for i in range(n_a)]
+    gpu_fl = [f"g{i}" for i in range(n_b)]
+    cqs, workloads, t = [], [], 0.0
+    for ci in range(n_cohorts):
+        for qi in range(cqs_per_cohort):
+            name = f"cq-{ci}-{qi}"
+            cqs.append({
+                "name": name,
+                "cohort": f"cohort-{ci}",
+                "groups": [
+                    {"resources": ["cpu", "memory"], "flavors": [
+                        (f, {"cpu": str(int(rng.integers(2, 10))),
+                             "memory": f"{int(rng.integers(4, 40))}Gi"}, None, None)
+                        for f in cpu_fl]},
+                    {"resources": ["gpu"], "flavors": [
+                        (f, {"gpu": str(int(rng.integers(1, 6)))}, None, None)
+                        for f in gpu_fl]},
+                ],
+                "preemption": None,
+                "fungibility": FlavorFungibility(
+                    when_can_borrow=(FF.TRY_NEXT_FLAVOR if rng.random() < 0.5
+                                     else FF.BORROW),
+                    when_can_preempt=FF.TRY_NEXT_FLAVOR,
+                ),
+            })
+            for wi in range(workloads_per_cq):
+                t += 1.0
+                req = {"cpu": str(int(rng.integers(1, 6))),
+                       "memory": f"{int(rng.integers(1, 12))}Gi"}
+                if rng.random() < 0.6:
+                    req["gpu"] = str(int(rng.integers(1, 4)))
+                workloads.append({
+                    "name": f"wl-{ci}-{qi}-{wi}", "queue": f"lq-{name}",
+                    "prio": int(rng.integers(0, 3)) * 10, "t": t,
+                    "pod_sets": [{"name": "main", "count": 1, "requests": req}],
+                })
+    return {"flavors": cpu_fl + gpu_fl, "cqs": cqs, "workloads": workloads}
+
+
 class TestResidentCycleState:
     def test_delta_updates_and_invalidation(self):
         """dispatch_lowered with device-resident tensors must decide
@@ -378,6 +426,46 @@ class TestSolverPathParity:
             ],
         }
         assert_parity(spec)
+
+
+    @pytest.mark.parametrize("seed", range(20, 28))
+    def test_randomized_try_next_flavor(self, seed):
+        # whenCanBorrow TryNextFlavor heads are lowered onto the device
+        # and decide as the host flavor walk does, cursors included
+        spec = try_next_spec(seed)
+        trace = assert_parity(spec)
+        assert any(c["admitted"] for c in trace)
+        sched, mgr, cache, _ = build_env(spec, use_solver=True)
+        drain_and_trace(sched, mgr, cache)
+        counts = [t.counts for t in sched.last_traces]
+        offered = sum(c.get("fungible_heads", 0) for c in counts)
+        assert offered > 0
+        assert sum(c.get("fungible_lowered", 0) for c in counts) == offered
+        assert sum(c.get("unrepresentable", 0) for c in counts) == 0
+
+    @pytest.mark.parametrize("seed", range(28, 32))
+    def test_try_next_flavor_host_mirror(self, seed):
+        # the guard's numpy mirror (KERNEL_MIRRORS["assign_kernel"]) takes
+        # the same candidate for a TryNextFlavor batch, skip flag included
+        from kueue_tpu.core.guard import results_match, solve_lowered_host
+        from kueue_tpu.core.snapshot import take_snapshot
+        from kueue_tpu.core.solver import dispatch_lowered, lower_heads
+
+        spec = try_next_spec(seed, workloads_per_cq=3)
+        sched, mgr, cache, workloads = build_env(spec, use_solver=True)
+        # admit one cycle so that later heads borrow
+        sched.schedule()
+        snapshot = take_snapshot(cache)
+        heads = [
+            (wl, f"cq-{wl.name.split('-')[1]}-{wl.name.split('-')[2]}")
+            for wl in workloads.values() if not wl.has_quota_reservation
+        ]
+        lowered = lower_heads(snapshot, heads, cache.flavors)
+        assert not lowered.fallback
+        dev = dispatch_lowered(snapshot, lowered)
+        host = solve_lowered_host(snapshot, lowered)
+        assert results_match(dev, host) == []
+        assert np.array_equal(np.asarray(dev.skipped), np.asarray(host.skipped))
 
 
 class TestDeviceResolution:
